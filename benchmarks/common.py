@@ -20,8 +20,11 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
-from repro.obs import metrics as OM  # noqa: E402  (import after x64 setup)
+from repro import compile_cache  # noqa: E402  (import after x64 setup)
+from repro.obs import metrics as OM  # noqa: E402
 from repro.perf import timing  # noqa: E402
+
+compile_cache.enable()
 
 
 def time_fn(fn: Callable, *args, iters: int = 20, warmup: int = 3) -> float:

@@ -90,7 +90,6 @@ def detection_wire(seeds=(0, 1, 2)) -> dict | None:
     """
     if jax.device_count() < 2:
         return None
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as P
 
@@ -101,10 +100,10 @@ def detection_wire(seeds=(0, 1, 2)) -> dict | None:
     full = jnp.asarray(np.random.default_rng(3).normal(size=64))
 
     def ok_under(hook, tag, wire) -> bool:
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda bnd: halo_all_gather(bnd, "sh", tag=tag, wire=wire,
                                         check=True)[1],
-            mesh=mesh, in_specs=P("sh"), out_specs=P(), check_rep=False,
+            mesh=mesh, in_specs=P("sh"), out_specs=P(), check_vma=False,
         )
         set_wire_fault(hook)
         try:

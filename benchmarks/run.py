@@ -111,7 +111,8 @@ def run_quick(out_path: pathlib.Path | None = None) -> dict:
     on the skewed benchmark matrix and is gated (DESIGN.md §12): the SELL
     layout must waste < 50% of uniform ELL's padded-slot fraction, stream
     < 50% of its modeled tag-1 bytes, and keep tag-1 effective bytes
-    within 10% of the 6 B/nnz the format promises.  The JSON is written
+    within 10% of the kernel's 10 B per slot (6 B/nnz of segments + 4 B of
+    gathered x; ``precision_table.KERNEL_SLOT_BYTES``).  The JSON is written
     BEFORE the gate raises so a failing run still uploads diagnostics.
     """
     from benchmarks import fig6_spmv_formats
@@ -139,10 +140,13 @@ def run_quick(out_path: pathlib.Path | None = None) -> dict:
             f"skewed smoke: SELL tag-1 bytes {sell['bytes_touched_tag1']} "
             f"not < 50% of uniform-ELL's {ell['bytes_touched_tag1']}"
         )
-    if abs(sell["bytes_per_nnz_tag1"] - 6.0) / 6.0 > 0.10:
+    from repro.core.precision_table import KERNEL_SLOT_BYTES
+
+    per_slot = KERNEL_SLOT_BYTES[1]
+    if abs(sell["bytes_per_nnz_tag1"] - per_slot) / per_slot > 0.10:
         raise SystemExit(
             f"skewed smoke: SELL tag-1 effective {sell['bytes_per_nnz_tag1']:.3f} "
-            "B/nnz strayed > 10% from the 6 B/nnz format promise"
+            f"B/nnz strayed > 10% from the kernel's {per_slot} B per slot"
         )
     return payload
 

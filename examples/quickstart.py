@@ -6,6 +6,10 @@ import jax
 
 jax.config.update("jax_enable_x64", True)
 
+from repro import compile_cache  # noqa: E402
+
+compile_cache.enable()
+
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 

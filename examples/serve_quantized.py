@@ -7,7 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.models import stepfns, transformer as T
 from repro.quant import gse_tensor as Q
 
@@ -50,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

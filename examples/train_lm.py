@@ -16,7 +16,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 
-from repro import configs
+from repro import compile_cache, configs
 from repro.checkpoint import ckpt
 from repro.data.pipeline import DataConfig, TokenPipeline
 from repro.launch.train import build
@@ -68,4 +68,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -12,7 +12,10 @@ from one fact about the GSE-SEM encoding (paper Section III.C):
 
 and every CSR/ELL/SELL entry additionally streams a packed u32 column
 index (``COLIDX_BYTES``), giving the paper's 6/8/12 B/nnz matrix-stream
-figures (``SLOT_BYTES``).  The halo wire ships only the *value* segments
+figures (``SLOT_BYTES``) -- the encoding's cost.  The ELL/SELL Pallas
+kernels also stream the f32 gathered-x tile ``x[col]`` per padded slot
+and right-hand side (``GATHERED_X_BYTES``), so one SpMV kernel launch
+streams ``KERNEL_SLOT_BYTES`` = 10/12/16 B per padded slot.  The halo wire ships only the *value* segments
 (the receiving shard already knows which boundary entries it asked for),
 so ``WIRE_ENTRY_BYTES == TAG_VALUE_BYTES``.
 
@@ -33,6 +36,8 @@ __all__ = [
     "SEGMENT_BYTES",
     "TAG_BITS_USED",
     "tag_operand_names",
+    "GATHERED_X_BYTES",
+    "KERNEL_SLOT_BYTES",
 ]
 
 # GSE tags in escalation order (head-only -> +tail1 -> +tail2).
@@ -64,8 +69,9 @@ TAG_VALUE_BYTES = {
 
 
 def tag_operand_names(tag: int) -> tuple:
-    """The pallas_call operand list the tag-specialized kernels stream."""
-    return ("scales", "colpak", "head") + TAG_SEGMENTS[tag] + ("x",)
+    """The pallas_call operand list the tag-specialized kernels stream
+    (``xg`` is the gathered-x tile, one f32 per padded slot and column)."""
+    return ("scales", "colpak", "head") + TAG_SEGMENTS[tag] + ("xg",)
 
 # Every stored entry also streams one packed u32 column index (expIdx in
 # the top EI_BIT bits, column in the rest).
@@ -74,6 +80,14 @@ COLIDX_BYTES = 4
 # Matrix-stream bytes one padded slot (or one nnz) costs at each tag:
 # the paper's 6/8/12 B/nnz format promise (DESIGN.md section 8).
 SLOT_BYTES = {t: TAG_VALUE_BYTES[t] + COLIDX_BYTES for t in TAGS}
+
+# f32 bytes of the gathered-x tile (``x[col]``) the ELL/SELL kernels stream
+# per padded slot and right-hand side: Mosaic lowers no 1-D gather of a
+# VMEM-pinned x, so the gather runs in XLA and the kernel streams its output.
+GATHERED_X_BYTES = 4
+
+# Bytes one tag-t SpMV kernel launch streams per padded slot: 10/12/16.
+KERNEL_SLOT_BYTES = {t: SLOT_BYTES[t] + GATHERED_X_BYTES for t in TAGS}
 
 # Bytes ONE boundary x-entry costs on the halo wire at each tag
 # (DESIGN.md section 13): the wire ships value segments only.

@@ -47,6 +47,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from repro.core import precision_table
 from repro.core.tagmap import TagMap, normalize_tags
@@ -62,7 +63,11 @@ __all__ = [
     "partition_gsecsr",
     "unshard",
     "WIRE_ENTRY_BYTES",
+    "AXIS",
 ]
+
+# The mesh axis the stacked per-shard arrays are split along.
+AXIS = "shards"
 
 # Bytes ONE boundary x-entry costs on the wire at each tag (DESIGN.md §13):
 # tag 1 ships the u16 GSE head, tag 2 head+tail1, tag 3 raw float64.
@@ -77,11 +82,13 @@ class PartitionedGSECSR:
 
     All per-shard arrays carry a leading ``n_shards`` axis and are padded
     to uniform extents (max nnz ``E``, max boundary ``B``, max halo ``H``)
-    so ``shard_map`` can split them along the mesh axis.  Padding matrix
-    entries decode to +0.0 and scatter into a dummy row (``row_ids == R``),
-    so they perturb nothing; padded boundary slots (``bnd_idx == -1``) are
-    masked to zero before the wire pack, and padded halo slots are never
-    read by real matrix entries.
+    so ``shard_map`` can split them along the mesh axis; with enough
+    devices visible, row ``i`` of each lives on device ``i`` of the
+    ``AXIS`` mesh.  Padding matrix entries decode to +0.0 and carry the
+    out-of-range row id ``R``, which the local segment sum drops, so they
+    perturb nothing; padded boundary slots (``bnd_idx == -1``) are masked
+    to zero before the wire pack, and padded halo slots are never read by
+    real matrix entries.
     """
 
     # -- stacked per-shard matrix blocks (leading dim n_shards) ------------
@@ -89,7 +96,7 @@ class PartitionedGSECSR:
     head: jnp.ndarray      # (s, E) uint16
     tail1: jnp.ndarray     # (s, E) uint16
     tail2: jnp.ndarray     # (s, E) uint32
-    row_ids: jnp.ndarray   # (s, E) int32 LOCAL row ids; padding -> R (dummy)
+    row_ids: jnp.ndarray   # (s, E) int32 LOCAL row ids; padding -> R (dropped)
     # -- halo exchange plan ------------------------------------------------
     bnd_idx: jnp.ndarray   # (s, B) int32 local x indices this shard sends
     #                        (-1 marks padded slots: masked to 0 on the wire)
@@ -401,14 +408,11 @@ def partition_gsecsr(a: GSECSR, n_shards: int) -> PartitionedGSECSR:
         )
         if n_shards > 1 and len(bnd_cols[i]):
             s_bnd[i, :len(bnd_cols[i])] = bnd_cols[i] - lo
-    return PartitionedGSECSR(
-        colpak=jnp.asarray(s_colpak),
-        head=jnp.asarray(s_head),
-        tail1=jnp.asarray(s_tail1),
-        tail2=jnp.asarray(s_tail2),
-        row_ids=jnp.asarray(s_rows),
-        bnd_idx=jnp.asarray(s_bnd),
-        halo_idx=jnp.asarray(s_halo),
+    stacked, mesh = _place(
+        (s_colpak, s_head, s_tail1, s_tail2, s_rows, s_bnd, s_halo),
+        n_shards)
+    part = PartitionedGSECSR(
+        *stacked,
         table=a.table,
         ei_bit=ei,
         shape=a.shape,
@@ -419,6 +423,23 @@ def partition_gsecsr(a: GSECSR, n_shards: int) -> PartitionedGSECSR:
         bnd_counts=bnd_counts if n_shards > 1 else (0,),
         halo_counts=tuple(halo_counts) if n_shards > 1 else (0,),
     )
+    if mesh is not None:
+        part.__dict__["_mesh"] = mesh  # what dist_spmv.shard_mesh returns
+    return part
+
+
+def _place(arrays, n_shards: int):
+    """Stacked (n_shards, ...) host arrays -> device arrays whose row ``i``
+    lives on device ``i`` of a 1-D ``AXIS`` mesh, so the sharded solvers
+    never copy the operator between devices per call.  With fewer visible
+    devices than shards (byte models, host-only use) the arrays stay on
+    the default device and no mesh is returned."""
+    devs = jax.devices()
+    if len(devs) < n_shards:
+        return [jnp.asarray(x) for x in arrays], None
+    mesh = Mesh(np.array(devs[:n_shards]), (AXIS,))
+    spec = NamedSharding(mesh, PartitionSpec(AXIS))
+    return [jax.device_put(x, spec) for x in arrays], mesh
 
 
 def unshard(part: PartitionedGSECSR, a_template: GSECSR) -> GSECSR:

@@ -176,12 +176,7 @@ def compressed_psum(grads: jnp.ndarray, axis_name: str, k: int = 8):
     (N,) with N divisible by the axis size.  Returns the (approximately)
     summed gradient, decoded to f32.
     """
-    # jax.lax.axis_size is a newer-jax spelling; psum(1) is the portable
-    # axis-size query on the pinned 0.4.x.
-    if hasattr(jax.lax, "axis_size"):
-        n_dev = jax.lax.axis_size(axis_name)
-    else:
-        n_dev = jax.lax.psum(1, axis_name)
+    n_dev = jax.lax.axis_size(axis_name)
     n = grads.shape[0]
     assert n % n_dev == 0, (n, n_dev)
 

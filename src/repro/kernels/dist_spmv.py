@@ -33,11 +33,10 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.tagmap import TagMap, normalize_tags
-from repro.distributed.partition import PartitionedGSECSR
+from repro.distributed.partition import AXIS, PartitionedGSECSR
 from repro.distributed.wire import halo_all_gather
 from repro.perf import plan as launch_plan
 from repro.perf.plan import KernelPlan
@@ -45,8 +44,6 @@ from repro.sparse.spmv import _decode_gsecsr
 
 __all__ = ["shard_mesh", "local_matvec", "dist_spmv", "dist_spmm",
            "make_sharded_operator"]
-
-AXIS = "shards"
 
 
 def shard_mesh(part: PartitionedGSECSR) -> Mesh:
@@ -76,8 +73,8 @@ def local_matvec(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
     ``colpak/head/tail1/tail2/row_ids/bnd_idx/halo_idx/table``.  The halo
     exchange gathers only boundary entries; the decode is the exact
     single-device ``_decode_gsecsr`` on the shard's segments, and the
-    segment sum scatters into ``rows + 1`` slots so padding entries land
-    in a dummy row (bit-identical local row sums).
+    segment sum drops the padding entries, whose row id is ``rows``
+    (bit-identical local row sums).
     """
     if blk["bnd_idx"].shape[0] == 0:
         xcat = x_sh  # single shard: every column is local
@@ -102,9 +99,10 @@ def local_matvec(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
     )
     xg = xcat.astype(acc_dtype)[col]
     prod = val * xg if x_sh.ndim == 1 else val[:, None] * xg
-    return jax.ops.segment_sum(
-        prod, blk["row_ids"], num_segments=rows + 1
-    )[:rows]
+    # Padding entries carry row id ``rows``: segment_sum drops ids out of
+    # range, so no dummy row (and no slice for XLA to fuse into the
+    # consumer's dot, which would change its summation order).
+    return jax.ops.segment_sum(prod, blk["row_ids"], num_segments=rows)
 
 
 def _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table):
@@ -141,11 +139,11 @@ def _dist_matvec_fn(part: PartitionedGSECSR, wire: str, ndim: int,
         return jax.lax.switch(jnp.clip(tag - 1, 0, 2), branches, x)
 
     sharded = P(AXIS)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
         in_specs=(sharded,) * 7 + (P(), sharded, P()),
         out_specs=sharded,
-        check_rep=False,
+        check_vma=False,
     ))
     part.__dict__[key] = fn
     return fn
@@ -177,11 +175,11 @@ def _dist_matvec_map_fn(part: PartitionedGSECSR, tm: TagMap, wire: str,
                             slot_tags=slot_tags[0])
 
     sharded = P(AXIS)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
         in_specs=(sharded,) * 7 + (P(), sharded, sharded),
         out_specs=sharded,
-        check_rep=False,
+        check_vma=False,
     ))
     part.__dict__[key] = fn
     return fn

@@ -13,9 +13,9 @@ kernel body whose ``pallas_call`` operand list contains ONLY the segments
 that tag reads (tail arrays for tags that skip them never enter the jaxpr,
 never get a BlockSpec, never get DMA'd):
 
-    tag 1   scales, colpak, head, x                   (6  B/nnz streamed)
-    tag 2   scales, colpak, head, tail1, x            (8  B/nnz)
-    tag 3   scales, colpak, head, tail1, tail2, x     (12 B/nnz)
+    tag 1   scales, colpak, head, xg                  (6 + 4*nrhs B/slot)
+    tag 2   scales, colpak, head, tail1, xg           (8 + 4*nrhs B/slot)
+    tag 3   scales, colpak, head, tail1, tail2, xg    (12 + 4*nrhs B/slot)
 
 Output layout (DESIGN.md §2.3 generalized): each RHS column owns its own
 lane-aligned (BM, LANE) accumulator strip inside a (BM, nrhs*LANE) VMEM
@@ -24,8 +24,10 @@ groups per column, so every store fills all 128 lanes.  The reduction
 epilogue collapses the LANE partials per (row, column) to the final
 (M, nrhs) result.
 
-The dense operand rides the kernel as a (nrhs, n) VMEM-pinned block (the
-transpose keeps each column's gather a contiguous minor-dim read); padded
+The dense operand is gathered in XLA before the kernel, as in the SpMV
+(``gse_spmv.gather_cols``): ``xg`` is (nrhs, M, L) f32, streamed as
+(nrhs, BM, BL) tiles.  So the matrix segments are read once for all
+columns while each column adds its own 4 B per padded slot.  Padded
 matrix slots carry col=0, head=0 -> mantissa 0 -> contribute 0 to every
 column.
 
@@ -40,7 +42,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.gse_spmv import LANE, decode_tile, spmv_operand_names
+from repro.kernels.gse_spmv import (
+    LANE,
+    decode_tile,
+    gather_cols,
+    segment_operands,
+    spmv_operand_names,
+)
 from repro.perf import plan as launch_plan
 
 __all__ = ["gse_spmm_pallas", "gse_spmm_call", "gse_spmm_sell_call",
@@ -52,11 +60,11 @@ spmm_operand_names = spmv_operand_names
 
 
 def _accumulate(scales_ref, colpak_ref, head_ref, tail1_ref, tail2_ref,
-                x_ref, out_ref, *, ei_bit: int, tag: int, k: int, nrhs: int):
+                xg_ref, out_ref, *, ei_bit: int, tag: int, k: int, nrhs: int):
     """Shared tile math; tail refs are ``None`` for the tags that skip them.
 
     The decode runs ONCE per (BM, BL) tile (``decode_tile``, shared with
-    the SpMV kernel body); the per-column gathers and lane-group
+    the SpMV kernel body); the per-column products and lane-group
     reductions reuse the same decoded ``vals`` -- the in-VMEM twin of the
     byte model's "matrix bytes once, vector bytes per column".
     """
@@ -64,35 +72,32 @@ def _accumulate(scales_ref, colpak_ref, head_ref, tail1_ref, tail2_ref,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    vals, col = decode_tile(scales_ref, colpak_ref, head_ref, tail1_ref,
-                            tail2_ref, ei_bit=ei_bit, tag=tag, k=k)
+    vals = decode_tile(scales_ref, colpak_ref, head_ref, tail1_ref,
+                       tail2_ref, ei_bit=ei_bit, tag=tag, k=k)
 
     bm, bl = vals.shape
-    flat_col = col.reshape(-1)
     for j in range(nrhs):                 # static unroll over RHS columns
-        xj = x_ref[j, :]                  # (N,) in VMEM
-        xg = xj[flat_col].reshape(col.shape)
-        prod = vals * xg                  # (BM, BL) -- decoded vals reused
+        prod = vals * xg_ref[j]           # (BM, BL) -- decoded vals reused
         out_ref[:, j * LANE:(j + 1) * LANE] += jnp.sum(
             prod.reshape(bm, bl // LANE, LANE), axis=1
         )
 
 
-def _spmm_body_tag1(scales_ref, colpak_ref, head_ref, x_ref, out_ref, *,
+def _spmm_body_tag1(scales_ref, colpak_ref, head_ref, xg_ref, out_ref, *,
                     ei_bit: int, k: int, nrhs: int):
-    _accumulate(scales_ref, colpak_ref, head_ref, None, None, x_ref, out_ref,
+    _accumulate(scales_ref, colpak_ref, head_ref, None, None, xg_ref, out_ref,
                 ei_bit=ei_bit, tag=1, k=k, nrhs=nrhs)
 
 
-def _spmm_body_tag2(scales_ref, colpak_ref, head_ref, tail1_ref, x_ref,
+def _spmm_body_tag2(scales_ref, colpak_ref, head_ref, tail1_ref, xg_ref,
                     out_ref, *, ei_bit: int, k: int, nrhs: int):
-    _accumulate(scales_ref, colpak_ref, head_ref, tail1_ref, None, x_ref,
+    _accumulate(scales_ref, colpak_ref, head_ref, tail1_ref, None, xg_ref,
                 out_ref, ei_bit=ei_bit, tag=2, k=k, nrhs=nrhs)
 
 
 def _spmm_body_tag3(scales_ref, colpak_ref, head_ref, tail1_ref, tail2_ref,
-                    x_ref, out_ref, *, ei_bit: int, k: int, nrhs: int):
-    _accumulate(scales_ref, colpak_ref, head_ref, tail1_ref, tail2_ref, x_ref,
+                    xg_ref, out_ref, *, ei_bit: int, k: int, nrhs: int):
+    _accumulate(scales_ref, colpak_ref, head_ref, tail1_ref, tail2_ref, xg_ref,
                 out_ref, ei_bit=ei_bit, tag=3, k=k, nrhs=nrhs)
 
 
@@ -100,7 +105,7 @@ _BODIES = {1: _spmm_body_tag1, 2: _spmm_body_tag2, 3: _spmm_body_tag3}
 
 
 def gse_spmm_call(colpak, head, tail1, tail2, x, scales, *, ei_bit: int,
-                  tag: int, blocks=None, interpret: bool = True):
+                  tag: int, blocks=None, interpret: bool):
     """Unjitted tag-specialized SpMM (exported for jaxpr inspection).
 
     colpak/head (+tails the tag reads): (M, L); x: (N, nrhs) dense
@@ -108,38 +113,28 @@ def gse_spmm_call(colpak, head, tail1, tail2, x, scales, *, ei_bit: int,
     when ``tag`` does not read them; arrays passed for unread segments are
     ignored (not streamed).  ``blocks=None`` resolves through
     ``perf.plan.resolve`` to the (8, 128) default (DESIGN.md §15).
-    Returns Y = A @ X as a (M, nrhs) f32 array.
+    ``interpret`` has no default: the CPU/TPU choice belongs to
+    ``kernels.ops``.  Returns Y = A @ X as a (M, nrhs) f32 array.
     """
     blocks = launch_plan.resolve(blocks=blocks).blocks
-    m, L = colpak.shape
-    bm, bl = blocks
-    assert m % bm == 0 and L % bl == 0, (colpak.shape, blocks)
-    assert bl % LANE == 0, f"BL must be lane-aligned (multiple of {LANE})"
     assert x.ndim == 2, f"x must be (n, nrhs); got {x.shape}"
-    n, nrhs = x.shape
-    nk = scales.shape[1]
-    grid = (m // bm, L // bl)
-    tile = pl.BlockSpec((bm, bl), lambda i, l: (i, l))
-
-    operands = [scales, colpak, head]
-    in_specs = [pl.BlockSpec((1, nk), lambda i, l: (0, 0)), tile, tile]
-    if tag >= 2:
-        assert tail1 is not None, "tag>=2 reads tail1"
-        operands.append(tail1)
-        in_specs.append(tile)
-    if tag == 3:
-        assert tail2 is not None, "tag==3 reads tail2"
-        operands.append(tail2)
-        in_specs.append(tile)
-    operands.append(x.T.reshape(nrhs, n))  # columns contiguous for gathers
-    in_specs.append(pl.BlockSpec((nrhs, n), lambda i, l: (0, 0)))  # pinned
+    bm, bl = blocks
+    m = colpak.shape[0]
+    nrhs = x.shape[1]
+    operands, in_specs = segment_operands(colpak, head, tail1, tail2,
+                                          scales, tag=tag, blocks=blocks)
+    operands.append(gather_cols(colpak, x, ei_bit))   # (nrhs, M, L)
+    in_specs.append(pl.BlockSpec((nrhs, bm, bl),
+                                 lambda i, l: (jnp.int32(0), i, l)))
 
     acc = pl.pallas_call(
-        functools.partial(_BODIES[tag], ei_bit=ei_bit, k=nk, nrhs=nrhs),
+        functools.partial(_BODIES[tag], ei_bit=ei_bit, k=scales.shape[1],
+                          nrhs=nrhs),
         out_shape=jax.ShapeDtypeStruct((m, nrhs * LANE), jnp.float32),
-        grid=grid,
+        grid=(m // bm, colpak.shape[1] // bl),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((bm, nrhs * LANE), lambda i, l: (i, 0)),
+        out_specs=pl.BlockSpec((bm, nrhs * LANE),
+                               lambda i, l: (i, jnp.int32(0))),
         interpret=interpret,
     )(*operands)
     # Reduction epilogue: collapse each column's LANE per-row partials.
@@ -153,7 +148,7 @@ gse_spmm_pallas = functools.partial(
 
 
 def gse_spmm_sell_call(buckets, unperm, x, scales, *, ei_bit: int, tag: int,
-                       blocks=None, interpret: bool = True):
+                       blocks=None, interpret: bool):
     """Sliced-ELL SpMM: the multi-RHS twin of
     :func:`repro.kernels.gse_spmv.gse_spmv_sell_call` -- one tag-
     specialized ``pallas_call`` per width-bucket, same per-bucket operand

@@ -1,7 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Handles padding to tile multiples, scale-LUT precomputation, and
-interpret-mode selection (interpret=True on CPU, compiled on TPU).
+interpret-mode selection: ``interpret=None`` resolves here, and only here,
+to the interpreter off the TPU and to compiled Mosaic kernels on it.  The
+raw kernel entry points take ``interpret`` without a default.
 """
 from __future__ import annotations
 
@@ -347,7 +349,7 @@ def _gse_sell_tagmap(sell: GSESellC, x, tm: TagMap, blocks, interpret,
 
 
 def spmv_kernel_for(tag: int, ei_bit: int, blocks=None,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Tag-specialized SpMV dispatch: one cached ``pallas_call`` wrapper per
     ``(tag, ei_bit, blocks)`` (DESIGN.md §2.4).
 
@@ -356,9 +358,12 @@ def spmv_kernel_for(tag: int, ei_bit: int, blocks=None,
     callable takes exactly the operands that ``tag`` streams --
     ``(colpak, head, x, scales)`` for tag 1, ``+ tail1`` for tag 2,
     ``+ tail2`` for tag 3 -- so the tag-1/-2 kernels provably never touch
-    the tail arrays (6/8/12 bytes per nnz of HBM traffic for tags 1/2/3).
+    the tail arrays (10/12/16 bytes per padded slot of HBM traffic for
+    tags 1/2/3, gathered-x tile included).
     """
     blocks = launch_plan.resolve(blocks=blocks).blocks
+    if interpret is None:
+        interpret = _interpret_default()
     return _spmv_kernel_cached(tag, ei_bit, blocks, interpret)
 
 
@@ -385,7 +390,7 @@ def _spmv_kernel_cached(tag: int, ei_bit: int, blocks, interpret: bool):
 
 
 def spmm_kernel_for(tag: int, ei_bit: int, blocks=None,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Tag-specialized SpMM dispatch: one cached ``pallas_call`` wrapper per
     ``(tag, ei_bit, blocks)`` -- the multi-RHS twin of ``spmv_kernel_for``
     (DESIGN.md §11).
@@ -399,6 +404,8 @@ def spmm_kernel_for(tag: int, ei_bit: int, blocks=None,
     provably never touch the tail arrays.
     """
     blocks = launch_plan.resolve(blocks=blocks).blocks
+    if interpret is None:
+        interpret = _interpret_default()
     return _spmm_kernel_cached(tag, ei_bit, blocks, interpret)
 
 
@@ -434,7 +441,9 @@ def gse_spmm_ell(ell, table, x: jnp.ndarray, ei_bit: int, tag: int = 1,
     ``tag`` reads are padded, passed, and streamed -- and they are
     streamed ONCE for all ``nrhs`` columns, so the modeled per-iteration
     traffic is ``iteration_stream_bytes(a, tag, nrhs=nrhs)`` instead of
-    ``nrhs`` full SpMV passes (DESIGN.md §11).  Launch blocks resolve
+    ``nrhs`` full SpMV passes (DESIGN.md §11); each column adds its own
+    gathered-x tile (``ELLLayout.bytes_touched(tag, nrhs)``).  Launch
+    blocks resolve
     explicit ``blocks`` > ``plan`` > the (8, 128) default (§15).
     """
     if interpret is None:
@@ -533,7 +542,7 @@ def _sell_dispatch(sell_call, tag: int, ei_bit: int, blocks, interpret):
 
 
 def sell_kernel_for(tag: int, ei_bit: int, blocks=None,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """Tag-specialized SELL-C-σ SpMV dispatch: one cached jitted wrapper
     per ``(tag, ei_bit, blocks)`` -- the sliced-layout twin of
     ``spmv_kernel_for`` (DESIGN.md §12).  ``blocks=None`` resolves
@@ -547,6 +556,8 @@ def sell_kernel_for(tag: int, ei_bit: int, blocks=None,
     uniform-ELL kernel, so tag-1/-2 still provably never touch the tails.
     """
     blocks = launch_plan.resolve(blocks=blocks).blocks
+    if interpret is None:
+        interpret = _interpret_default()
     return _sell_kernel_cached(tag, ei_bit, blocks, interpret)
 
 
@@ -556,10 +567,12 @@ def _sell_kernel_cached(tag: int, ei_bit: int, blocks, interpret: bool):
 
 
 def sell_spmm_kernel_for(tag: int, ei_bit: int, blocks=None,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """Multi-RHS twin of ``sell_kernel_for``: per-width-bucket SpMM
     dispatch with the same tag-specialized bucket operand lists."""
     blocks = launch_plan.resolve(blocks=blocks).blocks
+    if interpret is None:
+        interpret = _interpret_default()
     return _sell_spmm_kernel_cached(tag, ei_bit, blocks, interpret)
 
 
@@ -664,9 +677,9 @@ def gse_spmv_ell(ell, table, x: jnp.ndarray, ei_bit: int, tag: int = 1,
 
     Dispatches to the tag-specialized kernel (``spmv_kernel_for``): only the
     segment arrays ``tag`` reads are padded, passed, and streamed.  Modeled
-    HBM traffic is bandwidth-proportional -- ``GSECSR.bytes_touched(tag)``
-    gives the per-call byte count (6/8/12 bytes per nnz for tags 1/2/3
-    vs 12 for FP64 CSR).  Launch blocks resolve explicit ``blocks`` >
+    HBM traffic is ``ell_layout(a).bytes_touched(tag)``: 10/12/16 bytes
+    per padded slot for tags 1/2/3 (segments + the gathered-x f32 tile).
+    Launch blocks resolve explicit ``blocks`` >
     ``plan`` > the (8, 128) default (DESIGN.md §15).
     """
     if interpret is None:

@@ -751,5 +751,8 @@ def main():
 if __name__ == "__main__":
     import jax
 
+    from repro import compile_cache
+
     jax.config.update("jax_enable_x64", True)
+    compile_cache.enable()
     main()

@@ -7,9 +7,11 @@ same tag-specialized operand lists the kernels stream:
   * ``flops``         -- useful work: ``2 * nnz * nrhs`` (multiply + add
                          per stored entry per column; padded slots
                          multiply exact zeros and are NOT credited);
-  * ``matrix_bytes``  -- the slot-honest matrix-stream model
+  * ``matrix_bytes``  -- the slot-honest kernel-stream model
                          (``GSECSR.bytes_touched`` / ``ELLLayout`` /
-                         ``GSESellC.bytes_touched``);
+                         ``GSESellC.bytes_touched``); for the ELL/SELL
+                         layouts it includes the gathered-x f32 tile the
+                         kernels stream per slot and column;
   * ``vector_bytes``  -- x read + y write per column;
   * ``fp64_bytes``    -- what an fp64 CSR SpMV streams for the SAME math
                          (12 B/nnz + rowptr): dividing by wall time gives
@@ -57,7 +59,7 @@ class KernelLedger:
     nnz: int
     slots: int           # padded slots streamed (== nnz for raw CSR)
     flops: int           # useful FLOPs: 2 * nnz * nrhs
-    matrix_bytes: int    # modeled matrix-stream bytes (slot-honest)
+    matrix_bytes: int    # modeled kernel-stream bytes (slot-honest)
     vector_bytes: int    # per-column x/y traffic * nrhs
     fp64_bytes: int      # fp64-CSR-equivalent matrix bytes for same math
 
@@ -90,16 +92,16 @@ def spmv_ledger(a, tag=None, layout=None, nrhs: int = 1,
     slots = int(a.nnz)
     if isinstance(a, GSESellC) or isinstance(layout, GSESellC):
         lay = a if isinstance(a, GSESellC) else layout
-        mat = lay.bytes_touched(tag)
+        mat = lay.bytes_touched(tag, nrhs=nrhs)
         slots = lay.slots
         layout_name = "sell"
     elif isinstance(layout, ELLLayout):
-        mat = layout.bytes_touched(tag)
+        mat = layout.bytes_touched(tag, nrhs=nrhs)
         slots = layout.slots
         layout_name = "ell"
     elif layout == "ell":
         lay = ell_layout(a)
-        mat = lay.bytes_touched(tag)
+        mat = lay.bytes_touched(tag, nrhs=nrhs)
         slots = lay.slots
         layout_name = "ell"
     elif layout in (None, "csr"):
@@ -131,7 +133,8 @@ def _pad(x: int, b: int) -> int:
 
 def pallas_segment_bytes(src, tag: int, blocks=DEFAULT_BLOCKS,
                          lane: int = 128) -> int:
-    """EXACT packed-segment bytes a kernel launch takes as operands.
+    """EXACT packed-segment (integer) bytes a kernel launch takes as
+    operands -- the gathered-x f32 tile is not counted here.
 
     For a ``GSECSR`` (uniform-ELL path) this is the (rows, L) pack padded
     to the (BM, BL) grid -- ``ell_pack_gsecsr`` + ``_pad2`` reproduced

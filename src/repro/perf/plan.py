@@ -7,8 +7,8 @@ a fixed precedence:
   1. explicit ``blocks=`` argument        (today's call sites, unchanged)
   2. explicit ``plan=KernelPlan(...)``    (caller-owned plan)
   3. tuned cache entry                    (``perf.tunecache``, keyed by
-                                           ``(shape-class | tag | layout |
-                                           nrhs)``)
+                                           ``(device | shape-class | tag |
+                                           layout | nrhs)``)
   4. :data:`DEFAULT_PLAN`                 (bit-identical to pre-PR-7
                                            behavior -- blocks (8, 128),
                                            lane 128, SELL C=8 / full-sort
@@ -27,10 +27,12 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
+
 from repro.perf import tunecache
 
 __all__ = ["KernelPlan", "DEFAULT_PLAN", "DEFAULT_BLOCKS", "resolve",
-           "shape_class", "plan_key", "tag_token"]
+           "shape_class", "plan_key", "tag_token", "device_token"]
 
 DEFAULT_BLOCKS = (8, 128)
 
@@ -126,9 +128,20 @@ def tag_token(tag) -> str:
     return f"tag{tag}"
 
 
+def device_token() -> str:
+    """Cache-key token of the device a plan is tuned on:
+    ``platform:device_kind`` of ``jax.devices()[0]``.  A plan swept under
+    the CPU interpreter must never resolve on a TPU (or on another TPU
+    generation)."""
+    dev = jax.devices()[0]
+    return f"{dev.platform}:{dev.device_kind}"
+
+
 def plan_key(shape_cls: str, tag, layout: str, nrhs: int = 1) -> str:
-    """Tune-cache key: ``shape-class | tag-token | layout | nrhs``."""
-    return f"{shape_cls}|{tag_token(tag)}|{layout}|nrhs{int(nrhs)}"
+    """Tune-cache key: ``device | shape-class | tag-token | layout |
+    nrhs``."""
+    return (f"{device_token()}|{shape_cls}|{tag_token(tag)}|{layout}"
+            f"|nrhs{int(nrhs)}")
 
 
 def resolve(source=None, *, tag=None, layout: str | None = None,

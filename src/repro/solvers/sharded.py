@@ -31,7 +31,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import gse
@@ -273,12 +272,12 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
         # The flight ring is replicated: every recorded column derives
         # from psum'd scalars or the replicated monitor state.
         out_specs = out_specs + (P(),)
-    fn = jax.jit(shard_map(
+    fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
         in_specs=(sharded,) * 7 + (P(),) + (sharded,) * 3 + (P(),)
         + (sharded, sharded, P(), P()),
         out_specs=out_specs,
-        check_rep=False,
+        check_vma=False,
     ))
     part.__dict__[key] = fn
     return fn

@@ -47,6 +47,7 @@ __all__ = [
 # Canonical table lives in core/precision_table.py; this is the historical
 # alias other modules import.
 _SLOT_BYTES = precision_table.SLOT_BYTES
+_GATHERED_X_BYTES = precision_table.GATHERED_X_BYTES
 
 
 @jax.tree_util.register_pytree_node_class
@@ -117,11 +118,11 @@ class GSECSR:
         return n * per + self.table.size * 4
 
     def bytes_per_nnz(self, tag: int) -> int:
-        """Modeled bytes streamed per nonzero by a tag-``tag`` SpMV.
-
-        Only the segments the tag reads count (the tag-specialized kernels
-        provably omit the rest): 2/4/8 value bytes + 4 packed-colidx bytes
-        -> 6/8/12 for tags 1/2/3, vs 12 for FP64 CSR.
+        """Modeled matrix-stream bytes per nonzero of the encoding at
+        ``tag``: only the segments the tag reads count, 2/4/8 value bytes
+        + 4 packed-colidx bytes -> 6/8/12 for tags 1/2/3, vs 12 for FP64
+        CSR.  The ELL/SELL kernels add row padding and a gathered-x f32
+        per slot (``ELLLayout``/``GSESellC.bytes_touched``).
         """
         pt = precision_table
         return pt.TAG_VALUE_BYTES[tag] + pt.COLIDX_BYTES
@@ -176,9 +177,10 @@ class ELLLayout:
     Uniform ELL pads EVERY row to the longest row's lane-aligned width, so
     one dense row on a skewed matrix multiplies the streamed slots for the
     whole matrix.  This descriptor makes that cost explicit:
-    ``bytes_touched(tag)`` charges every padded slot the kernels actually
-    stream (value segment + packed colidx per slot, plus the shared-
-    exponent table); ``padding_ratio`` is the wasted fraction.
+    ``bytes_touched(tag, nrhs)`` charges every padded slot the kernels
+    actually stream (value segment + packed colidx + one gathered-x f32
+    per right-hand side, plus the shared-exponent table);
+    ``padding_ratio`` is the wasted fraction.
     """
 
     rows: int           # padded row count the kernel grid covers
@@ -195,7 +197,7 @@ class ELLLayout:
         """Fraction of streamed slots that are padding, in [0, 1)."""
         return 1.0 - self.nnz / max(self.slots, 1)
 
-    def bytes_touched(self, tag) -> int:
+    def bytes_touched(self, tag, nrhs: int = 1) -> int:
         """``tag`` may be a :class:`~repro.core.tagmap.TagMap`: each row's
         padded slots are then charged at the ROW's group tag (the default
         group size equals the kernels' 8-row grid block, so a per-row-
@@ -204,13 +206,15 @@ class ELLLayout:
         their COLUMN's group (symmetric induced tags) are charged at the
         row tag, so it lower-bounds the blended nnz model slightly.
         A uniform map reproduces the scalar figure exactly."""
+        xg = nrhs * _GATHERED_X_BYTES
         if isinstance(tag, TagMap):
             rt = tag.row_tags(self.rows)
-            per = np.array([0] + [_SLOT_BYTES[t] for t in (1, 2, 3)],
+            per = np.array([0] + [_SLOT_BYTES[t] + xg for t in (1, 2, 3)],
                            np.int64)
             return (int(per[rt].sum()) * self.width
                     + self.table_entries * 4)
-        return self.slots * _SLOT_BYTES[tag] + self.table_entries * 4
+        return (self.slots * (_SLOT_BYTES[tag] + xg)
+                + self.table_entries * 4)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -287,10 +291,12 @@ class GSESellC:
         return 1.0 - self.nnz / max(self.slots, 1)
 
     def bytes_per_nnz(self, tag: int) -> float:
-        """EFFECTIVE bytes streamed per nonzero: padded slots amortized
-        over the real entries (the honest twin of
-        ``GSECSR.bytes_per_nnz``, which charges nnz only)."""
-        return _SLOT_BYTES[tag] * self.slots / max(self.nnz, 1)
+        """EFFECTIVE bytes the SpMV kernel streams per nonzero: padded
+        slots (segments + gathered x) amortized over the real entries (the
+        honest twin of ``GSECSR.bytes_per_nnz``, which charges the
+        encoding's nnz only)."""
+        return ((_SLOT_BYTES[tag] + _GATHERED_X_BYTES) * self.slots
+                / max(self.nnz, 1))
 
     def bucket_tags(self, tm: "TagMap") -> Tuple[int, ...]:
         """Per-width-bucket max INDUCED entry tag (max of row/column group
@@ -310,10 +316,11 @@ class GSESellC:
         np.maximum.at(tags, bidx, et.astype(np.int64))
         return tuple(int(t) for t in tags)
 
-    def bytes_touched(self, tag) -> int:
-        """Modeled HBM bytes one tag-``tag`` SpMV streams through this
-        layout: every padded slot's value segment + packed colidx, the
-        output row permutation, and the shared-exponent table.
+    def bytes_touched(self, tag, nrhs: int = 1) -> int:
+        """Modeled HBM bytes one tag-``tag`` SpMV (``nrhs`` > 1: SpMM)
+        kernel pass streams through this layout: every padded slot's value
+        segment + packed colidx + one gathered-x f32 per right-hand side,
+        the output row permutation, and the shared-exponent table.
 
         ``tag`` may be a :class:`~repro.core.tagmap.TagMap`: each width-
         bucket's slots are then charged at the bucket's MAX group tag --
@@ -321,13 +328,14 @@ class GSESellC:
         bucket never touches tails), so this blended figure is the
         PHYSICAL model, not an optimistic nnz blend (DESIGN.md §18)."""
         fixed = self.perm.shape[0] * 4 + self.table.size * 4
+        xg = nrhs * _GATHERED_X_BYTES
         if isinstance(tag, TagMap):
             return fixed + int(sum(
-                r * w * _SLOT_BYTES[t]
+                r * w * (_SLOT_BYTES[t] + xg)
                 for r, w, t in zip(self.bucket_rows, self.widths,
                                    self.bucket_tags(tag))
             ))
-        return self.slots * _SLOT_BYTES[tag] + fixed
+        return self.slots * (_SLOT_BYTES[tag] + xg) + fixed
 
     def tree_flatten(self):
         leaves = (
@@ -453,15 +461,18 @@ def iteration_stream_bytes(op, tag, precond=None, nrhs: int = 1,
 
     ``layout`` selects the padding-honest account (DESIGN.md §12): a
     ``GSESellC`` or ``ELLLayout`` charges the operator's ACTUAL padded
-    slots instead of nnz only.  Passing a ``GSESellC`` as ``op`` itself is
+    slots instead of nnz only, with the kernels' gathered-x tile per
+    active column.  Passing a ``GSESellC`` as ``op`` itself is
     equivalent -- its ``bytes_touched`` is already slot-honest.  The
     default (``layout=None``) keeps the nnz-only mode the format-
     comparison figures use, unchanged.
     """
     if nrhs < 1:
         raise ValueError(f"nrhs must be >= 1, got {nrhs}")
+    if layout is None and isinstance(op, GSESellC):
+        layout = op
     if layout is not None:
-        total = layout.bytes_touched(tag)
+        total = layout.bytes_touched(tag, nrhs=nrhs)
     else:
         total = op.bytes_touched(tag)
     if precond is not None:

@@ -162,7 +162,8 @@ def skewed_spd(n: int = 2048, dense_rows: int = 4, base_halfwidth: int = 58,
     dense rows' width (padding_ratio ~0.94 at the defaults) while
     SELL-C-σ quarantines the hubs in their own wide slice
     (padding_ratio < 0.1) -- the ``run.py --quick`` CI gate asserts the
-    gap and that tag-1 modeled bytes stay within 10% of 6 B/nnz.
+    gap and that tag-1 modeled kernel bytes stay within 10% of 10 B/nnz
+    (6 B of segments + 4 B of gathered x per slot).
     """
     rng = np.random.default_rng(seed)
     tail = np.minimum((rng.pareto(1.8, n) * tail_scale).astype(np.int64),

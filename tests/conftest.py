@@ -11,3 +11,6 @@ code passes explicit dtypes everywhere so this does not perturb LM tests.
 import jax
 
 jax.config.update("jax_enable_x64", True)
+# Tests never read or write the persistent compile cache, even where a
+# module they import (benchmarks.common) points it at the checkout.
+jax.config.update("jax_enable_compilation_cache", False)

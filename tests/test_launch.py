@@ -119,7 +119,8 @@ def test_mini_mesh_lower_compile():
     from repro.optim import AdamW
 
     cfg = configs.get_config("granite_3_2b", smoke=True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rules = configs.get_rules("granite_3_2b")
     with SH.axis_rules(rules, mesh):
         captured = {}
@@ -235,15 +236,6 @@ def test_compressed_psum_wire_u16():
     from jax.sharding import PartitionSpec as P
     from repro.distributed.wire import compressed_psum
 
-    # jax.shard_map + check_vma are newer-jax spellings; fall back to
-    # jax.experimental.shard_map / check_rep on the pinned 0.4.x.
-    import inspect
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:
-        from jax.experimental.shard_map import shard_map
-    _chk = ("check_vma" if "check_vma" in inspect.signature(shard_map).parameters
-            else "check_rep")
-
     mesh = jax.make_mesh((8,), ("pod",))
     n = 8 * 1024
     rng = np.random.default_rng(0)
@@ -252,8 +244,8 @@ def test_compressed_psum_wire_u16():
     def body(gs):
         return compressed_psum(gs[0], "pod")
 
-    sm = shard_map(body, mesh=mesh, in_specs=P("pod"), out_specs=P(None),
-                   **{_chk: False})
+    sm = jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                       out_specs=P(None), check_vma=False)
     out = jax.jit(sm)(g)
     exact = np.asarray(g).sum(0)
     rel = np.abs(np.asarray(out) - exact) / np.maximum(np.abs(exact), 1e-3)

@@ -51,23 +51,26 @@ def test_precision_table_values():
     assert pt.TAG_VALUE_BYTES == {1: 2, 2: 4, 3: 8}
     assert pt.COLIDX_BYTES == 4
     assert pt.SLOT_BYTES == {1: 6, 2: 8, 3: 12}
+    assert pt.GATHERED_X_BYTES == 4
+    assert pt.KERNEL_SLOT_BYTES == {1: 10, 2: 12, 3: 16}
     assert pt.WIRE_ENTRY_BYTES == pt.TAG_VALUE_BYTES
     assert pt.DTYPE_BYTES["u16"] == 2 and pt.DTYPE_BYTES["u32"] == 4
     assert pt.DTYPE_BYTES["f64"] == 8
 
 
 def test_bytes_touched_regression():
-    """Pinned pre-PR-7 figures on poisson2d(12): the table refactor must
-    not move a single modeled byte."""
+    """Pinned figures on poisson2d(12): the nnz-only encoding model keeps
+    its pre-PR-7 values; the ELL/SELL kernel layouts also charge the
+    gathered-x f32 tile (4 B per padded slot)."""
     a, g = _operand()
     assert (a.nnz, a.shape[0]) == (672, (144, 144)[0])
     assert [g.bytes_per_nnz(t) for t in (1, 2, 3)] == [6, 8, 12]
     assert [g.bytes_touched(t) for t in (1, 2, 3)] == [4644, 5988, 8676]
     assert a.bytes_touched() == 8644  # fp64 CSR: 12 B/nnz + rowptr
     lay = ell_layout(g)
-    assert (lay.slots, lay.bytes_touched(1)) == (18432, 110624)
+    assert (lay.slots, lay.bytes_touched(1)) == (18432, 184352)
     sell = ops.sell_pack_gsecsr(g)
-    assert (sell.slots, sell.bytes_touched(1)) == (18432, 111200)
+    assert (sell.slots, sell.bytes_touched(1)) == (18432, 184928)
     from repro.distributed.partition import WIRE_ENTRY_BYTES
     assert WIRE_ENTRY_BYTES is pt.WIRE_ENTRY_BYTES
 
@@ -192,9 +195,12 @@ def test_spmv_ledger_accounts():
     # fp64-equivalent bytes price the SAME math on fp64 CSR streams.
     led64 = ledger.spmv_ledger(a)
     assert led.fp64_bytes == led64.matrix_bytes + led64.vector_bytes
-    # SpMM streams the matrix once, vectors per column.
+    # SpMM streams the matrix segments once, vectors per column; each
+    # column also streams its own gathered-x tile through the kernel.
     led4 = ledger.spmv_ledger(g, tag=1, layout="ell", nrhs=4)
-    assert led4.matrix_bytes == led.matrix_bytes
+    assert led4.matrix_bytes == lay.bytes_touched(1, nrhs=4)
+    assert (led4.matrix_bytes - led.matrix_bytes
+            == 3 * lay.slots * pt.GATHERED_X_BYTES)
     assert led4.vector_bytes == 4 * led.vector_bytes
     assert led4.flops == 4 * led.flops
 
@@ -241,6 +247,25 @@ def test_tune_persist_and_replay(tmp_cache):
                                blocks=(8, 128))
     np.testing.assert_allclose(np.asarray(tuned), np.asarray(default),
                                rtol=2e-6, atol=0)
+
+
+def test_tune_cache_key_names_the_device(tmp_cache, monkeypatch):
+    """A plan tuned on the CPU (interpreter) misses on any other device
+    kind: the key leads with ``platform:device_kind``."""
+    from repro.perf import autotune
+
+    _, g = _operand(8)
+    autotune.get_or_tune(g, tag=1, layout="ell", iters=1, warmup=1)
+    assert launch_plan.device_token().startswith("cpu:")
+    key = next(iter(json.loads(tmp_cache.read_text())["plans"]))
+    assert key.startswith(launch_plan.device_token() + "|")
+    assert launch_plan.resolve(g, tag=1, layout="ell").source == "tuned"
+
+    monkeypatch.setattr(launch_plan, "device_token",
+                        lambda: "tpu:TPU v5 lite")
+    tunecache.clear_memory()
+    got = launch_plan.resolve(g, tag=1, layout="ell")
+    assert got is launch_plan.DEFAULT_PLAN
 
 
 def test_tune_cache_corruption_detected(tmp_cache):

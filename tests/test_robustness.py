@@ -366,17 +366,16 @@ def test_wire_checksum_sees_high_bits_of_f64():
 
 
 def _wire_harness(tag, wire):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh
     from jax.sharding import PartitionSpec as Spec
 
     from repro.distributed.wire import halo_all_gather
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("sh",))
-    return shard_map(
+    return jax.shard_map(
         lambda bnd: halo_all_gather(bnd, "sh", tag=tag, wire=wire,
                                     check=True)[1],
-        mesh=mesh, in_specs=Spec("sh"), out_specs=Spec(), check_rep=False,
+        mesh=mesh, in_specs=Spec("sh"), out_specs=Spec(), check_vma=False,
     )
 
 

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.kernels import ops, ref
 from repro.kernels.gse_spmm import gse_spmm_call, spmm_operand_names

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax import core as jcore
+from jax.extend import core as jcore
 
 from repro.core import precision as P
 from repro.core.gse import pack

@@ -42,7 +42,7 @@ def timed(fn: Callable, *args, iters: int = 2, warmup: int = 1,
     best are recorded separately in the metrics registry (DESIGN.md §16):
     ``repro_bench_compile_seconds{case=label}`` gets ``max(first - best,
     0)`` and ``repro_bench_execute_seconds{case=label}`` gets the best --
-    so BENCH_obs.json can show how much of a benchmark's wall clock was
+    so the registry can show how much of a benchmark's wall clock was
     XLA compilation rather than execution.
     """
     if label is None:

@@ -58,15 +58,6 @@ injected stall skew dominates, so the gate is not wall-clock noise).
 Forces two host CPU devices (for the sharded wire-fault case) when
 XLA_FLAGS is unset.  Composes with ``--quick`` for the trimmed CI smoke.
 
-``--obs`` runs the observability sweep (benchmarks/obs_bench.py,
-DESIGN.md section 16) and writes ``BENCH_obs.json`` plus a span capture
-``TRACE_obs.jsonl``, gating recorder-on/off bit identity across every
-solver family, flight-vs-monitor telemetry consistency, the <= 1.10
-flight+span overhead ratio, and trace schema validity.  The serve-replay
-section reports p50/p95/p99 flush latency and bytes/request straight
-from the metrics registry.  Forces two host CPU devices (for the sharded
-identity case) when XLA_FLAGS is unset.  Composes with ``--quick``.
-
 ``--adaptive`` runs the per-group precision sweep
 (benchmarks/adaptive_bench.py, DESIGN.md section 18) and writes
 ``BENCH_adaptive.json``: on the ill-conditioned and skewed generators,
@@ -443,69 +434,6 @@ def run_serve(quick: bool, out_path: pathlib.Path | None = None) -> dict:
     return payload
 
 
-def run_obs(quick: bool, out_path: pathlib.Path | None = None,
-            trace_path: pathlib.Path | None = None) -> dict:
-    """Observability sweep -> BENCH_obs.json + TRACE_obs.jsonl (§16).
-
-    Runs ``benchmarks/obs_bench.py`` under a span capture and gates:
-
-      * every recorder-on solve is BIT-IDENTICAL to recorder-off (and its
-        telemetry consistent with the solver's own monitor/guard report)
-        across CG fused/guarded, PCG, GMRES, batched, and sharded;
-      * the clean-path overhead ratio with flight + spans active is
-        <= 1.10 (the observability twin of the guard-overhead bar);
-      * the captured trace JSONL round-trips through the schema
-        validator (``repro.obs.trace.validate_jsonl``).
-
-    The JSON and trace are written BEFORE the gates raise so a failing
-    run still uploads diagnostics.
-    """
-    from benchmarks import obs_bench
-    from repro.obs import trace as OT
-
-    tpath = trace_path or (_REPO_ROOT / "TRACE_obs.jsonl")
-    with OT.capture(str(tpath)):
-        results = obs_bench.run(quick=quick)
-    print(f"wrote {tpath}", file=sys.stderr)
-    payload = {
-        "bench": "observability",
-        "schema": "bit_identity -> case -> {identical, consistent, rows, "
-                  "switch_iters}; overhead -> {obs_on_s, obs_off_s, ratio}"
-                  "; serve -> {flush_latency_s, request_bytes, stats}; "
-                  "metrics -> registry exposition (DESIGN.md section 16)",
-        "results": results,
-    }
-    _write_payload(payload, out_path or (_REPO_ROOT / "BENCH_obs.json"))
-
-    n_events = OT.validate_jsonl(str(tpath))
-    if n_events < 1:
-        raise SystemExit("obs sweep: trace capture recorded no spans")
-    for name, case in results["bit_identity"].items():
-        if "skipped" in case:
-            raise SystemExit(
-                f"obs sweep: {name} identity case skipped ({case['skipped']}"
-                "; run.py forces 2 host devices when XLA_FLAGS is unset)"
-            )
-        if not case["identical"]:
-            raise SystemExit(
-                f"obs sweep: recorder-on solve NOT bit-identical on {name}"
-            )
-        if not case["consistent"]:
-            raise SystemExit(
-                f"obs sweep: flight telemetry inconsistent with the "
-                f"solver's own report on {name}"
-            )
-    if results["overhead"]["ratio"] > 1.10:
-        raise SystemExit(
-            f"obs sweep: flight+span overhead ratio "
-            f"{results['overhead']['ratio']:.3f} > 1.10"
-        )
-    lat = results["serve"]["flush_latency_s"]
-    if not lat["count"] or lat["p99"] is None:
-        raise SystemExit("obs sweep: serve replay recorded no flush latency")
-    return payload
-
-
 def run_adaptive(quick: bool, out_path: pathlib.Path | None = None) -> dict:
     """Adaptive per-group precision sweep -> BENCH_adaptive.json (§18).
 
@@ -611,13 +539,6 @@ def main() -> None:
                          "typed shedding, and a loose absolute p99 bound "
                          "(DESIGN.md section 17; forces 2 host CPU "
                          "devices if XLA_FLAGS is unset)")
-    ap.add_argument("--obs", action="store_true",
-                    help="observability sweep -> BENCH_obs.json + "
-                         "TRACE_obs.jsonl, gating recorder-on/off bit "
-                         "identity, the <= 1.10 flight+span overhead "
-                         "ratio, and trace schema validity (DESIGN.md "
-                         "section 16; forces 2 host CPU devices if "
-                         "XLA_FLAGS is unset)")
     ap.add_argument("--adaptive", action="store_true",
                     help="adaptive per-group precision sweep -> "
                          "BENCH_adaptive.json, gating the data-driven "
@@ -641,21 +562,17 @@ def main() -> None:
                       or args.only):
         ap.error("--tune is its own sweep: drop "
                  "--robust/--shards/--nrhs/--only")
-    if args.obs and (args.robust or args.tune or args.shards > 1
-                     or args.nrhs > 1 or args.only):
-        ap.error("--obs is its own sweep: drop "
-                 "--robust/--tune/--shards/--nrhs/--only")
-    if args.serve and (args.robust or args.tune or args.obs
+    if args.serve and (args.robust or args.tune
                        or args.shards > 1 or args.nrhs > 1 or args.only):
         ap.error("--serve is its own sweep: drop "
-                 "--robust/--tune/--obs/--shards/--nrhs/--only")
-    if args.adaptive and (args.robust or args.tune or args.obs
+                 "--robust/--tune/--shards/--nrhs/--only")
+    if args.adaptive and (args.robust or args.tune
                           or args.serve or args.shards > 1
                           or args.nrhs > 1 or args.only):
         ap.error("--adaptive is its own sweep: drop "
-                 "--robust/--tune/--obs/--serve/--shards/--nrhs/--only")
+                 "--robust/--tune/--serve/--shards/--nrhs/--only")
     force_devices = args.shards if args.shards > 1 else (
-        2 if args.robust or args.obs or args.serve else 0)
+        2 if args.robust or args.serve else 0)
     if force_devices and "xla_force_host_platform_device_count" not in (
             os.environ.get("XLA_FLAGS", "")):
         # Must land before jax initializes (all jax imports are lazy,
@@ -672,9 +589,6 @@ def main() -> None:
         return
     if args.serve:
         run_serve(quick=args.quick)
-        return
-    if args.obs:
-        run_obs(quick=args.quick)
         return
     if args.robust:
         run_robust(quick=args.quick)

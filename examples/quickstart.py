@@ -292,9 +292,6 @@ def main():
     line = [ln for ln in om.REGISTRY.to_prometheus().splitlines()
             if ln.startswith("repro_pack_cache_events_total")][:2]
     print("  metrics excerpt: " + "; ".join(line))
-    # The full observability sweep (bit-identity, overhead <= 1.10x,
-    # serve latency percentiles) runs with:
-    #   PYTHONPATH=src python benchmarks/run.py --quick --obs
 
     # --- 12. resilient async serving: chunks, deadlines, breakers --------
     # (DESIGN.md section 17) The async service runs every solve in

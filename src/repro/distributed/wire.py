@@ -110,63 +110,61 @@ def halo_all_gather(bnd: jnp.ndarray, axis_name: str, *, tag,
     (``halo_wire_bytes(tagmap)``) charges each slot at its own tag.  A
     tag-3 wire ships raw floats for every slot (exact bits never
     perturb); ``slot_tags`` then only informs the byte model.
+
+    The sharded SpMV (``kernels.dist_spmv.local_matvec``) calls it under
+    the ``spmv/halo`` device scope (DESIGN.md §16).
     """
     if wire not in ("gse", "exact"):
         raise ValueError(f"unknown wire mode {wire!r}; 'gse' or 'exact'")
     tag = normalize_tags(tag)
     if isinstance(tag, TagMap):
         tag = tag.max_tag
-    # Device-side attribution (DESIGN.md §16): the scope name lands in
-    # profiler traces for every halo exchange this call site emits.
-    scope = jax.named_scope(f"halo_all_gather.{wire}.tag{tag}")
     if wire == "exact" or tag == 3:
-        with scope:
-            if not check:
-                return jax.lax.all_gather(_send("raw", bnd), axis_name)
-            ref = jax.lax.all_gather(wire_checksum(bnd), axis_name)
-            out = jax.lax.all_gather(_send("raw", bnd), axis_name)
-            got = jax.vmap(wire_checksum)(out)
-            return out, (got == ref).all()
-    with scope:
-        b32 = bnd.astype(jnp.float32)
-        table = gse.extract_shared_exponents_jnp(b32, k)
-        head, tail1 = gse.pack32_jnp(b32, table, k)
-        if slot_tags is not None and tag != 1:
-            # Per-slot wire precision: tag-1 slots drop their tail1 bits
-            # before the payload leaves, exactly as the masked HBM
-            # operand drops sub-tag tail segments.
-            keep = jnp.asarray(slot_tags) >= 2
-            if tail1.ndim > keep.ndim:
-                keep = keep[:, None]
-            tail1 = jnp.where(keep, tail1, jnp.zeros_like(tail1))
-        sums, refs = [], []
-        if check:
-            sums = [wire_checksum(head), wire_checksum(table)]
-            if tag != 1:
-                sums.append(wire_checksum(tail1))
-            refs = [jax.lax.all_gather(c, axis_name) for c in sums]
-        h_all = jax.lax.all_gather(_send("head", head), axis_name)
-        tb_all = jax.lax.all_gather(_send("table", table), axis_name)
-        if tag == 1:
-            dec = jax.vmap(
-                lambda h, tb: gse.decode32_jnp(
-                    tb, h, jnp.zeros(h.shape, jnp.uint16), k, 1, jnp.float32
-                )
-            )(h_all, tb_all)
-            gathered = (h_all, tb_all)
-        else:
-            t_all = jax.lax.all_gather(_send("tail1", tail1), axis_name)
-            dec = jax.vmap(
-                lambda h, t, tb: gse.decode32_jnp(tb, h, t, k, 2, jnp.float32)
-            )(h_all, t_all, tb_all)
-            gathered = (h_all, tb_all, t_all)
-        dec = dec.astype(bnd.dtype)
         if not check:
-            return dec
-        ok = jnp.bool_(True)
-        for buf, ref in zip(gathered, refs):
-            ok = ok & (jax.vmap(wire_checksum)(buf) == ref).all()
-        return dec, ok
+            return jax.lax.all_gather(_send("raw", bnd), axis_name)
+        ref = jax.lax.all_gather(wire_checksum(bnd), axis_name)
+        out = jax.lax.all_gather(_send("raw", bnd), axis_name)
+        got = jax.vmap(wire_checksum)(out)
+        return out, (got == ref).all()
+    b32 = bnd.astype(jnp.float32)
+    table = gse.extract_shared_exponents_jnp(b32, k)
+    head, tail1 = gse.pack32_jnp(b32, table, k)
+    if slot_tags is not None and tag != 1:
+        # Per-slot wire precision: tag-1 slots drop their tail1 bits
+        # before the payload leaves, exactly as the masked HBM
+        # operand drops sub-tag tail segments.
+        keep = jnp.asarray(slot_tags) >= 2
+        if tail1.ndim > keep.ndim:
+            keep = keep[:, None]
+        tail1 = jnp.where(keep, tail1, jnp.zeros_like(tail1))
+    sums, refs = [], []
+    if check:
+        sums = [wire_checksum(head), wire_checksum(table)]
+        if tag != 1:
+            sums.append(wire_checksum(tail1))
+        refs = [jax.lax.all_gather(c, axis_name) for c in sums]
+    h_all = jax.lax.all_gather(_send("head", head), axis_name)
+    tb_all = jax.lax.all_gather(_send("table", table), axis_name)
+    if tag == 1:
+        dec = jax.vmap(
+            lambda h, tb: gse.decode32_jnp(
+                tb, h, jnp.zeros(h.shape, jnp.uint16), k, 1, jnp.float32
+            )
+        )(h_all, tb_all)
+        gathered = (h_all, tb_all)
+    else:
+        t_all = jax.lax.all_gather(_send("tail1", tail1), axis_name)
+        dec = jax.vmap(
+            lambda h, t, tb: gse.decode32_jnp(tb, h, t, k, 2, jnp.float32)
+        )(h_all, t_all, tb_all)
+        gathered = (h_all, tb_all, t_all)
+    dec = dec.astype(bnd.dtype)
+    if not check:
+        return dec
+    ok = jnp.bool_(True)
+    for buf, ref in zip(gathered, refs):
+        ok = ok & (jax.vmap(wire_checksum)(buf) == ref).all()
+    return dec, ok
 
 
 def compressed_psum(grads: jnp.ndarray, axis_name: str, k: int = 8):

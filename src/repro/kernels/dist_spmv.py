@@ -38,9 +38,10 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.tagmap import TagMap, normalize_tags
 from repro.distributed.partition import AXIS, PartitionedGSECSR
 from repro.distributed.wire import halo_all_gather
+from repro.obs import trace as OT
 from repro.perf import plan as launch_plan
 from repro.perf.plan import KernelPlan
-from repro.sparse.spmv import _decode_gsecsr
+from repro.sparse.spmv import _decode_gsecsr, gather_scatter
 
 __all__ = ["shard_mesh", "local_matvec", "dist_spmv", "dist_spmm",
            "make_sharded_operator"]
@@ -74,35 +75,45 @@ def local_matvec(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
     exchange gathers only boundary entries; the decode is the exact
     single-device ``_decode_gsecsr`` on the shard's segments, and the
     segment sum drops the padding entries, whose row id is ``rows``
-    (bit-identical local row sums).
+    (bit-identical local row sums).  Runs under the ``spmv`` scope, the
+    boundary pack, all-gather and halo concatenation under ``halo``.
     """
-    if blk["bnd_idx"].shape[0] == 0:
-        xcat = x_sh  # single shard: every column is local
-    else:
-        # Padded boundary slots (bnd_idx == -1) are masked to ZERO before
-        # the wire pack: zeros are excluded from the shared-exponent
-        # histogram, so a shard with fewer real boundary entries than the
-        # padded width B cannot skew its wire table (the padded pool
-        # slots are never gathered by halo_idx).
-        idx = blk["bnd_idx"]
-        valid = idx >= 0
-        bnd = x_sh[jnp.clip(idx, 0, None)]
-        mask = valid if x_sh.ndim == 1 else valid[:, None]
-        bnd = jnp.where(mask, bnd, 0.0)
-        pool = halo_all_gather(bnd, AXIS, tag=tag, wire=wire, k=k,
-                               slot_tags=slot_tags)
-        flat = pool.reshape((-1,) + pool.shape[2:])
-        xcat = jnp.concatenate([x_sh, flat[blk["halo_idx"]]], axis=0)
-    val, col = _decode_gsecsr(
-        blk["colpak"], blk["head"], blk["tail1"], blk["tail2"],
-        blk["table"], ei_bit, tag, acc_dtype,
-    )
-    xg = xcat.astype(acc_dtype)[col]
-    prod = val * xg if x_sh.ndim == 1 else val[:, None] * xg
-    # Padding entries carry row id ``rows``: segment_sum drops ids out of
-    # range, so no dummy row (and no slice for XLA to fuse into the
-    # consumer's dot, which would change its summation order).
-    return jax.ops.segment_sum(prod, blk["row_ids"], num_segments=rows)
+    with OT.scope(OT.SPMV):
+        if blk["bnd_idx"].shape[0] == 0:
+            xcat = x_sh  # single shard: every column is local
+        else:
+            with OT.scope(OT.HALO):
+                xcat = _halo_extend(blk, x_sh, tag=tag, wire=wire, k=k,
+                                    slot_tags=slot_tags)
+        with OT.scope(OT.DECODE):
+            val, col = _decode_gsecsr(
+                blk["colpak"], blk["head"], blk["tail1"], blk["tail2"],
+                blk["table"], ei_bit, tag, acc_dtype,
+            )
+        # Padding entries carry row id ``rows``: segment_sum drops ids out
+        # of range, so no dummy row (and no slice for XLA to fuse into the
+        # consumer's dot, which would change its summation order).
+        return gather_scatter(val, col, xcat, blk["row_ids"], rows,
+                              acc_dtype)
+
+
+def _halo_extend(blk, x_sh, *, tag, wire, k, slot_tags):
+    """This shard's ``x`` followed by the halo entries its columns read
+    from other shards."""
+    # Padded boundary slots (bnd_idx == -1) are masked to ZERO before the
+    # wire pack: zeros are excluded from the shared-exponent histogram, so
+    # a shard with fewer real boundary entries than the padded width B
+    # cannot skew its wire table (the padded pool slots are never gathered
+    # by halo_idx).
+    idx = blk["bnd_idx"]
+    valid = idx >= 0
+    bnd = x_sh[jnp.clip(idx, 0, None)]
+    mask = valid if x_sh.ndim == 1 else valid[:, None]
+    bnd = jnp.where(mask, bnd, 0.0)
+    pool = halo_all_gather(bnd, AXIS, tag=tag, wire=wire, k=k,
+                           slot_tags=slot_tags)
+    flat = pool.reshape((-1,) + pool.shape[2:])
+    return jnp.concatenate([x_sh, flat[blk["halo_idx"]]], axis=0)
 
 
 def _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table):

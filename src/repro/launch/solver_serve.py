@@ -184,7 +184,7 @@ class SolverService:
         # Registry-backed telemetry (DESIGN.md §16).  ``stats`` keeps the
         # historical dict shape; the gauge tracks the live queue depth and
         # the histograms feed the p50/p95/p99 flush-latency and
-        # bytes-per-request numbers ``run.py --obs`` reports.
+        # bytes-per-request numbers of the registry exposition.
         self.service_id = str(next(_SERVICE_IDS))
         const = {"service": self.service_id}
         self.stats = OM.stats_view(

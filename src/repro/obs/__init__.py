@@ -6,8 +6,9 @@ Three parts (DESIGN.md section 16):
   ``PACK_STATS``, ``TUNE_STATS`` and ``SolverService`` stats, with
   Prometheus-text and JSON exposition.
 - ``obs.trace``: nested wall-clock spans with byte/flop annotations written
-  as schema-versioned JSONL, plus ``jax.named_scope`` names on kernel call
-  sites so device profiles carry the same vocabulary.
+  as schema-versioned JSONL, plus the device scope vocabulary
+  (``spmv/scatter``, ``krylov/dot``, ...) the solve path's stages carry as
+  ``jax.named_scope`` names, so device profiles name each op's stage.
 - ``obs.flight``: a fixed-size device-side ring buffer carried through the
   solver ``lax.while_loop`` recording one row per iteration with zero
   host syncs; decoded post-solve into a ``FlightLog``.

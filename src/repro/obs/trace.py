@@ -11,8 +11,9 @@ consumers can reject what they don't understand, and
 When no tracer is installed, :func:`span` is a near-zero-cost no-op, so
 instrumented call sites cost nothing on the clean path.  Spans that wrap
 code inside a jit trace measure trace/compile-time cost (they run once per
-compilation); device-side time is attributed through the
-``jax.named_scope`` names the kernels carry (see DESIGN.md section 16).
+compilation); device-side time is attributed through the device scope
+vocabulary below, which the solve path's stages carry as
+``jax.named_scope`` names (see DESIGN.md section 16).
 """
 
 from __future__ import annotations
@@ -31,6 +32,7 @@ __all__ = [
     "current",
     "event",
     "install",
+    "scope",
     "span",
     "uninstall",
     "validate_event",
@@ -140,6 +142,51 @@ class Tracer:
             for rec in events:
                 fh.write(json.dumps(rec, sort_keys=False) + "\n")
         return len(events)
+
+
+# -- device scope vocabulary --------------------------------------------
+#
+# Every device stage of the solve path runs under one of these
+# ``jax.named_scope`` paths, so each op XLA emits for it carries the path
+# in its ``op_name`` metadata (``.../spmv/scatter/scatter-add``) whatever
+# the compiler fuses or numbers.  Scopes are HLO metadata only: they change
+# no arithmetic and no compiled program.
+SPMV = "spmv"          # one operator application
+DECODE = "decode"      #   GSE-SEM segments to acc_dtype values
+GATHER = "gather"      #   x[col] and the product with the values
+SCATTER = "scatter"    #   the segment_sum over row ids
+HALO = "halo"          #   boundary pack and all-gather (sharded only)
+KRYLOV = "krylov"      # the Krylov vector work
+DOT = "dot"            #   inner products, psum included
+UPDATE = "update"      #   axpys, xpby and their scalars
+PRECOND = "precond"    # the preconditioner apply
+MONITOR = "monitor"    # monitor record/step, switch log, guards, flight
+
+SCOPES = {
+    SPMV: (DECODE, GATHER, SCATTER, HALO),
+    KRYLOV: (DOT, UPDATE),
+    PRECOND: (),
+    MONITOR: (),
+}
+_SCOPE_NAMES = frozenset(SCOPES).union(*SCOPES.values())
+
+
+@contextlib.contextmanager
+def scope(*path: str):
+    """Enter the nested ``jax.named_scope`` of each name in ``path``.
+
+    Names come from :data:`SCOPES`: a stage's own path
+    (``scope(KRYLOV, DOT)``), or a child alone inside a scope its caller
+    opened (``scope(DECODE)`` under ``scope(SPMV)``).
+    """
+    import jax
+
+    with contextlib.ExitStack() as stack:
+        for name in path:
+            if name not in _SCOPE_NAMES:
+                raise ValueError(f"{name!r} is not a device scope name")
+            stack.enter_context(jax.named_scope(name))
+        yield
 
 
 # -- module-level installed tracer --------------------------------------

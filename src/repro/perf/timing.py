@@ -43,8 +43,8 @@ def measure_split(fn, *args, iters: int = 10, warmup: int = 2, **kwargs):
     executable.  ``first - best`` is therefore a cheap compile-time
     estimate with no profiler dependency (clamp at 0: on a cache hit the
     first call can land inside run-to-run noise).  Observability callers
-    (``benchmarks.common.timed``, ``run.py --obs``) record both sides as
-    registry metrics (DESIGN.md §16).
+    (``benchmarks.common.timed``) record both sides as registry metrics
+    (DESIGN.md §16).
     """
     if iters < 1:
         raise ValueError(f"iters must be >= 1, got {iters}")

@@ -37,6 +37,7 @@ from repro.robustness.guards import (
     run_with_recovery,
     run_with_recovery_map,
 )
+from repro.solvers.fused_cg import kdot
 from repro.sparse.csr import GSECSR, GSESellC
 
 __all__ = ["CGResult", "solve_cg", "solve_pcg"]
@@ -135,6 +136,10 @@ class CGResult(NamedTuple):
     # Observability (DESIGN.md §16): raw flight-recorder ring state (None
     # when recording is off); decode with ``obs.flight.FlightLog.from_state``.
     flight: object = None
+    # Iterations the final correction's resumed segment ran (0 where the
+    # true residual already met ``tol``; None without final_correction).
+    # Included in ``iters``.
+    correction_iters: object = None
 
 
 def _guarded_init(state, relres0, guards):
@@ -230,12 +235,12 @@ def _solve_cg(apply_a, b, x0, tol, maxiter, params: P.MonitorParams,
         state = resume
     else:
         mon = P.init(params, dtype=dtype, tag=init_tag)
-        r0 = b - apply_a(x0, mon.tag)
+        r0 = _residual(b, apply_a(x0, mon.tag))
         state = dict(
             x=x0,
             r=r0,
             p=r0,
-            rs=jnp.vdot(r0, r0),
+            rs=kdot(r0, r0),
             it=jnp.int32(0),
             mon=mon,
             switches=jnp.full((2,), -1, jnp.int32),
@@ -254,23 +259,27 @@ def _solve_cg(apply_a, b, x0, tol, maxiter, params: P.MonitorParams,
     def body(s):
         tag = s["mon"].tag
         ap = apply_a(s["p"], tag)
-        denom = jnp.vdot(s["p"], ap)
-        alpha = s["rs"] / jnp.where(denom == 0, 1.0, denom)
-        x = s["x"] + alpha * s["p"]
-        r = s["r"] - alpha * ap
-        rs_new = jnp.vdot(r, r)
-        mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rs_new)) / bnorm)
-        mon2 = P.update_tag(mon, params)
-        switches = _record_switch(s["switches"], mon, mon2, s["it"])
-        beta = rs_new / jnp.where(s["rs"] == 0, 1.0, s["rs"])
-        p = r + beta * s["p"]
+        denom = kdot(s["p"], ap)
+        with OT.scope(OT.KRYLOV, OT.UPDATE):
+            alpha = s["rs"] / jnp.where(denom == 0, 1.0, denom)
+            x = s["x"] + alpha * s["p"]
+            r = s["r"] - alpha * ap
+        rs_new = kdot(r, r)
+        with OT.scope(OT.MONITOR):
+            mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rs_new)) / bnorm)
+            mon2 = P.update_tag(mon, params)
+            switches = _record_switch(s["switches"], mon, mon2, s["it"])
+        with OT.scope(OT.KRYLOV, OT.UPDATE):
+            beta = rs_new / jnp.where(s["rs"] == 0, 1.0, s["rs"])
+            p = r + beta * s["p"]
         out = dict(
             x=x, r=r, p=p, rs=rs_new, it=s["it"] + 1, mon=mon2, switches=switches
         )
-        out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
-                            guards, denom=denom)
-        return _flight_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
-                            flight, a0=alpha, a1=beta, a2=denom)
+        with OT.scope(OT.MONITOR):
+            out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
+                                guards, denom=denom)
+            return _flight_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
+                                flight, a0=alpha, a1=beta, a2=denom)
 
     out = jax.lax.while_loop(cond, body, state)
     res, ckpt = _guarded_result(
@@ -305,6 +314,13 @@ def _record_switch(switches, mon, mon2, it):
     return jnp.where(stepped, switches.at[slot].set(it + 1), switches)
 
 
+def _residual(b, ax):
+    """``b - A x`` of the loop's start, under the ``krylov/update`` scope
+    (the SpMV that made ``ax`` carries its own)."""
+    with OT.scope(OT.KRYLOV, OT.UPDATE):
+        return b - ax
+
+
 @partial(jax.jit, static_argnames=("maxiter", "params", "init_tag", "guards",
                                    "flight", "return_ckpt", "return_state"))
 def _solve_cg_fused(a, b, x0, tol, maxiter, params: P.MonitorParams,
@@ -337,12 +353,12 @@ def _solve_cg_fused(a, b, x0, tol, maxiter, params: P.MonitorParams,
         state = resume
     else:
         mon = P.init(params, dtype=dtype, tag=init_tag)
-        r0 = b - gse_matvec(a, x0, mon.tag)
+        r0 = _residual(b, gse_matvec(a, x0, mon.tag))
         state = dict(
             x=x0,
             r=r0,
             p=r0,
-            rs=jnp.vdot(r0, r0),
+            rs=kdot(r0, r0),
             it=jnp.int32(0),
             mon=mon,
             switches=jnp.full((2,), -1, jnp.int32),
@@ -366,21 +382,24 @@ def _solve_cg_fused(a, b, x0, tol, maxiter, params: P.MonitorParams,
             x, r, p, rs_new, denom = fused_cg_step_g(
                 a, s["x"], s["r"], s["p"], s["rs"], s["mon"].tag
             )
-        mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rs_new)) / bnorm)
-        mon2 = P.update_tag(mon, params)
-        switches = _record_switch(s["switches"], mon, mon2, s["it"])
-        out = dict(
-            x=x, r=r, p=p, rs=rs_new, it=s["it"] + 1, mon=mon2, switches=switches
-        )
-        out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
-                            guards, denom=denom)
-        if flight is not None:
-            # Observation-only recomputation of the step scalars from the
-            # surfaced curvature (the fused step consumed them internally).
-            alpha = s["rs"] / jnp.where(denom == 0, 1.0, denom)
-            beta = rs_new / jnp.where(s["rs"] == 0, 1.0, s["rs"])
-            out = _flight_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
-                               flight, a0=alpha, a1=beta, a2=denom)
+        with OT.scope(OT.MONITOR):
+            mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rs_new)) / bnorm)
+            mon2 = P.update_tag(mon, params)
+            switches = _record_switch(s["switches"], mon, mon2, s["it"])
+            out = dict(
+                x=x, r=r, p=p, rs=rs_new, it=s["it"] + 1, mon=mon2,
+                switches=switches
+            )
+            out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
+                                guards, denom=denom)
+            if flight is not None:
+                # Observation-only recomputation of the step scalars from
+                # the surfaced curvature (the fused step consumed them
+                # internally).
+                alpha = s["rs"] / jnp.where(denom == 0, 1.0, denom)
+                beta = rs_new / jnp.where(s["rs"] == 0, 1.0, s["rs"])
+                out = _flight_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
+                                   flight, a0=alpha, a1=beta, a2=denom)
         return out
 
     out = jax.lax.while_loop(cond, body, state)
@@ -428,14 +447,15 @@ def _solve_pcg(apply_a, apply_m, b, x0, tol, maxiter, params: P.MonitorParams,
         state = resume
     else:
         mon = P.init(params, dtype=dtype, tag=init_tag)
-        r0 = b - apply_a(x0, mon.tag)
-        z0 = apply_m(r0, mon.tag)
+        r0 = _residual(b, apply_a(x0, mon.tag))
+        with OT.scope(OT.PRECOND):
+            z0 = apply_m(r0, mon.tag)
         state = dict(
             x=x0,
             r=r0,
             p=z0,
-            rz=jnp.vdot(r0, z0),
-            rr=jnp.vdot(r0, r0),
+            rz=kdot(r0, z0),
+            rr=kdot(r0, r0),
             it=jnp.int32(0),
             mon=mon,
             switches=jnp.full((2,), -1, jnp.int32),
@@ -452,28 +472,34 @@ def _solve_pcg(apply_a, apply_m, b, x0, tol, maxiter, params: P.MonitorParams,
     def body(s):
         tag = s["mon"].tag
         ap = apply_a(s["p"], tag)
-        denom = jnp.vdot(s["p"], ap)
-        alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
-        x = s["x"] + alpha * s["p"]
-        r = s["r"] - alpha * ap
-        z = apply_m(r, tag)
-        rz_new = jnp.vdot(r, z)
-        rr_new = jnp.vdot(r, r)
-        mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rr_new)) / bnorm)
-        mon2 = P.update_tag(mon, params)
-        switches = _record_switch(s["switches"], mon, mon2, s["it"])
-        beta = rz_new / jnp.where(s["rz"] == 0, 1.0, s["rz"])
-        p = z + beta * s["p"]
+        denom = kdot(s["p"], ap)
+        with OT.scope(OT.KRYLOV, OT.UPDATE):
+            alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
+            x = s["x"] + alpha * s["p"]
+            r = s["r"] - alpha * ap
+        with OT.scope(OT.PRECOND):
+            z = apply_m(r, tag)
+        rz_new = kdot(r, z)
+        rr_new = kdot(r, r)
+        with OT.scope(OT.MONITOR):
+            mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rr_new)) / bnorm)
+            mon2 = P.update_tag(mon, params)
+            switches = _record_switch(s["switches"], mon, mon2, s["it"])
+        with OT.scope(OT.KRYLOV, OT.UPDATE):
+            beta = rz_new / jnp.where(s["rz"] == 0, 1.0, s["rz"])
+            p = z + beta * s["p"]
         out = dict(
             x=x, r=r, p=p, rz=rz_new, rr=rr_new, it=s["it"] + 1, mon=mon2,
             switches=switches,
         )
-        # z.r < 0 breaks PCG's M-SPD contract: an extra breakdown predicate.
-        out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
-                            guards, denom=denom, breakdown=rz_new < 0,
-                            finite_aux=(rz_new,))
-        return _flight_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
-                            flight, a0=alpha, a1=beta, a2=denom)
+        with OT.scope(OT.MONITOR):
+            # z.r < 0 breaks PCG's M-SPD contract: an extra breakdown
+            # predicate.
+            out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
+                                guards, denom=denom, breakdown=rz_new < 0,
+                                finite_aux=(rz_new,))
+            return _flight_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
+                                flight, a0=alpha, a1=beta, a2=denom)
 
     out = jax.lax.while_loop(cond, body, state)
     res, ckpt = _guarded_result(
@@ -522,14 +548,15 @@ def _solve_pcg_fused(a, m, b, x0, tol, maxiter, params: P.MonitorParams,
         state = resume
     else:
         mon = P.init(params, dtype=dtype, tag=init_tag)
-        r0 = b - gse_matvec(a, x0, mon.tag)
-        z0 = m.apply(r0, mon.tag)
+        r0 = _residual(b, gse_matvec(a, x0, mon.tag))
+        with OT.scope(OT.PRECOND):
+            z0 = m.apply(r0, mon.tag)
         state = dict(
             x=x0,
             r=r0,
             p=z0,
-            rz=jnp.vdot(r0, z0),
-            rr=jnp.vdot(r0, r0),
+            rz=kdot(r0, z0),
+            rr=kdot(r0, r0),
             it=jnp.int32(0),
             mon=mon,
             switches=jnp.full((2,), -1, jnp.int32),
@@ -553,21 +580,22 @@ def _solve_pcg_fused(a, m, b, x0, tol, maxiter, params: P.MonitorParams,
             x, r, p, rz_new, rr_new, denom = fused_pcg_step_g(
                 a, m, s["x"], s["r"], s["p"], s["rz"], s["mon"].tag
             )
-        mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rr_new)) / bnorm)
-        mon2 = P.update_tag(mon, params)
-        switches = _record_switch(s["switches"], mon, mon2, s["it"])
-        out = dict(
-            x=x, r=r, p=p, rz=rz_new, rr=rr_new, it=s["it"] + 1, mon=mon2,
-            switches=switches,
-        )
-        out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
-                            guards, denom=denom, breakdown=rz_new < 0,
-                            finite_aux=(rz_new,))
-        if flight is not None:
-            alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
-            beta = rz_new / jnp.where(s["rz"] == 0, 1.0, s["rz"])
-            out = _flight_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
-                               flight, a0=alpha, a1=beta, a2=denom)
+        with OT.scope(OT.MONITOR):
+            mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rr_new)) / bnorm)
+            mon2 = P.update_tag(mon, params)
+            switches = _record_switch(s["switches"], mon, mon2, s["it"])
+            out = dict(
+                x=x, r=r, p=p, rz=rz_new, rr=rr_new, it=s["it"] + 1, mon=mon2,
+                switches=switches,
+            )
+            out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
+                                guards, denom=denom, breakdown=rz_new < 0,
+                                finite_aux=(rz_new,))
+            if flight is not None:
+                alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
+                beta = rz_new / jnp.where(s["rz"] == 0, 1.0, s["rz"])
+                out = _flight_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
+                                   flight, a0=alpha, a1=beta, a2=denom)
         return out
 
     out = jax.lax.while_loop(cond, body, state)
@@ -597,27 +625,41 @@ def _finish_with_correction(res, b, tol, maxiter, apply3, resume):
     optimistic, resume at full precision.  The resume budget is clamped to
     >= 1 -- the first solve may have exhausted ``maxiter`` exactly at
     tolerance, and a non-positive budget would run zero iterations and
-    report a stale result."""
-    bnorm = jnp.linalg.norm(b)
-    bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
-    true_rel = jnp.linalg.norm(b - apply3(res.x)) / bnorm
-    if not (bool(res.converged) and float(true_rel) > tol):
-        return res
-    res2 = resume(res.x, max(maxiter - int(res.iters), 1))
-    return type(res)(
-        x=res2.x,
-        iters=res.iters + res2.iters,
-        relres=res2.relres,
-        tag=res2.tag,
-        switch_iters=res.switch_iters,
-        converged=res2.converged,
-        health=res2.health,
-        trip_iter=jnp.where(res2.trip_iter >= 0,
-                            res2.trip_iter + res.iters, res.trip_iter),
-        # The resumed segment's recording (its `it` restarts at 0); fall
-        # back to the first run's when the resume didn't record.
-        flight=res2.flight if res2.flight is not None else res.flight,
-    )
+    report a stale result.
+
+    Runs under the host span ``solve.correction`` (annotated with the
+    ``true_relres`` the check read), with the children
+    ``solve.correction.check`` and, when it resumes,
+    ``solve.correction.resume``.  The resumed segment's iteration count
+    lands on the result as ``correction_iters`` (a device scalar; 0 when
+    the check passes), so no wait is added for it."""
+    with OT.span("solve.correction"):
+        with OT.span("solve.correction.check"):
+            bnorm = jnp.linalg.norm(b)
+            bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
+            true_rel = jnp.linalg.norm(b - apply3(res.x)) / bnorm
+            rel = float(true_rel) if bool(res.converged) else None
+        if rel is not None:
+            OT.annotate(true_relres=rel)
+        if rel is None or not rel > tol:
+            return res._replace(correction_iters=jnp.zeros_like(res.iters))
+        with OT.span("solve.correction.resume"):
+            res2 = resume(res.x, max(maxiter - int(res.iters), 1))
+        return type(res)(
+            x=res2.x,
+            iters=res.iters + res2.iters,
+            relres=res2.relres,
+            tag=res2.tag,
+            switch_iters=res.switch_iters,
+            converged=res2.converged,
+            health=res2.health,
+            trip_iter=jnp.where(res2.trip_iter >= 0,
+                                res2.trip_iter + res.iters, res.trip_iter),
+            # The resumed segment's recording (its `it` restarts at 0); fall
+            # back to the first run's when the resume didn't record.
+            flight=res2.flight if res2.flight is not None else res.flight,
+            correction_iters=res2.iters,
+        )
 
 
 def _pin_params(params: P.MonitorParams, max_tag: int) -> P.MonitorParams:
@@ -799,20 +841,20 @@ def solve_pcg(
             res = run_with_recovery_map(
                 run, x0, maxiter, tm,
                 recover=recover and guards is not None)
-        if not final_correction:
-            return _restore_shape(res, orig_shape)
-        apply3_op = _gsecsr_operator(apply_a)
+            if not final_correction:
+                return _restore_shape(res, orig_shape)
+            apply3_op = _gsecsr_operator(apply_a)
 
-        def apply3(v):
-            return apply3_op(v, jnp.int32(3))
+            def apply3(v):
+                return apply3_op(v, jnp.int32(3))
 
-        def resume(xr, budget):
-            return run(xr, budget, 3)[0]
+            def resume(xr, budget):
+                return run(xr, budget, 3)[0]
 
-        return _restore_shape(
-            _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-            orig_shape,
-        )
+            return _restore_shape(
+                _finish_with_correction(res, b, tol, maxiter, apply3, resume),
+                orig_shape,
+            )
 
     if fused:
         def run(x_start, budget, tag):
@@ -834,20 +876,20 @@ def solve_pcg(
                  init_tag=init_tag, fused=fused):
         res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
                                 recover=recover and guards is not None)
-    if not final_correction:
-        return _restore_shape(res, orig_shape)
-    apply3_op = _gsecsr_operator(apply_a) if fused else apply_a
+        if not final_correction:
+            return _restore_shape(res, orig_shape)
+        apply3_op = _gsecsr_operator(apply_a) if fused else apply_a
 
-    def apply3(v):
-        return apply3_op(v, jnp.int32(3))
+        def apply3(v):
+            return apply3_op(v, jnp.int32(3))
 
-    def resume(xr, budget):
-        return run(xr, budget, 3)[0]
+        def resume(xr, budget):
+            return run(xr, budget, 3)[0]
 
-    return _restore_shape(
-        _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-        orig_shape,
-    )
+        return _restore_shape(
+            _finish_with_correction(res, b, tol, maxiter, apply3, resume),
+            orig_shape,
+        )
 
 
 def solve_cg(
@@ -931,9 +973,33 @@ def solve_cg(
             res = run_with_recovery_map(
                 run, x0, maxiter, tm,
                 recover=recover and guards is not None)
+            if not final_correction:
+                return _restore_shape(res, orig_shape)
+            apply3_op = _gsecsr_operator(apply_a)
+
+            def apply3(v):
+                return apply3_op(v, jnp.int32(3))
+
+            def resume(xr, budget):
+                return run(xr, budget, 3)[0]
+
+            return _restore_shape(
+                _finish_with_correction(res, b, tol, maxiter, apply3, resume),
+                orig_shape,
+            )
+
+    def run(x_start, budget, tag):
+        return solve(apply_a, b, x_start, tol_, budget, params,
+                     init_tag=tag, guards=guards, flight=flight,
+                     return_ckpt=True)
+
+    with OT.span("solve.cg", n=int(b.shape[0]), tol=float(tol),
+                 init_tag=init_tag, fused=fused):
+        res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
+                                recover=recover and guards is not None)
         if not final_correction:
             return _restore_shape(res, orig_shape)
-        apply3_op = _gsecsr_operator(apply_a)
+        apply3_op = _gsecsr_operator(apply_a) if fused else apply_a
 
         def apply3(v):
             return apply3_op(v, jnp.int32(3))
@@ -945,27 +1011,3 @@ def solve_cg(
             _finish_with_correction(res, b, tol, maxiter, apply3, resume),
             orig_shape,
         )
-
-    def run(x_start, budget, tag):
-        return solve(apply_a, b, x_start, tol_, budget, params,
-                     init_tag=tag, guards=guards, flight=flight,
-                     return_ckpt=True)
-
-    with OT.span("solve.cg", n=int(b.shape[0]), tol=float(tol),
-                 init_tag=init_tag, fused=fused):
-        res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
-                                recover=recover and guards is not None)
-    if not final_correction:
-        return _restore_shape(res, orig_shape)
-    apply3_op = _gsecsr_operator(apply_a) if fused else apply_a
-
-    def apply3(v):
-        return apply3_op(v, jnp.int32(3))
-
-    def resume(xr, budget):
-        return run(xr, budget, 3)[0]
-
-    return _restore_shape(
-        _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-        orig_shape,
-    )

@@ -20,6 +20,10 @@ The arithmetic is EXACTLY the sequence of the unfused ``solve_cg`` body
 (same ops, same order, same ``acc_dtype``), so fused and unfused runs
 produce bit-identical iterate trajectories -- asserted by
 tests/test_spmv_pipeline.py.
+
+Each stage runs under its device scope (``obs.trace.SCOPES``): the SpMV
+under ``spmv``, the dots and axpys under ``krylov/dot`` and
+``krylov/update``, the preconditioner under ``precond``.
 """
 from __future__ import annotations
 
@@ -28,10 +32,17 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from repro.sparse.spmv import decode_operand
+from repro.obs import trace as OT
+from repro.sparse.spmv import spmv_operand
 
 __all__ = ["fused_cg_step", "fused_cg_step_g", "fused_pcg_step",
-           "fused_pcg_step_g", "gse_matvec"]
+           "fused_pcg_step_g", "gse_matvec", "kdot"]
+
+
+def kdot(u, v):
+    """``vdot(u, v)`` under the ``krylov/dot`` scope."""
+    with OT.scope(OT.KRYLOV, OT.DOT):
+        return jnp.vdot(u, v)
 
 
 def _step_at_tag(a, x, r, p, rs, *, tag: int, acc_dtype, with_denom=False):
@@ -44,17 +55,16 @@ def _step_at_tag(a, x, r, p, rs, *, tag: int, acc_dtype, with_denom=False):
     the matvec and (via ``ap``) the direction dot; everything downstream of
     the decode fuses into the same program under jit.
     """
-    val, col = decode_operand(a, tag, acc_dtype)
-    ap = jax.ops.segment_sum(
-        val * p.astype(acc_dtype)[col], a.row_ids, num_segments=a.shape[0]
-    )
-    denom = jnp.vdot(p, ap)                     # same sweep as the matvec
-    alpha = rs / jnp.where(denom == 0, 1.0, denom)
-    x2 = x + alpha * p
-    r2 = r - alpha * ap
-    rs2 = jnp.vdot(r2, r2)                      # residual norm, same sweep
-    beta = rs2 / jnp.where(rs == 0, 1.0, rs)
-    p2 = r2 + beta * p
+    ap = spmv_operand(a, p, tag, acc_dtype)
+    denom = kdot(p, ap)                         # same sweep as the matvec
+    with OT.scope(OT.KRYLOV, OT.UPDATE):
+        alpha = rs / jnp.where(denom == 0, 1.0, denom)
+        x2 = x + alpha * p
+        r2 = r - alpha * ap
+    rs2 = kdot(r2, r2)                          # residual norm, same sweep
+    with OT.scope(OT.KRYLOV, OT.UPDATE):
+        beta = rs2 / jnp.where(rs == 0, 1.0, rs)
+        p2 = r2 + beta * p
     if with_denom:
         return x2, r2, p2, rs2, denom
     return x2, r2, p2, rs2
@@ -112,19 +122,19 @@ def _pcg_step_at_tag(a, m, x, r, p, rz, *, tag: int, acc_dtype,
     ``decode_operand``).  The arithmetic is the exact op sequence of the
     unfused ``_solve_pcg`` body -- bit-identical trajectories.
     """
-    val, col = decode_operand(a, tag, acc_dtype)
-    ap = jax.ops.segment_sum(
-        val * p.astype(acc_dtype)[col], a.row_ids, num_segments=a.shape[0]
-    )
-    denom = jnp.vdot(p, ap)
-    alpha = rz / jnp.where(denom == 0, 1.0, denom)
-    x2 = x + alpha * p
-    r2 = r - alpha * ap
-    z2 = m.apply_at(r2, tag, acc_dtype)        # same tag as the SpMV
-    rz2 = jnp.vdot(r2, z2)
-    rr2 = jnp.vdot(r2, r2)                     # monitor sees sqrt(rr)/||b||
-    beta = rz2 / jnp.where(rz == 0, 1.0, rz)
-    p2 = z2 + beta * p
+    ap = spmv_operand(a, p, tag, acc_dtype)
+    denom = kdot(p, ap)
+    with OT.scope(OT.KRYLOV, OT.UPDATE):
+        alpha = rz / jnp.where(denom == 0, 1.0, denom)
+        x2 = x + alpha * p
+        r2 = r - alpha * ap
+    with OT.scope(OT.PRECOND):
+        z2 = m.apply_at(r2, tag, acc_dtype)    # same tag as the SpMV
+    rz2 = kdot(r2, z2)
+    rr2 = kdot(r2, r2)                         # monitor sees sqrt(rr)/||b||
+    with OT.scope(OT.KRYLOV, OT.UPDATE):
+        beta = rz2 / jnp.where(rz == 0, 1.0, rz)
+        p2 = z2 + beta * p
     if with_denom:
         return x2, r2, p2, rz2, rr2, denom
     return x2, r2, p2, rz2, rr2

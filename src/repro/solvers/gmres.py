@@ -46,6 +46,10 @@ class GMRESResult(NamedTuple):
     # when recording is off); rows are inner iterations with a0 = the
     # Givens magnitude d, a1 = the Arnoldi subdiagonal H[j+1, j].
     flight: object = None
+    # Inner iterations the final correction's resumed segment ran (0
+    # where the true residual already met ``tol``; None without
+    # final_correction).  Included in ``iters``.
+    correction_iters: object = None
 
 
 def _givens(a, b):
@@ -328,17 +332,17 @@ def solve_gmres(
                  restart=restart, init_tag=init_tag):
         res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
                                 recover=recover and guards is not None)
-    if not final_correction:
-        return _restore_shape(res, orig_shape)
-    from repro.solvers.cg import _finish_with_correction
+        if not final_correction:
+            return _restore_shape(res, orig_shape)
+        from repro.solvers.cg import _finish_with_correction
 
-    def apply3(v):
-        return apply_a(v, jnp.int32(3))
+        def apply3(v):
+            return apply_a(v, jnp.int32(3))
 
-    def resume(xr, budget):
-        return run(xr, budget, 3)[0]
+        def resume(xr, budget):
+            return run(xr, budget, 3)[0]
 
-    return _restore_shape(
-        _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-        orig_shape,
-    )
+        return _restore_shape(
+            _finish_with_correction(res, b, tol, maxiter, apply3, resume),
+            orig_shape,
+        )

@@ -59,6 +59,7 @@ from repro.solvers.cg import (
     _guarded_init,
     _normalize_b_x0,
     _record_switch,
+    _residual,
     _restore_shape,
 )
 
@@ -67,8 +68,10 @@ __all__ = ["solve_cg_sharded", "solve_pcg_sharded"]
 
 def _pdot(u, v):
     """Distributed dot: per-shard partial + psum (the ONE place sharded
-    trajectories differ from single-device -- summation order)."""
-    return jax.lax.psum(jnp.vdot(u, v), AXIS)
+    trajectories differ from single-device -- summation order), under
+    the ``krylov/dot`` scope."""
+    with OT.scope(OT.KRYLOV, OT.DOT):
+        return jax.lax.psum(jnp.vdot(u, v), AXIS)
 
 
 def _pad_to(x, n_padded):
@@ -144,7 +147,7 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
             return jnp.sqrt(jnp.abs(rs)) / bnorm
 
         if kind == "cg":
-            r0 = b - matvec(x0, mon.tag)
+            r0 = _residual(b, matvec(x0, mon.tag))
             state = dict(x=x0, r=r0, p=r0, rs=_pdot(r0, r0),
                          it=jnp.int32(0), mon=mon,
                          switches=jnp.full((2,), -1, jnp.int32))
@@ -157,27 +160,32 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
                 tag = s["mon"].tag
                 ap = matvec(s["p"], tag)
                 denom = _pdot(s["p"], ap)
-                alpha = s["rs"] / jnp.where(denom == 0, 1.0, denom)
-                x = s["x"] + alpha * s["p"]
-                r = s["r"] - alpha * ap
+                with OT.scope(OT.KRYLOV, OT.UPDATE):
+                    alpha = s["rs"] / jnp.where(denom == 0, 1.0, denom)
+                    x = s["x"] + alpha * s["p"]
+                    r = s["r"] - alpha * ap
                 rs2 = _pdot(r, r)
-                mon1 = Prec.record(s["mon"], relres(rs2))
-                mon2 = Prec.update_tag(mon1, params)
-                sw = _record_switch(s["switches"], mon1, mon2, s["it"])
-                beta = rs2 / jnp.where(s["rs"] == 0, 1.0, s["rs"])
-                p = r + beta * s["p"]
+                with OT.scope(OT.MONITOR):
+                    mon1 = Prec.record(s["mon"], relres(rs2))
+                    mon2 = Prec.update_tag(mon1, params)
+                    sw = _record_switch(s["switches"], mon1, mon2, s["it"])
+                with OT.scope(OT.KRYLOV, OT.UPDATE):
+                    beta = rs2 / jnp.where(s["rs"] == 0, 1.0, s["rs"])
+                    p = r + beta * s["p"]
                 out = dict(x=x, r=r, p=p, rs=rs2, it=s["it"] + 1,
                            mon=mon2, switches=sw)
-                out = _guarded_body(s, out, relres(rs2), guards,
-                                    denom=denom)
-                if flight is not None:
-                    # The recorded scalars are all psum'd/replicated, so
-                    # every shard writes the SAME ring (out_spec P()).
-                    g = out.get("g")
-                    out["fl"] = OF.flight_record(
-                        s["fl"], it=s["it"], relres=relres(rs2), tag=tag,
-                        health=g["health"] if g is not None else None,
-                        a0=alpha, a1=beta, a2=denom)
+                with OT.scope(OT.MONITOR):
+                    out = _guarded_body(s, out, relres(rs2), guards,
+                                        denom=denom)
+                    if flight is not None:
+                        # The recorded scalars are all psum'd/replicated,
+                        # so every shard writes the SAME ring (out_spec
+                        # P()).
+                        g = out.get("g")
+                        out["fl"] = OF.flight_record(
+                            s["fl"], it=s["it"], relres=relres(rs2), tag=tag,
+                            health=g["health"] if g is not None else None,
+                            a0=alpha, a1=beta, a2=denom)
                 return out
 
             def cond(s):
@@ -191,8 +199,9 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
             m_apply, m_apply_at = _diag_apply_dispatch(
                 (m_head, m_tail1, m_tail2, m_table), *precond_meta
             )
-            r0 = b - matvec(x0, mon.tag)
-            z0 = m_apply(r0, mon.tag)
+            r0 = _residual(b, matvec(x0, mon.tag))
+            with OT.scope(OT.PRECOND):
+                z0 = m_apply(r0, mon.tag)
             state = dict(x=x0, r=r0, p=z0, rz=_pdot(r0, z0),
                          rr=_pdot(r0, r0), it=jnp.int32(0), mon=mon,
                          switches=jnp.full((2,), -1, jnp.int32))
@@ -207,14 +216,17 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
                 ap = local_matvec(blk, s["p"], tag=tag, wire=wire, k=k,
                                   rows=rows, ei_bit=ei)
                 denom = _pdot(s["p"], ap)
-                alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
-                x = s["x"] + alpha * s["p"]
-                r = s["r"] - alpha * ap
-                z = m_apply_at(r, tag)
+                with OT.scope(OT.KRYLOV, OT.UPDATE):
+                    alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
+                    x = s["x"] + alpha * s["p"]
+                    r = s["r"] - alpha * ap
+                with OT.scope(OT.PRECOND):
+                    z = m_apply_at(r, tag)
                 rz2 = _pdot(r, z)
                 rr2 = _pdot(r, r)
-                beta = rz2 / jnp.where(s["rz"] == 0, 1.0, s["rz"])
-                p = z + beta * s["p"]
+                with OT.scope(OT.KRYLOV, OT.UPDATE):
+                    beta = rz2 / jnp.where(s["rz"] == 0, 1.0, s["rz"])
+                    p = z + beta * s["p"]
                 stepped = dict(x=x, r=r, p=p, rz=rz2, rr=rr2)
                 if guards is not None or flight is not None:
                     stepped["denom"] = denom
@@ -228,24 +240,26 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
                     krylov,
                 )
                 denom = stepped.pop("denom", None)
-                mon1 = Prec.record(s["mon"], relres(stepped["rr"]))
-                mon2 = Prec.update_tag(mon1, params)
-                sw = _record_switch(s["switches"], mon1, mon2, s["it"])
-                rz2 = stepped["rz"]
-                stepped.update(it=s["it"] + 1, mon=mon2, switches=sw)
-                out = _guarded_body(s, stepped, relres(stepped["rr"]),
-                                    guards, denom=denom,
-                                    breakdown=rz2 < 0, finite_aux=(rz2,))
-                if flight is not None:
-                    # Observation-only recompute (bit-identity contract).
-                    alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
-                    beta = rz2 / jnp.where(s["rz"] == 0, 1.0, s["rz"])
-                    g = out.get("g")
-                    out["fl"] = OF.flight_record(
-                        s["fl"], it=s["it"], relres=relres(stepped["rr"]),
-                        tag=s["mon"].tag,
-                        health=g["health"] if g is not None else None,
-                        a0=alpha, a1=beta, a2=denom)
+                with OT.scope(OT.MONITOR):
+                    mon1 = Prec.record(s["mon"], relres(stepped["rr"]))
+                    mon2 = Prec.update_tag(mon1, params)
+                    sw = _record_switch(s["switches"], mon1, mon2, s["it"])
+                    rz2 = stepped["rz"]
+                    stepped.update(it=s["it"] + 1, mon=mon2, switches=sw)
+                    out = _guarded_body(s, stepped, relres(stepped["rr"]),
+                                        guards, denom=denom,
+                                        breakdown=rz2 < 0, finite_aux=(rz2,))
+                    if flight is not None:
+                        # Observation-only recompute (bit-identity
+                        # contract).
+                        alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
+                        beta = rz2 / jnp.where(s["rz"] == 0, 1.0, s["rz"])
+                        g = out.get("g")
+                        out["fl"] = OF.flight_record(
+                            s["fl"], it=s["it"],
+                            relres=relres(stepped["rr"]), tag=s["mon"].tag,
+                            health=g["health"] if g is not None else None,
+                            a0=alpha, a1=beta, a2=denom)
                 return out
 
             def cond(s):
@@ -374,20 +388,20 @@ def solve_cg_sharded(
                  wire=wire, shards=int(part.n_shards)):
         res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
                                 recover=recover and guards is not None)
-    if not final_correction:
-        return _restore_shape(res, orig_shape)
-    op = make_sharded_operator(part, wire)
+        if not final_correction:
+            return _restore_shape(res, orig_shape)
+        op = make_sharded_operator(part, wire)
 
-    def apply3(v):
-        return op(v, jnp.int32(3))
+        def apply3(v):
+            return op(v, jnp.int32(3))
 
-    def resume(xr, budget):
-        return run(xr, budget, 3)[0]
+        def resume(xr, budget):
+            return run(xr, budget, 3)[0]
 
-    return _restore_shape(
-        _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-        orig_shape,
-    )
+        return _restore_shape(
+            _finish_with_correction(res, b, tol, maxiter, apply3, resume),
+            orig_shape,
+        )
 
 
 def solve_pcg_sharded(
@@ -438,17 +452,17 @@ def solve_pcg_sharded(
                  wire=wire, shards=int(part.n_shards)):
         res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
                                 recover=recover and guards is not None)
-    if not final_correction:
-        return _restore_shape(res, orig_shape)
-    op = make_sharded_operator(part, wire)
+        if not final_correction:
+            return _restore_shape(res, orig_shape)
+        op = make_sharded_operator(part, wire)
 
-    def apply3(v):
-        return op(v, jnp.int32(3))
+        def apply3(v):
+            return op(v, jnp.int32(3))
 
-    def resume(xr, budget):
-        return run(xr, budget, 3)[0]
+        def resume(xr, budget):
+            return run(xr, budget, 3)[0]
 
-    return _restore_shape(
-        _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-        orig_shape,
-    )
+        return _restore_shape(
+            _finish_with_correction(res, b, tol, maxiter, apply3, resume),
+            orig_shape,
+        )

@@ -7,6 +7,9 @@ the target precision but multiply-accumulate happens at high precision
 The jnp implementations use ``segment_sum`` over precomputed row ids, which
 XLA lowers to a scatter-add; the Pallas blocked-ELL kernel
 (``repro.kernels.gse_spmv``) is the TPU-tiled version of the same math.
+Each stage runs under its device scope (``spmv/decode``, ``spmv/gather``,
+``spmv/scatter``; ``obs.trace.SCOPES``), so a profile names the ops of
+every SpMV the solve path runs.
 """
 from __future__ import annotations
 
@@ -16,17 +19,31 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import gse
+from repro.obs import trace as OT
 from repro.sparse.csr import CSR, GSECSR, GSESellC
 
 __all__ = ["spmv", "spmv_gse", "spmv_ell", "spmm", "spmm_gse",
-           "decode_gsecsr", "decode_operand"]
+           "decode_gsecsr", "decode_operand", "gather_scatter",
+           "spmv_operand"]
+
+
+def gather_scatter(val, col, x, row_ids, num_rows, acc_dtype):
+    """``segment_sum(val * x[col], row_ids)``, the SpMV after its decode,
+    under the ``gather`` and ``scatter`` scopes.  ``x`` is ``(n,)`` or an
+    ``(n, nrhs)`` block."""
+    with OT.scope(OT.GATHER):
+        xg = x.astype(acc_dtype)[col]
+        prod = val * xg if x.ndim == 1 else val[:, None] * xg
+    with OT.scope(OT.SCATTER):
+        return jax.ops.segment_sum(prod, row_ids, num_segments=num_rows)
 
 
 @partial(jax.jit, static_argnames=("store_dtype", "acc_dtype", "num_rows"))
 def _spmv_cast(row_ids, col, val, x, store_dtype, acc_dtype, num_rows):
-    v = val.astype(store_dtype).astype(acc_dtype)  # storage round-trip
-    prod = v * x.astype(acc_dtype)[col]
-    return jax.ops.segment_sum(prod, row_ids, num_segments=num_rows)
+    with OT.scope(OT.SPMV):
+        with OT.scope(OT.DECODE):
+            v = val.astype(store_dtype).astype(acc_dtype)  # storage round-trip
+        return gather_scatter(v, col, x, row_ids, num_rows, acc_dtype)
 
 
 def spmv(a: CSR, x: jnp.ndarray, store_dtype=jnp.float64, acc_dtype=jnp.float64):
@@ -98,31 +115,41 @@ def decode_operand(a, tag: int, acc_dtype=jnp.float64):
     """CSR-order ``(values, columns)`` decode of a ``GSECSR`` OR a packed
     ``GSESellC`` at precision ``tag`` -- the one dispatch point the fused
     solver steps and the reference SpMV/SpMM share, so every solver path
-    rides whichever layout the caller packed, bit-identically."""
-    if isinstance(a, GSESellC):
-        cp, hd, t1, t2 = _sell_csr_segments(a)
-        return _decode_gsecsr(cp, hd, t1, t2, a.table, a.ei_bit, tag,
-                              acc_dtype)
-    return _decode_gsecsr(
-        a.colpak, a.head, a.tail1, a.tail2, a.table, a.ei_bit, tag, acc_dtype
-    )
+    rides whichever layout the caller packed, bit-identically.  Runs
+    under the ``decode`` scope (the SELL segment gather included)."""
+    with OT.scope(OT.DECODE):
+        if isinstance(a, GSESellC):
+            cp, hd, t1, t2 = _sell_csr_segments(a)
+            return _decode_gsecsr(cp, hd, t1, t2, a.table, a.ei_bit, tag,
+                                  acc_dtype)
+        return _decode_gsecsr(
+            a.colpak, a.head, a.tail1, a.tail2, a.table, a.ei_bit, tag,
+            acc_dtype
+        )
 
 
 @partial(jax.jit, static_argnames=("tag", "acc_dtype", "num_rows", "ei_bit"))
 def _spmv_gse(colpak, head, tail1, tail2, table, row_ids, x, ei_bit, tag,
               acc_dtype, num_rows):
-    val, col = _decode_gsecsr(
-        colpak, head, tail1, tail2, table, ei_bit, tag, acc_dtype
-    )
-    prod = val * x.astype(acc_dtype)[col]
-    return jax.ops.segment_sum(prod, row_ids, num_segments=num_rows)
+    with OT.scope(OT.SPMV):
+        with OT.scope(OT.DECODE):
+            val, col = _decode_gsecsr(
+                colpak, head, tail1, tail2, table, ei_bit, tag, acc_dtype
+            )
+        return gather_scatter(val, col, x, row_ids, num_rows, acc_dtype)
 
 
-@partial(jax.jit, static_argnames=("tag", "acc_dtype"))
-def _spmv_gse_sell(a: GSESellC, x, tag, acc_dtype):
-    val, col = decode_operand(a, tag, acc_dtype)
-    prod = val * x.astype(acc_dtype)[col]
-    return jax.ops.segment_sum(prod, a.row_ids, num_segments=a.shape[0])
+def spmv_operand(a, x, tag: int, acc_dtype=jnp.float64):
+    """``A @ x`` of a ``GSECSR`` or ``GSESellC`` at a static ``tag``, under
+    the ``spmv`` scope: one ``decode_operand``, then ``gather_scatter``.
+    The fused solver steps inline it; ``spmv_gse`` jits it for SELL."""
+    with OT.scope(OT.SPMV):
+        val, col = decode_operand(a, tag, acc_dtype)
+        return gather_scatter(val, col, x, a.row_ids, a.shape[0], acc_dtype)
+
+
+_spmv_gse_sell = partial(jax.jit, static_argnames=("tag", "acc_dtype"))(
+    spmv_operand)
 
 
 def spmv_gse(a, x: jnp.ndarray, tag: int = 1, acc_dtype=jnp.float64):

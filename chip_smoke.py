@@ -13,9 +13,9 @@ CSR arrays, independent of the code under test.  The last line printed
 on success is ``{"ok": true, "device": {...}}``.
 
 The solves run at 64^3 (262,144 unknowns) and not at 128^3: their SpMV is
-the emulated-float64 jnp decode + gather + ``segment_sum``, which takes
-about 1.6 s per call at 128^3 on a v5e, so one 128^3 solve would take
-about 12 minutes.  The kernels stream float32 and run at 128^3.
+the emulated-float64 jnp decode + gather + row reduction, which took
+about 1.6 s per call at 128^3 on a v5e with a ``segment_sum`` reduction,
+so one 128^3 solve would have taken about 12 minutes.  The kernels stream float32 and run at 128^3.
 
 Times printed here are set-up and smoke numbers; they are not benchmark
 measurements.

@@ -55,6 +55,8 @@ from repro.sparse.csr import (
     _SLOT_BYTES,
     GSECSR,
     iteration_stream_bytes,
+    row_slots,
+    slot_map_fits,
     vector_stream_bytes,
 )
 
@@ -88,7 +90,9 @@ class PartitionedGSECSR:
     out-of-range row id ``R``, which the local segment sum drops, so they
     perturb nothing; padded boundary slots (``bnd_idx == -1``) are masked
     to zero before the wire pack, and padded halo slots are never read by
-    real matrix entries.
+    real matrix entries.  ``slot_map`` is each shard's row-slot map
+    (``csr.row_slots``, one width ``W`` for all): padding entries are
+    never in it and padded rows read only the sentinel ``E``.
     """
 
     # -- stacked per-shard matrix blocks (leading dim n_shards) ------------
@@ -112,6 +116,8 @@ class PartitionedGSECSR:
     rows_real: Tuple[int, ...]       # real rows owned by each shard
     bnd_counts: Tuple[int, ...]      # real boundary entries each shard sends
     halo_counts: Tuple[int, ...]     # real halo entries each shard gathers
+    # -- row reduction -------------------------------------------------------
+    slot_map: jnp.ndarray | None = None  # (s, W, R) int32 local row slots
 
     # -- sizes -------------------------------------------------------------
 
@@ -293,7 +299,8 @@ class PartitionedGSECSR:
 
     def tree_flatten(self):
         leaves = (self.colpak, self.head, self.tail1, self.tail2,
-                  self.row_ids, self.bnd_idx, self.halo_idx, self.table)
+                  self.row_ids, self.bnd_idx, self.halo_idx, self.table,
+                  self.slot_map)
         aux = (self.ei_bit, self.shape, self.n_shards, self.rows_per_shard,
                self.nnz_per_shard, self.rows_real, self.bnd_counts,
                self.halo_counts)
@@ -301,7 +308,8 @@ class PartitionedGSECSR:
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(*leaves, *aux)
+        *arrays, slot_map = leaves
+        return cls(*arrays, *aux, slot_map=slot_map)
 
 
 def partition_gsecsr(a: GSECSR, n_shards: int) -> PartitionedGSECSR:
@@ -408,11 +416,22 @@ def partition_gsecsr(a: GSECSR, n_shards: int) -> PartitionedGSECSR:
         )
         if n_shards > 1 and len(bnd_cols[i]):
             s_bnd[i, :len(bnd_cols[i])] = bnd_cols[i] - lo
+    # One slot-map width for every shard, the longest row anywhere; each
+    # shard's local rowptr runs over its R rows, the padded ones empty.
+    W = int(np.diff(rowptr).max(initial=0))
+    s_slots = None
+    if slot_map_fits(W, n_shards * r_blk, int(rowptr[-1])):
+        s_slots = np.stack([
+            row_slots(np.pad(rowptr[lo:hi + 1] - rowptr[lo],
+                             (0, r_blk - (hi - lo)), mode="edge"), W, E)
+            for lo, hi in zip(starts[:-1], starts[1:])
+        ])
     stacked, mesh = _place(
-        (s_colpak, s_head, s_tail1, s_tail2, s_rows, s_bnd, s_halo),
-        n_shards)
+        (s_colpak, s_head, s_tail1, s_tail2, s_rows, s_bnd, s_halo,
+         s_slots), n_shards)
     part = PartitionedGSECSR(
-        *stacked,
+        *stacked[:7],
+        slot_map=stacked[7],
         table=a.table,
         ei_bit=ei,
         shape=a.shape,
@@ -433,10 +452,10 @@ def _place(arrays, n_shards: int):
     lives on device ``i`` of a 1-D ``AXIS`` mesh, so the sharded solvers
     never copy the operator between devices per call.  With fewer visible
     devices than shards (byte models, host-only use) the arrays stay on
-    the default device and no mesh is returned."""
+    the default device and no mesh is returned.  A ``None`` stays None."""
     devs = jax.devices()
     if len(devs) < n_shards:
-        return [jnp.asarray(x) for x in arrays], None
+        return [jax.device_put(x) for x in arrays], None
     mesh = Mesh(np.array(devs[:n_shards]), (AXIS,))
     spec = NamedSharding(mesh, PartitionSpec(AXIS))
     return [jax.device_put(x, spec) for x in arrays], mesh
@@ -446,8 +465,8 @@ def unshard(part: PartitionedGSECSR, a_template: GSECSR) -> GSECSR:
     """Reassemble the original ``GSECSR`` segment arrays from a partition
     (round-trip check: partitioning is a pure redistribution).
 
-    ``a_template`` supplies the global ``rowptr``/``row_ids`` (the
-    partition keeps only local forms); the returned container's packed
+    ``a_template`` supplies the global ``rowptr``/``row_ids``/``slot_map``
+    (the partition keeps only local forms); the returned container's packed
     segments are reconstructed from the shard blocks and must be
     bit-identical to the original's (tests/test_distributed.py).
     """
@@ -492,4 +511,5 @@ def unshard(part: PartitionedGSECSR, a_template: GSECSR) -> GSECSR:
         row_ids=a_template.row_ids,
         ei_bit=ei,
         shape=part.shape,
+        slot_map=a_template.slot_map,
     )

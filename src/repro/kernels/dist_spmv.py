@@ -3,10 +3,11 @@
 Each shard streams ITS slice of the packed segment arrays through the
 SAME tag-specialized decode the single-device solvers use
 (``sparse.spmv._decode_gsecsr`` -- the fused CG/PCG steps' decode), then
-reduces locally with a segment sum over local row ids.  What crosses the
-interconnect is only the boundary x-entries, through the tag-aware halo
-exchange (``distributed.wire.halo_all_gather``): a tag-1 iteration ships
-2-byte GSE heads, tag 2 head+tail1, tag 3 exact float64 (DESIGN.md §13).
+sums its rows locally over its row-slot map (``gather_scatter``).  What
+crosses the interconnect is only the boundary x-entries, through the
+tag-aware halo exchange (``distributed.wire.halo_all_gather``): a tag-1
+iteration ships 2-byte GSE heads, tag 2 head+tail1, tag 3 exact float64
+(DESIGN.md §13).
 
 Entry points:
 
@@ -71,11 +72,12 @@ def local_matvec(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
     """One shard's y-block at a STATIC tag, called inside shard_map.
 
     ``blk`` holds this shard's slices (leading axis already dropped):
-    ``colpak/head/tail1/tail2/row_ids/bnd_idx/halo_idx/table``.  The halo
-    exchange gathers only boundary entries; the decode is the exact
-    single-device ``_decode_gsecsr`` on the shard's segments, and the
-    segment sum drops the padding entries, whose row id is ``rows``
-    (bit-identical local row sums).  Runs under the ``spmv`` scope, the
+    ``colpak/head/tail1/tail2/row_ids/bnd_idx/halo_idx/table/slot_map``.
+    The halo exchange gathers only boundary entries; the decode is the exact
+    single-device ``_decode_gsecsr`` on the shard's segments, and the row
+    reduction never reads the padding entries: the slot map leaves them
+    out, and ``segment_sum`` drops their row id ``rows`` (bit-identical
+    local row sums either way).  Runs under the ``spmv`` scope, the
     boundary pack, all-gather and halo concatenation under ``halo``.
     """
     with OT.scope(OT.SPMV):
@@ -94,7 +96,7 @@ def local_matvec(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
         # of range, so no dummy row (and no slice for XLA to fuse into the
         # consumer's dot, which would change its summation order).
         return gather_scatter(val, col, xcat, blk["row_ids"], rows,
-                              acc_dtype)
+                              acc_dtype, blk["slot_map"])
 
 
 def _halo_extend(blk, x_sh, *, tag, wire, k, slot_tags):
@@ -116,13 +118,14 @@ def _halo_extend(blk, x_sh, *, tag, wire, k, slot_tags):
     return jnp.concatenate([x_sh, flat[blk["halo_idx"]]], axis=0)
 
 
-def _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table):
+def _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
+         slot_map):
     """Drop the leading per-device axis shard_map leaves on stacked
     operands and bundle the shard's block for ``local_matvec``."""
     return dict(
         colpak=colpak[0], head=head[0], tail1=tail1[0], tail2=tail2[0],
         row_ids=row_ids[0], bnd_idx=bnd_idx[0], halo_idx=halo_idx[0],
-        table=table,
+        table=table, slot_map=None if slot_map is None else slot_map[0],
     )
 
 
@@ -139,9 +142,9 @@ def _dist_matvec_fn(part: PartitionedGSECSR, wire: str, ndim: int,
     rows, ei, k = part.rows_per_shard, part.ei_bit, int(part.table.size)
 
     def run(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-            x, tag):
+            slot_map, x, tag):
         blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
-                   table)
+                   table, slot_map)
         branches = [
             partial(local_matvec, blk, tag=t, wire=wire, k=k, rows=rows,
                     ei_bit=ei, acc_dtype=acc_dtype)
@@ -152,7 +155,7 @@ def _dist_matvec_fn(part: PartitionedGSECSR, wire: str, ndim: int,
     sharded = P(AXIS)
     fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
-        in_specs=(sharded,) * 7 + (P(), sharded, P()),
+        in_specs=(sharded,) * 7 + (P(), sharded, sharded, P()),
         out_specs=sharded,
         check_vma=False,
     ))
@@ -178,9 +181,9 @@ def _dist_matvec_map_fn(part: PartitionedGSECSR, tm: TagMap, wire: str,
     tag = tm.max_tag
 
     def run(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-            slot_tags, x):
+            slot_map, slot_tags, x):
         blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
-                   table)
+                   table, slot_map)
         return local_matvec(blk, x, tag=tag, wire=wire, k=k, rows=rows,
                             ei_bit=ei, acc_dtype=acc_dtype,
                             slot_tags=slot_tags[0])
@@ -188,7 +191,7 @@ def _dist_matvec_map_fn(part: PartitionedGSECSR, tm: TagMap, wire: str,
     sharded = P(AXIS)
     fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
-        in_specs=(sharded,) * 7 + (P(), sharded, sharded),
+        in_specs=(sharded,) * 7 + (P(), sharded, sharded, sharded),
         out_specs=sharded,
         check_vma=False,
     ))
@@ -208,11 +211,11 @@ def _apply_padded(part: PartitionedGSECSR, x: jnp.ndarray, tag,
         st = jnp.asarray(part.bnd_slot_tags(tag).astype(np.int32))
         y = fn(part.colpak, part.head, part.tail1, part.tail2,
                part.row_ids, part.bnd_idx, part.halo_idx, part.table,
-               st, xp)
+               part.slot_map, st, xp)
         return y[:n]
     fn = _dist_matvec_fn(part, wire, x.ndim, acc_dtype)
     y = fn(part.colpak, part.head, part.tail1, part.tail2, part.row_ids,
-           part.bnd_idx, part.halo_idx, part.table, xp,
+           part.bnd_idx, part.halo_idx, part.table, part.slot_map, xp,
            jnp.asarray(tag, jnp.int32))
     return y[:n]
 
@@ -220,7 +223,7 @@ def _apply_padded(part: PartitionedGSECSR, x: jnp.ndarray, tag,
 def _resolve_dist_plan(part, tag, nrhs, plan) -> KernelPlan:
     """Uniform launch-plan resolution for the distributed path (DESIGN.md
     §15): explicit plan > tuned cache (layout key "dist") > default.  The
-    shard-local matvec rides the jnp segment-sum decode -- there is no
+    shard-local matvec rides the jnp decode and row reduction -- there is no
     Pallas block knob here yet -- so the resolved plan records provenance
     and reserves the slot a shard-local kernel will take its blocks from.
     Resolution is skipped for traced tags (the solvers' escalation path

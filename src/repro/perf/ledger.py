@@ -83,9 +83,10 @@ def spmv_ledger(a, tag=None, layout=None, nrhs: int = 1,
     ``store_dtype``).  ``layout`` selects the byte account: ``None`` (raw
     CSR nnz model), ``"ell"`` (uniform lane-padded), or an
     ``ELLLayout``/``GSESellC`` instance for the exact pack in hand.
-    ``jnp_path=True`` charges the reference decode's extra ``row_ids``
-    stream (nnz * 4 B -- the Pallas kernels derive rows from the grid and
-    do not pay this).
+    ``jnp_path=True`` charges the reference decode's extra row-reduction
+    index stream: the ``slot_map`` where the operand has one (W * rows *
+    4 B), else ``segment_sum``'s ``row_ids`` (nnz * 4 B).  The Pallas
+    kernels derive rows from the grid and pay neither.
     """
     if nrhs < 1:
         raise ValueError(f"nrhs must be >= 1, got {nrhs}")
@@ -115,7 +116,8 @@ def spmv_ledger(a, tag=None, layout=None, nrhs: int = 1,
     else:
         raise ValueError(f"unknown layout {layout!r}")
     if jnp_path:
-        mat += int(a.nnz) * 4  # row_ids stream of the segment-sum decode
+        slot_map = getattr(a, "slot_map", None)
+        mat += 4 * (int(a.nnz) if slot_map is None else int(slot_map.size))
     kernel = ("spmv" if nrhs == 1 else "spmm") + "_" + layout_name
     return KernelLedger(
         kernel=kernel, tag=tag, layout=layout_name, nrhs=nrhs,

@@ -137,9 +137,9 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
     rows, ei, k = part.rows_per_shard, part.ei_bit, int(part.table.size)
 
     def run(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-            m_head, m_tail1, m_tail2, m_table, b, x0, tol, bnorm):
+            slot_map, m_head, m_tail1, m_tail2, m_table, b, x0, tol, bnorm):
         blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
-                   table)
+                   table, slot_map)
         matvec = _matvec_dispatch(blk, wire, k, rows, ei)
         mon = Prec.init(params, dtype=b.dtype, tag=init_tag)
 
@@ -288,7 +288,7 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
         out_specs = out_specs + (P(),)
     fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
-        in_specs=(sharded,) * 7 + (P(),) + (sharded,) * 3 + (P(),)
+        in_specs=(sharded,) * 7 + (P(),) + (sharded,) * 4 + (P(),)
         + (sharded, sharded, P(), P()),
         out_specs=out_specs,
         check_vma=False,
@@ -331,7 +331,7 @@ def _run_sharded(part, kind, b, x0, tol, maxiter, params, init_tag, wire,
     bnorm = jnp.where(bnorm == 0, 1.0, bnorm)  # it matches single-device
     outs = fn(
         part.colpak, part.head, part.tail1, part.tail2, part.row_ids,
-        part.bnd_idx, part.halo_idx, part.table,
+        part.bnd_idx, part.halo_idx, part.table, part.slot_map,
         m_head, m_tail1, m_tail2, m_table,
         _pad_to(b, part.n_padded), _pad_to(x0, part.n_padded),
         jnp.asarray(tol, b.dtype), bnorm,

@@ -40,6 +40,8 @@ __all__ = [
     "ell_layout",
     "iteration_stream_bytes",
     "vector_stream_bytes",
+    "row_slots",
+    "slot_map_fits",
 ]
 
 # Matrix-stream bytes one padded slot (or one nnz) costs at each GSE tag:
@@ -48,6 +50,13 @@ __all__ = [
 # alias other modules import.
 _SLOT_BYTES = precision_table.SLOT_BYTES
 _GATHERED_X_BYTES = precision_table.GATHERED_X_BYTES
+
+# A row-slot map (``row_slots``) is built only while it holds at most this
+# many slots per stored entry.  On a v5e the emulated float64 scatter-add
+# of ``segment_sum`` costs about 74 ns an entry and a gathered float64 slot
+# about 13 ns (PERF.md §5), so slots win up to about 5.7 per entry; 4
+# leaves room for the (W, rows) temporary the reduction gathers.
+MAX_SLOTS_PER_NNZ = 4
 
 
 @jax.tree_util.register_pytree_node_class
@@ -98,6 +107,7 @@ class GSECSR:
     row_ids: jnp.ndarray  # (nnz,) int32
     ei_bit: int
     shape: Tuple[int, int]
+    slot_map: jnp.ndarray | None = None  # (W, m) int32 row slots, or None
 
     @property
     def m_h(self) -> int:
@@ -162,12 +172,13 @@ class GSECSR:
     def tree_flatten(self):
         return (
             self.rowptr, self.colpak, self.head, self.tail1, self.tail2,
-            self.table, self.row_ids,
+            self.table, self.row_ids, self.slot_map,
         ), (self.ei_bit, self.shape)
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(*leaves, ei_bit=aux[0], shape=aux[1])
+        *arrays, slot_map = leaves
+        return cls(*arrays, ei_bit=aux[0], shape=aux[1], slot_map=slot_map)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -245,7 +256,9 @@ class GSESellC:
       * ``unperm``  -- (m,) position of each original row in that
         concatenation (``perm[unperm[i]] == i``);
       * ``row_ids`` -- (nnz,) CSR-order row ids (segment reduction);
-      * ``table``   -- shared-exponent table.
+      * ``table``   -- shared-exponent table;
+      * ``slot_map`` -- the ``GSECSR``'s (W, m) row-slot map, or None: the
+        SELL path gathers back to CSR order before the row reduction.
 
     Static: per-bucket ``widths``, ``c``, ``sigma``, ``lane``, ``ei_bit``,
     ``shape``.  The byte model charges ACTUAL padded slots
@@ -267,6 +280,7 @@ class GSESellC:
     lane: int
     ei_bit: int
     shape: Tuple[int, int]
+    slot_map: jnp.ndarray | None = None  # (W, m) int32 row slots, or None
 
     @property
     def nnz(self) -> int:
@@ -341,6 +355,7 @@ class GSESellC:
         leaves = (
             self.colpak, self.head, self.tail1, self.tail2,
             self.gather, self.perm, self.unperm, self.row_ids, self.table,
+            self.slot_map,
         )
         aux = (self.widths, self.c, self.sigma, self.lane, self.ei_bit,
                self.shape)
@@ -348,7 +363,8 @@ class GSESellC:
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(*leaves, *aux)
+        *arrays, slot_map = leaves
+        return cls(*arrays, *aux, slot_map=slot_map)
 
 
 def from_coo(rows, cols, vals, shape) -> CSR:
@@ -417,6 +433,10 @@ def pack_csr(a: CSR, k: int = 8) -> GSECSR:
             "use the value-array encoding variant (paper III.C.1)"
         )
     colpak = (exp_idx.astype(np.uint32) << shift) | col
+    rowptr = np.asarray(a.rowptr, np.int64)
+    width = int(np.diff(rowptr).max(initial=0))
+    slot_map = (jnp.asarray(row_slots(rowptr, width, col.size))
+                if slot_map_fits(width, a.shape[0], col.size) else None)
     return GSECSR(
         rowptr=a.rowptr,
         colpak=jnp.asarray(colpak),
@@ -427,7 +447,34 @@ def pack_csr(a: CSR, k: int = 8) -> GSECSR:
         row_ids=a.row_ids,
         ei_bit=ei,
         shape=a.shape,
+        slot_map=slot_map,
     )
+
+
+def slot_map_fits(width: int, rows: int, nnz: int) -> bool:
+    """Whether a ``(width, rows)`` row-slot map holds at most
+    ``MAX_SLOTS_PER_NNZ`` slots per stored entry (DESIGN.md §19): past
+    that, one long row pads every other row and ``segment_sum`` is the
+    cheaper row reduction."""
+    return width * rows <= MAX_SLOTS_PER_NNZ * nnz
+
+
+def row_slots(rowptr, width: int, sentinel: int) -> np.ndarray:
+    """The ``(width, rows)`` int32 row-slot map of a CSR row pointer.
+
+    Entry ``[k, i]`` is ``rowptr[i] + k``, the position of row ``i``'s
+    ``k``-th stored entry, while ``k`` is below the row's length, and
+    ``sentinel`` (the index of one appended zero product) past it.  Rows
+    run along the minor axis, which the TPU tiles as lanes: a
+    ``(rows, width)`` map with a short minor axis would pad it to 128.
+    ``sparse.spmv.gather_scatter`` sums each column of the gathered
+    products from the top, which is ``segment_sum``'s order.
+    """
+    rowptr = np.asarray(rowptr, np.int64)
+    k = np.arange(width, dtype=np.int64)[:, None]
+    pos = rowptr[None, :-1] + k
+    return np.where(k < np.diff(rowptr)[None, :], pos,
+                    sentinel).astype(np.int32)
 
 
 def vector_stream_bytes(op, dtype=jnp.float64) -> int:
@@ -666,6 +713,7 @@ def pack_sell(a: GSECSR, c: int = 8, sigma: int | None = None,
         unperm=jnp.asarray(unperm, jnp.int32),
         row_ids=a.row_ids,
         table=a.table,
+        slot_map=a.slot_map,
         widths=widths,
         c=c,
         sigma=int(sigma_eff),

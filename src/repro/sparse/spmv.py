@@ -4,8 +4,12 @@ All variants follow the paper's compute discipline: values are *stored* at
 the target precision but multiply-accumulate happens at high precision
 (f64 on CPU; f32 or two-float on TPU -- ``acc_dtype``).
 
-The jnp implementations use ``segment_sum`` over precomputed row ids, which
-XLA lowers to a scatter-add; the Pallas blocked-ELL kernel
+The jnp implementations sum each row over the operand's static row-slot
+map (``csr.row_slots``): one gather of the products into a ``(W, rows)``
+layout and a sum over its W slots, in ``segment_sum``'s order.  An operand
+without a map (plain ``CSR`` baselines, rows too skewed for one) keeps
+``segment_sum`` over precomputed row ids, which XLA lowers to a
+scatter-add (DESIGN.md §19).  The Pallas blocked-ELL kernel
 (``repro.kernels.gse_spmv``) is the TPU-tiled version of the same math.
 Each stage runs under its device scope (``spmv/decode``, ``spmv/gather``,
 ``spmv/scatter``; ``obs.trace.SCOPES``), so a profile names the ops of
@@ -19,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import gse
+from repro.obs import metrics as OM
 from repro.obs import trace as OT
 from repro.sparse.csr import CSR, GSECSR, GSESellC
 
@@ -27,15 +32,40 @@ __all__ = ["spmv", "spmv_gse", "spmv_ell", "spmm", "spmm_gse",
            "spmv_operand"]
 
 
-def gather_scatter(val, col, x, row_ids, num_rows, acc_dtype):
+# Which row reduction each traced SpMV took: counted once a trace, so it
+# says what every compiled program runs at no cost per call.
+ROW_REDUCTION = OM.REGISTRY.counter(
+    "spmv_row_reduction_total",
+    "Traced SpMVs by row reduction: the static slot map or segment_sum.",
+    labelnames=("path",))
+
+
+def gather_scatter(val, col, x, row_ids, num_rows, acc_dtype,
+                   slot_map=None):
     """``segment_sum(val * x[col], row_ids)``, the SpMV after its decode,
     under the ``gather`` and ``scatter`` scopes.  ``x`` is ``(n,)`` or an
-    ``(n, nrhs)`` block."""
+    ``(n, nrhs)`` block.
+
+    With a ``(W, num_rows)`` row-slot map (``csr.row_slots``) the rows are
+    summed without a scatter: the products, one zero appended as the
+    padding slots' sentinel, are gathered into the map's layout and
+    reduced over its slot axis.  XLA's CPU backend runs that reduce from
+    zero down the W slots, each row's terms in CSR order, so it is
+    bitwise ``segment_sum``'s sum; and a reduce, unlike chained adds, is
+    not fused into a consuming dot, whose own summation order would then
+    change.  ``slot_map=None`` keeps ``segment_sum``, which drops entries
+    whose row id is ``num_rows``."""
     with OT.scope(OT.GATHER):
         xg = x.astype(acc_dtype)[col]
         prod = val * xg if x.ndim == 1 else val[:, None] * xg
     with OT.scope(OT.SCATTER):
-        return jax.ops.segment_sum(prod, row_ids, num_segments=num_rows)
+        if slot_map is None:
+            ROW_REDUCTION.labels(path="segment_sum").inc()
+            return jax.ops.segment_sum(prod, row_ids, num_segments=num_rows)
+        ROW_REDUCTION.labels(path="slots").inc()
+        zero = jnp.zeros((1,) + prod.shape[1:], prod.dtype)
+        terms = jnp.concatenate([prod, zero])[slot_map]
+        return jnp.sum(terms, axis=0)
 
 
 @partial(jax.jit, static_argnames=("store_dtype", "acc_dtype", "num_rows"))
@@ -129,14 +159,15 @@ def decode_operand(a, tag: int, acc_dtype=jnp.float64):
 
 
 @partial(jax.jit, static_argnames=("tag", "acc_dtype", "num_rows", "ei_bit"))
-def _spmv_gse(colpak, head, tail1, tail2, table, row_ids, x, ei_bit, tag,
-              acc_dtype, num_rows):
+def _spmv_gse(colpak, head, tail1, tail2, table, row_ids, slot_map, x,
+              ei_bit, tag, acc_dtype, num_rows):
     with OT.scope(OT.SPMV):
         with OT.scope(OT.DECODE):
             val, col = _decode_gsecsr(
                 colpak, head, tail1, tail2, table, ei_bit, tag, acc_dtype
             )
-        return gather_scatter(val, col, x, row_ids, num_rows, acc_dtype)
+        return gather_scatter(val, col, x, row_ids, num_rows, acc_dtype,
+                              slot_map)
 
 
 def spmv_operand(a, x, tag: int, acc_dtype=jnp.float64):
@@ -145,7 +176,8 @@ def spmv_operand(a, x, tag: int, acc_dtype=jnp.float64):
     The fused solver steps inline it; ``spmv_gse`` jits it for SELL."""
     with OT.scope(OT.SPMV):
         val, col = decode_operand(a, tag, acc_dtype)
-        return gather_scatter(val, col, x, a.row_ids, a.shape[0], acc_dtype)
+        return gather_scatter(val, col, x, a.row_ids, a.shape[0], acc_dtype,
+                              a.slot_map)
 
 
 _spmv_gse_sell = partial(jax.jit, static_argnames=("tag", "acc_dtype"))(
@@ -174,8 +206,8 @@ def spmv_gse(a, x: jnp.ndarray, tag: int = 1, acc_dtype=jnp.float64):
     if isinstance(a, GSESellC):
         return _spmv_gse_sell(a, x, tag, acc_dtype)
     return _spmv_gse(
-        a.colpak, a.head, a.tail1, a.tail2, a.table, a.row_ids, x,
-        a.ei_bit, tag, acc_dtype, a.shape[0]
+        a.colpak, a.head, a.tail1, a.tail2, a.table, a.row_ids, a.slot_map,
+        x, a.ei_bit, tag, acc_dtype, a.shape[0]
     )
 
 
@@ -211,23 +243,6 @@ def spmm(a: CSR, x: jnp.ndarray, store_dtype=jnp.float64,
     )
 
 
-@partial(jax.jit, static_argnames=("ei_bit", "tag", "acc_dtype", "num_rows"))
-def _spmm_gse(colpak, head, tail1, tail2, table, row_ids, x, ei_bit, tag,
-              acc_dtype, num_rows):
-    val, col = _decode_gsecsr(
-        colpak, head, tail1, tail2, table, ei_bit, tag, acc_dtype
-    )
-    prod = val[:, None] * x.astype(acc_dtype)[col]  # decode once, nrhs uses
-    return jax.ops.segment_sum(prod, row_ids, num_segments=num_rows)
-
-
-@partial(jax.jit, static_argnames=("tag", "acc_dtype"))
-def _spmm_gse_sell(a: GSESellC, x, tag, acc_dtype):
-    val, col = decode_operand(a, tag, acc_dtype)
-    prod = val[:, None] * x.astype(acc_dtype)[col]  # decode once, nrhs uses
-    return jax.ops.segment_sum(prod, a.row_ids, num_segments=a.shape[0])
-
-
 def spmm_gse(a, x: jnp.ndarray, tag: int = 1, acc_dtype=jnp.float64):
     """GSE-SEM SpMM at precision ``tag``: Y = A @ X, X dense (n, nrhs).
 
@@ -240,13 +255,9 @@ def spmm_gse(a, x: jnp.ndarray, tag: int = 1, acc_dtype=jnp.float64):
     TPU-tiled equivalents (``kernels/ops.gse_spmm_ell`` /
     ``gse_spmm_sell``) dispatch to tag-specialized Pallas kernels that
     provably stream only the segments ``tag`` reads, exactly like the
-    SpMV pipeline.
+    SpMV pipeline.  Here it is ``spmv_gse`` on the block: one decode, one
+    gather and one row reduction for every column.
     """
     if x.ndim != 2:
         raise ValueError(f"spmm_gse wants a (n, nrhs) block; got {x.shape}")
-    if isinstance(a, GSESellC):
-        return _spmm_gse_sell(a, x, tag, acc_dtype)
-    return _spmm_gse(
-        a.colpak, a.head, a.tail1, a.tail2, a.table, a.row_ids, x,
-        a.ei_bit, tag, acc_dtype, a.shape[0]
-    )
+    return spmv_gse(a, x, tag, acc_dtype)

@@ -175,6 +175,34 @@ def test_dist_spmv_bitwise_equals_reference(shards, tag):
 
 
 @multidevice
+@pytest.mark.parametrize("shards", [3, 5])
+@pytest.mark.parametrize("tag", [1, 3])
+def test_dist_spmv_ragged_rows_bitwise(shards, tag):
+    """Rows of 0..7 entries over shard counts that leave padded rows and
+    padding entries: each shard's row-slot map skips both, and the
+    sharded SpMV/SpMM stays bitwise the single-device one."""
+    from repro.kernels.dist_spmv import dist_spmm, dist_spmv
+    from repro.sparse.csr import from_coo
+
+    rng = np.random.default_rng(shards)
+    n = 203
+    lens = rng.integers(0, 8, n)
+    rows = np.repeat(np.arange(n), lens)
+    cols = np.concatenate([rng.choice(n, k, replace=False) for k in lens])
+    a = from_coo(rows, cols, rng.standard_normal(rows.size), (n, n))
+    g = pack_csr(a, k=8)
+    part = partition_gsecsr(g, shards)
+    assert part.slot_map is not None and part.n_padded > n
+    assert len(set(part.nnz_per_shard)) > 1
+    x = jnp.asarray(rng.normal(size=n))
+    assert np.array_equal(np.asarray(spmv_gse(g, x, tag=tag)),
+                          np.asarray(dist_spmv(part, x, tag=tag)))
+    xb = jnp.asarray(rng.normal(size=(n, 3)))
+    assert np.array_equal(np.asarray(spmm_gse(g, xb, tag=tag)),
+                          np.asarray(dist_spmm(part, xb, tag=tag)))
+
+
+@multidevice
 def test_gse_wire_low_tags_close_but_lossy():
     """Tag-1/2 compressed halos perturb ONLY boundary contributions: the
     SpMV error stays at the wire format's mantissa scale."""

@@ -121,15 +121,16 @@ def test_sell_bucket_compiles_for_v5e(one_chip):
 
 @pytest.mark.parametrize("tag", [1, 3])
 def test_f64_jnp_spmv_compiles_for_v5e(one_chip, tag):
-    """The decode + segment-sum SpMV the stepped solvers run, in float64
-    (emulated on the v5e), at poisson3d(128)'s CSR shapes."""
+    """The decode + row-slot reduction SpMV the stepped solvers run, in
+    float64 (emulated on the v5e), at poisson3d(128)'s CSR shapes and its
+    (7, rows) slot map."""
     s = one_chip
 
-    def spmv(cp, hd, t1, t2, table, row_ids, x):
-        return _spmv_gse(cp, hd, t1, t2, table, row_ids, x, EI_BIT, tag,
-                         jnp.float64, N)
+    def spmv(cp, hd, t1, t2, table, row_ids, slot_map, x):
+        return _spmv_gse(cp, hd, t1, t2, table, row_ids, slot_map, x, EI_BIT,
+                         tag, jnp.float64, N)
 
     _compile(spmv, _spec(s, (NNZ,), jnp.uint32), _spec(s, (NNZ,), jnp.uint16),
              _spec(s, (NNZ,), jnp.uint16), _spec(s, (NNZ,), jnp.uint32),
              _spec(s, (K,), jnp.int32), _spec(s, (NNZ,), jnp.int32),
-             _spec(s, (N,), jnp.float64))
+             _spec(s, (7, N), jnp.int32), _spec(s, (N,), jnp.float64))

@@ -160,12 +160,15 @@ KRYLOV = "krylov"      # the Krylov vector work
 DOT = "dot"            #   inner products, psum included
 UPDATE = "update"      #   axpys, xpby and their scalars
 PRECOND = "precond"    # the preconditioner apply
+SMOOTH = "smooth"      #   the multigrid smoother's sweeps
+RESIDUAL = "residual"  #   the multigrid residual before restriction
+TRANSFER = "transfer"  #   restriction, prolongation, the level ordering
 MONITOR = "monitor"    # monitor record/step, switch log, guards, flight
 
 SCOPES = {
     SPMV: (DECODE, GATHER, SCATTER, HALO),
     KRYLOV: (DOT, UPDATE),
-    PRECOND: (),
+    PRECOND: (SMOOTH, RESIDUAL, TRANSFER),
     MONITOR: (),
 }
 _SCOPE_NAMES = frozenset(SCOPES).union(*SCOPES.values())

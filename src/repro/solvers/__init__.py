@@ -22,6 +22,10 @@ Robustness subsystem (DESIGN.md §14): every solver result carries a
 structured ``health`` status (``health_name`` renders it), the in-loop
 guardrails are tuned via ``GuardParams`` (``guards=None`` disables), and
 low-tag breakdowns recover by tag escalation on the same packed operand.
+
+Multigrid (DESIGN.md §20): ``make_mg`` is HPCG's V-cycle with a
+multicolour symmetric Gauss-Seidel smoother over GSE-packed levels, a
+preconditioner for ``solve_pcg`` on box-stencil operators.
 """
 from repro.robustness.guards import (
     DEFAULT_GUARDS,
@@ -41,6 +45,7 @@ from repro.solvers.cg import CGResult, solve_cg, solve_pcg
 from repro.solvers.fused_cg import fused_cg_step, fused_pcg_step, gse_matvec
 from repro.solvers.gmres import GMRESResult, solve_gmres
 from repro.solvers.ir import IRResult, solve_ir
+from repro.solvers.multigrid import MGPrecond, make_mg
 from repro.solvers.operators import (
     make_dense_operator,
     make_fixed_operator,
@@ -89,4 +94,6 @@ __all__ = [
     "make_block_jacobi",
     "make_jacobi",
     "make_spai0",
+    "MGPrecond",
+    "make_mg",
 ]

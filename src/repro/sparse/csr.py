@@ -33,6 +33,7 @@ __all__ = [
     "ELLLayout",
     "from_coo",
     "pack_csr",
+    "stack_row_blocks",
     "to_ell",
     "scatter_rows",
     "sell_slices",
@@ -448,6 +449,51 @@ def pack_csr(a: CSR, k: int = 8) -> GSECSR:
         ei_bit=ei,
         shape=a.shape,
         slot_map=slot_map,
+    )
+
+
+def stack_row_blocks(g: GSECSR, rows: int) -> GSECSR:
+    """``g``'s rows in blocks of ``rows`` as one ``GSECSR`` whose leaves
+    carry a leading block axis: ``jax.tree.map(lambda v: v[i], stacked)``
+    is rows ``i * rows:(i + 1) * rows``, a ``(rows, n)`` operand with the
+    columns unchanged.  Each block's entries are padded to the longest
+    block's count, and each block has its own ``(W, rows)`` slot map
+    against that count (W the longest row of ``g``), so padding is never
+    summed; the exponent table is ``g``'s, repeated."""
+    rowptr = np.asarray(g.rowptr, np.int64)
+    m = rowptr.size - 1
+    if m % rows:
+        raise ValueError(f"{m} rows do not split into blocks of {rows}")
+    nb = m // rows
+    starts = rowptr[0:m:rows]
+    counts = rowptr[rows::rows] - starts
+    emax = int(counts.max())
+    real = np.arange(emax) < counts[:, None]
+    src = np.where(real, starts[:, None] + np.arange(emax), 0)
+
+    def take(arr):
+        a = np.asarray(arr)
+        return jnp.asarray(np.where(real, a[src], 0).astype(a.dtype))
+
+    local = np.concatenate([rowptr[:-1].reshape(nb, rows),
+                            (starts + counts)[:, None]], axis=1)
+    local -= starts[:, None]
+    width = int(np.diff(rowptr).max(initial=0))
+    row_ids = np.where(real, np.asarray(g.row_ids)[src]
+                       - rows * np.arange(nb)[:, None], rows)
+    table = np.asarray(g.table)
+    return GSECSR(
+        rowptr=jnp.asarray(local, jnp.int32),
+        colpak=take(g.colpak),
+        head=take(g.head),
+        tail1=take(g.tail1),
+        tail2=take(g.tail2),
+        table=jnp.asarray(np.broadcast_to(table, (nb,) + table.shape)),
+        row_ids=jnp.asarray(row_ids, jnp.int32),
+        ei_bit=g.ei_bit,
+        shape=(rows, g.shape[1]),
+        slot_map=jnp.asarray(np.stack([row_slots(r, width, emax)
+                                       for r in local])),
     )
 
 

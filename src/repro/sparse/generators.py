@@ -23,6 +23,8 @@ from repro.sparse.csr import CSR, from_coo
 __all__ = [
     "poisson2d",
     "poisson3d",
+    "box_stencil",
+    "hpcg27",
     "convection_diffusion_2d",
     "random_spd",
     "circuit_like",
@@ -59,26 +61,48 @@ def poisson2d(n: int) -> CSR:
 
 def poisson3d(n: int) -> CSR:
     """7-point Laplacian on an n^3 grid (SPD, bone010/Queen role)."""
-    N = n ** 3
-    idx = np.arange(N).reshape(n, n, n)
-    rows, cols, vals = [], [], []
-
-    def add(r, c, v):
-        rows.append(r.ravel())
-        cols.append(c.ravel())
-        vals.append(np.full(r.size, v))
-
-    add(idx, idx, 6.0)
+    taps = {(0, 0, 0): 6.0}
     for axis in range(3):
-        sl_lo = [slice(None)] * 3
-        sl_hi = [slice(None)] * 3
-        sl_lo[axis] = slice(1, None)
-        sl_hi[axis] = slice(None, -1)
-        add(idx[tuple(sl_lo)], idx[tuple(sl_hi)], -1.0)
-        add(idx[tuple(sl_hi)], idx[tuple(sl_lo)], -1.0)
-    return from_coo(
-        np.concatenate(rows), np.concatenate(cols), np.concatenate(vals), (N, N)
-    )
+        for d in (-1, 1):
+            off = [0, 0, 0]
+            off[axis] = d
+            taps[tuple(off)] = -1.0
+    return box_stencil((n, n, n), taps)
+
+
+def box_stencil(grid, taps) -> CSR:
+    """A constant-coefficient stencil on an ``nx x ny x nz`` box,
+    lexicographic with x fastest, zero Dirichlet boundary: row
+    ``ix + nx * (iy + ny * iz)`` holds ``taps[(dx, dy, dz)]`` at the point
+    ``(ix + dx, iy + dy, iz + dz)`` wherever that point lies in the box.
+    ``taps`` maps offsets (the centre ``(0, 0, 0)`` included) to values."""
+    nx, ny, nz = (int(g) for g in grid)
+    ix, iy, iz = (v.ravel() for v in np.meshgrid(
+        np.arange(nx), np.arange(ny), np.arange(nz), indexing="ij"))
+    me = ix + nx * (iy + ny * iz)
+    rows, cols, vals = [], [], []
+    for (dx, dy, dz), v in sorted(taps.items()):
+        jx, jy, jz = ix + dx, iy + dy, iz + dz
+        ok = ((jx >= 0) & (jx < nx) & (jy >= 0) & (jy < ny)
+              & (jz >= 0) & (jz < nz))
+        rows.append(me[ok])
+        cols.append((jx + nx * (jy + ny * jz))[ok])
+        vals.append(np.full(int(ok.sum()), float(v)))
+    n = nx * ny * nz
+    return from_coo(np.concatenate(rows), np.concatenate(cols),
+                    np.concatenate(vals), (n, n))
+
+
+HPCG27_TAPS = {(dx, dy, dz): 26.0 if (dx, dy, dz) == (0, 0, 0) else -1.0
+               for dx in (-1, 0, 1) for dy in (-1, 0, 1) for dz in (-1, 0, 1)}
+
+
+def hpcg27(nx: int, ny: int | None = None, nz: int | None = None) -> CSR:
+    """HPCG's problem (``GenerateProblem_ref``): the 27-point stencil, 26 on
+    the diagonal and -1 for each neighbour inside the box."""
+    ny = nx if ny is None else ny
+    nz = nx if nz is None else nz
+    return box_stencil((nx, ny, nz), HPCG27_TAPS)
 
 
 def convection_diffusion_2d(n: int, beta: float = 20.0) -> CSR:

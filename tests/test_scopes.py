@@ -68,6 +68,47 @@ def test_fused_pcg_step_scopes(op):
     assert found >= SPMV | KRYLOV | {"precond"}
 
 
+MG = {"precond/smooth", "precond/residual", "precond/transfer"}
+
+
+@pytest.fixture(scope="module")
+def mg_op():
+    """HPCG's 27-point operator on 8^3 with its four-level V-cycle, built
+    under a tracer."""
+    from repro.solvers import make_mg
+
+    a = G.hpcg27(8)
+    with OT.capture() as tr:
+        m = make_mg(a)
+    return pack_csr(a, k=8), m, jnp.linspace(0.5, 1.5, a.shape[0]), tr
+
+
+def test_mg_pcg_step_scopes(mg_op):
+    """A lowered MG-PCG iteration names the V-cycle's stages under
+    ``precond``, beside the CG SpMV and the Krylov work."""
+    g, m, v, _ = mg_op
+    found = _paths(_hlo(fused_pcg_step, g, m, v, v, v, 1.0, 1))
+    assert found >= SPMV | KRYLOV | MG
+
+
+def test_mg_counter_and_setup_span(mg_op):
+    """``precond.mg.setup`` is recorded with each level's size, and each
+    traced V-cycle counts once in ``mg_vcycle_total``."""
+    from repro.obs import metrics as OM
+
+    g, m, v, tr = mg_op
+    (setup,) = [e for e in tr.events if e["name"] == "precond.mg.setup"]
+    assert setup["attrs"]["levels"] == 4
+    assert setup["attrs"]["rows"] == [512, 64, 8, 1]
+    assert setup["attrs"]["nnz"] == [22 ** 3, 10 ** 3, 4 ** 3, 1]
+    counter = OM.REGISTRY.get("mg_vcycle_total").labels(levels="4")
+    before = counter.value
+    jax.jit(lambda r: m.apply_at(r, 1)).lower(v)
+    assert counter.value == before + 1
+    jax.jit(lambda r: m.apply(r, 2)).lower(v)      # one per tag branch
+    assert counter.value == before + 4
+
+
 @pytest.mark.parametrize("tag", [1, 2, 3])
 def test_spmv_gse_scopes(op, tag):
     _, g, v = op

@@ -142,6 +142,7 @@ class Smoke:
         return a, ref, g
 
     def build(self):
+        from repro.sparse.csr import csr_order
         from repro.sparse.spmv import decode_gsecsr
 
         self.rng = np.random.default_rng(self.seed)
@@ -149,7 +150,8 @@ class Smoke:
         self.b = self.ref.matvec(self.rng.standard_normal(self.a.shape[0]))
         self.b_dev = jnp.asarray(self.b)
         val, _ = decode_gsecsr(self.g, 3)
-        diff = float(np.max(np.abs(np.asarray(val) - self.ref.val)))
+        val = csr_order(val, self.g.rowptr)
+        diff = float(np.max(np.abs(val - self.ref.val)))
         print(f"tag-3 device decode vs host float64 values: "
               f"max_abs_diff={diff:.3e}", flush=True)
         self.check(diff == 0.0, "tag-3 decode is not exact")

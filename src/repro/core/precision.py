@@ -213,6 +213,7 @@ def decode_error_scores(g, xhat, group_size: int = GROUP_SIZE) -> np.ndarray:
     from repro.kernels import ref
 
     xh = np.abs(np.asarray(xhat, np.float64)).reshape(-1)
+    g = g.in_csr_order()
     cols = (np.asarray(g.colpak, np.uint32)
             & np.uint32((1 << (32 - g.ei_bit)) - 1)).astype(np.int64)
     v3 = np.asarray(ref.decode_csr_ref(g.colpak, g.head, g.tail1, g.tail2,

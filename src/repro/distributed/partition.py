@@ -6,8 +6,10 @@ split the byte stream across devices.  ``partition_gsecsr`` cuts a
 :class:`~repro.sparse.csr.GSECSR` into ``n_shards`` contiguous row blocks:
 
   * each shard keeps its row slice of the packed segment streams
-    (``colpak/head/tail1/tail2``), padded to the max per-shard nnz so the
-    shards stack into ``(n_shards, E)`` device arrays for ``shard_map``;
+    (``colpak/head/tail1/tail2``), stored like the operand's: slot-major
+    ``(W, R)`` blocks, or in CSR order padded to the max per-shard nnz,
+    so the shards stack into ``(n_shards, ...)`` device arrays for
+    ``shard_map``;
   * column indices are REMAPPED to index the shard's local x window
     ``concat(x_shard, x_halo)`` -- columns owned by the shard index the
     local block directly, remote columns go through a compact halo map;
@@ -54,8 +56,10 @@ from repro.core.tagmap import TagMap, normalize_tags
 from repro.sparse.csr import (
     _SLOT_BYTES,
     GSECSR,
+    csr_order,
+    is_slot_major,
     iteration_stream_bytes,
-    row_slots,
+    slot_major,
     slot_map_fits,
     vector_stream_bytes,
 )
@@ -83,24 +87,31 @@ class PartitionedGSECSR:
     """Row-sharded view of a ``GSECSR``: stacked per-shard blocks + halo map.
 
     All per-shard arrays carry a leading ``n_shards`` axis and are padded
-    to uniform extents (max nnz ``E``, max boundary ``B``, max halo ``H``)
-    so ``shard_map`` can split them along the mesh axis; with enough
-    devices visible, row ``i`` of each lives on device ``i`` of the
-    ``AXIS`` mesh.  Padding matrix entries decode to +0.0 and carry the
-    out-of-range row id ``R``, which the local segment sum drops, so they
-    perturb nothing; padded boundary slots (``bnd_idx == -1``) are masked
-    to zero before the wire pack, and padded halo slots are never read by
-    real matrix entries.  ``slot_map`` is each shard's row-slot map
-    (``csr.row_slots``, one width ``W`` for all): padding entries are
-    never in it and padded rows read only the sentinel ``E``.
+    to uniform extents (max boundary ``B``, max halo ``H``) so
+    ``shard_map`` can split them along the mesh axis; with enough devices
+    visible, row ``i`` of each lives on device ``i`` of the ``AXIS`` mesh.
+    Where the operand is stored slot-major (``GSECSR.slot_major``) each
+    shard's entries are too, ``(W, R)`` with one width ``W`` for all, its
+    padded rows holding padding entries only; padding entries decode to
+    +0.0 and read the sentinel column ``R + H``, one zero past the halo
+    window.  Otherwise each shard's entries are in CSR order, padded to
+    the max nnz ``E`` with entries that decode to +0.0 and carry the
+    out-of-range row id ``R``, which the local segment sum drops.  Either
+    way padding perturbs nothing; padded boundary slots (``bnd_idx ==
+    -1``) are masked to zero before the wire pack, and padded halo slots
+    are never read by real matrix entries.
     """
 
     # -- stacked per-shard matrix blocks (leading dim n_shards) ------------
-    colpak: jnp.ndarray    # (s, E) uint32: [expIdx][LOCAL col in x_shard++halo]
-    head: jnp.ndarray      # (s, E) uint16
-    tail1: jnp.ndarray     # (s, E) uint16
-    tail2: jnp.ndarray     # (s, E) uint32
-    row_ids: jnp.ndarray   # (s, E) int32 LOCAL row ids; padding -> R (dropped)
+    colpak: jnp.ndarray    # (s, W, R) | (s, E) uint32: [expIdx][LOCAL col
+    #                        in x_shard ++ halo]
+    head: jnp.ndarray      # (s, W, R) | (s, E) uint16
+    tail1: jnp.ndarray     # (s, W, R) | (s, E) uint16
+    tail2: jnp.ndarray     # (s, W, R) | (s, E) uint32
+    row_ids: jnp.ndarray   # (s, W, R) | (s, E) int32 LOCAL row ids (CSR
+    #                        order: padding -> R, dropped)
+    rowptr: jnp.ndarray    # (s, R + 1) int32 LOCAL row pointer, padded
+    #                        rows empty
     # -- halo exchange plan ------------------------------------------------
     bnd_idx: jnp.ndarray   # (s, B) int32 local x indices this shard sends
     #                        (-1 marks padded slots: masked to 0 on the wire)
@@ -116,14 +127,17 @@ class PartitionedGSECSR:
     rows_real: Tuple[int, ...]       # real rows owned by each shard
     bnd_counts: Tuple[int, ...]      # real boundary entries each shard sends
     halo_counts: Tuple[int, ...]     # real halo entries each shard gathers
-    # -- row reduction -------------------------------------------------------
-    slot_map: jnp.ndarray | None = None  # (s, W, R) int32 local row slots
 
     # -- sizes -------------------------------------------------------------
 
     @property
     def nnz(self) -> int:
         return int(sum(self.nnz_per_shard))
+
+    @property
+    def slot_major(self) -> bool:
+        """Whether each shard's entries are stored ``(W, R)`` by row slot."""
+        return is_slot_major(self.colpak, 1)
 
     @property
     def n_padded(self) -> int:
@@ -148,6 +162,13 @@ class PartitionedGSECSR:
         # value segment + packed colidx per nnz (csr._SLOT_BYTES).
         return _SLOT_BYTES[tag]
 
+    def _shard_entries(self, i: int, field: str) -> np.ndarray:
+        """Shard ``i``'s real entries of ``field`` in CSR order
+        (``csr_order`` over the shard's local row pointer)."""
+        rp = np.asarray(self.rowptr)[i]
+        arr = np.asarray(getattr(self, field))[i]
+        return csr_order(arr if self.slot_major else arr[:rp[-1]], rp)
+
     def _global_entries(self):
         """Per-shard (global_rows, global_cols) of the REAL entries, int64.
 
@@ -161,14 +182,11 @@ class PartitionedGSECSR:
         ei = self.ei_bit
         shift = np.uint32(32 - ei)
         r_blk = self.rows_per_shard
-        s_colpak = np.asarray(self.colpak)
-        s_rows = np.asarray(self.row_ids)
         halo = np.asarray(self.halo_idx)
         bnd = np.asarray(self.bnd_idx)
         out = []
         for i in range(self.n_shards):
-            nz = self.nnz_per_shard[i]
-            loc = (s_colpak[i, :nz]
+            loc = (self._shard_entries(i, "colpak")
                    & np.uint32((1 << (32 - ei)) - 1)).astype(np.int64)
             is_halo = loc >= r_blk
             pool = halo[i]
@@ -182,7 +200,8 @@ class PartitionedGSECSR:
                 if pool.size else 0,
                 loc + i * r_blk,
             )
-            grow = s_rows[i, :nz].astype(np.int64) + i * r_blk
+            grow = (self._shard_entries(i, "row_ids").astype(np.int64)
+                    + i * r_blk)
             out.append((grow, gcol))
         self.__dict__["_global_entries_memo"] = out
         return out
@@ -299,8 +318,8 @@ class PartitionedGSECSR:
 
     def tree_flatten(self):
         leaves = (self.colpak, self.head, self.tail1, self.tail2,
-                  self.row_ids, self.bnd_idx, self.halo_idx, self.table,
-                  self.slot_map)
+                  self.row_ids, self.rowptr, self.bnd_idx, self.halo_idx,
+                  self.table)
         aux = (self.ei_bit, self.shape, self.n_shards, self.rows_per_shard,
                self.nnz_per_shard, self.rows_real, self.bnd_counts,
                self.halo_counts)
@@ -308,8 +327,7 @@ class PartitionedGSECSR:
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        *arrays, slot_map = leaves
-        return cls(*arrays, *aux, slot_map=slot_map)
+        return cls(*leaves, *aux)
 
 
 def partition_gsecsr(a: GSECSR, n_shards: int) -> PartitionedGSECSR:
@@ -330,10 +348,11 @@ def partition_gsecsr(a: GSECSR, n_shards: int) -> PartitionedGSECSR:
             f"row sharding wants a square operator, got {a.shape}"
         )
     rowptr = np.asarray(a.rowptr, np.int64)
-    colpak = np.asarray(a.colpak, np.uint32)
-    head = np.asarray(a.head, np.uint16)
-    tail1 = np.asarray(a.tail1, np.uint16)
-    tail2 = np.asarray(a.tail2, np.uint32)
+    c_ord = a.in_csr_order()
+    colpak = np.asarray(c_ord.colpak, np.uint32)
+    head = np.asarray(c_ord.head, np.uint16)
+    tail1 = np.asarray(c_ord.tail1, np.uint16)
+    tail2 = np.asarray(c_ord.tail2, np.uint32)
     ei = a.ei_bit
     shift = np.uint32(32 - ei)
     col = (colpak & np.uint32((1 << (32 - ei)) - 1)).astype(np.int64)
@@ -416,22 +435,25 @@ def partition_gsecsr(a: GSECSR, n_shards: int) -> PartitionedGSECSR:
         )
         if n_shards > 1 and len(bnd_cols[i]):
             s_bnd[i, :len(bnd_cols[i])] = bnd_cols[i] - lo
-    # One slot-map width for every shard, the longest row anywhere; each
-    # shard's local rowptr runs over its R rows, the padded ones empty.
+    blocks = (s_colpak, s_head, s_tail1, s_tail2, s_rows)
+    # Each shard's local rowptr runs over its R rows, the padded ones empty.
+    local_rp = np.stack([np.pad(rowptr[lo:hi + 1] - rowptr[lo],
+                                (0, r_blk - (hi - lo)), mode="edge")
+                         for lo, hi in zip(starts[:-1], starts[1:])])
     W = int(np.diff(rowptr).max(initial=0))
-    s_slots = None
     if slot_map_fits(W, n_shards * r_blk, int(rowptr[-1])):
-        s_slots = np.stack([
-            row_slots(np.pad(rowptr[lo:hi + 1] - rowptr[lo],
-                             (0, r_blk - (hi - lo)), mode="edge"), W, E)
-            for lo, hi in zip(starts[:-1], starts[1:])
-        ])
+        # One width for every shard, the longest row anywhere.
+        fills = (max_local, 0, 0, 0)    # padding reads past the halo
+        blocks = tuple(
+            np.stack([slot_major(blk[i, :nnz_per_shard[i]], local_rp[i], W,
+                                 fill) for i in range(n_shards)])
+            for blk, fill in zip(blocks[:4], fills))
+        blocks += (np.ascontiguousarray(np.broadcast_to(
+            np.arange(r_blk, dtype=np.int32), (n_shards, W, r_blk))),)
     stacked, mesh = _place(
-        (s_colpak, s_head, s_tail1, s_tail2, s_rows, s_bnd, s_halo,
-         s_slots), n_shards)
+        blocks + (local_rp.astype(np.int32), s_bnd, s_halo), n_shards)
     part = PartitionedGSECSR(
-        *stacked[:7],
-        slot_map=stacked[7],
+        *stacked,
         table=a.table,
         ei_bit=ei,
         shape=a.shape,
@@ -452,7 +474,7 @@ def _place(arrays, n_shards: int):
     lives on device ``i`` of a 1-D ``AXIS`` mesh, so the sharded solvers
     never copy the operator between devices per call.  With fewer visible
     devices than shards (byte models, host-only use) the arrays stay on
-    the default device and no mesh is returned.  A ``None`` stays None."""
+    the default device and no mesh is returned."""
     devs = jax.devices()
     if len(devs) < n_shards:
         return [jax.device_put(x) for x in arrays], None
@@ -465,25 +487,20 @@ def unshard(part: PartitionedGSECSR, a_template: GSECSR) -> GSECSR:
     """Reassemble the original ``GSECSR`` segment arrays from a partition
     (round-trip check: partitioning is a pure redistribution).
 
-    ``a_template`` supplies the global ``rowptr``/``row_ids``/``slot_map``
-    (the partition keeps only local forms); the returned container's packed
-    segments are reconstructed from the shard blocks and must be
-    bit-identical to the original's (tests/test_distributed.py).
+    ``a_template`` supplies the global ``rowptr``/``row_ids`` and the
+    entry order (the partition keeps only local forms); the returned
+    container's packed segments are reconstructed from the shard blocks
+    and must be bit-identical to the original's
+    (tests/test_distributed.py).
     """
-    n = part.shape[0]
     ei = part.ei_bit
     shift = np.uint32(32 - ei)
     r_blk = part.rows_per_shard
     colpak_parts, head_parts, t1_parts, t2_parts = [], [], [], []
-    s_colpak = np.asarray(part.colpak)
-    s_head = np.asarray(part.head)
-    s_t1 = np.asarray(part.tail1)
-    s_t2 = np.asarray(part.tail2)
     halo = np.asarray(part.halo_idx)
     bnd = np.asarray(part.bnd_idx)
     for i in range(part.n_shards):
-        nz = part.nnz_per_shard[i]
-        cp = s_colpak[i, :nz]
+        cp = part._shard_entries(i, "colpak")
         loc = (cp & np.uint32((1 << (32 - ei)) - 1)).astype(np.int64)
         exp_idx = cp >> shift
         lo = i * r_blk
@@ -498,18 +515,23 @@ def unshard(part: PartitionedGSECSR, a_template: GSECSR) -> GSECSR:
                         if pool.size else 0,
                         loc + lo)
         colpak_parts.append((exp_idx << shift) | gcol.astype(np.uint32))
-        head_parts.append(s_head[i, :nz])
-        t1_parts.append(s_t1[i, :nz])
-        t2_parts.append(s_t2[i, :nz])
+        head_parts.append(part._shard_entries(i, "head"))
+        t1_parts.append(part._shard_entries(i, "tail1"))
+        t2_parts.append(part._shard_entries(i, "tail2"))
+    segs = {"colpak": colpak_parts, "head": head_parts, "tail1": t1_parts,
+            "tail2": t2_parts}
+    segs = {f: np.concatenate(v) for f, v in segs.items()}
+    if a_template.slot_major:
+        rowptr = np.asarray(a_template.rowptr, np.int64)
+        width = a_template.colpak.shape[0]
+        segs = {f: slot_major(v, rowptr, width,
+                              part.shape[1] if f == "colpak" else 0)
+                for f, v in segs.items()}
     return GSECSR(
         rowptr=a_template.rowptr,
-        colpak=jnp.asarray(np.concatenate(colpak_parts)),
-        head=jnp.asarray(np.concatenate(head_parts)),
-        tail1=jnp.asarray(np.concatenate(t1_parts)),
-        tail2=jnp.asarray(np.concatenate(t2_parts)),
         table=part.table,
         row_ids=a_template.row_ids,
         ei_bit=ei,
         shape=part.shape,
-        slot_map=a_template.slot_map,
+        **{f: jnp.asarray(v) for f, v in segs.items()},
     )

@@ -3,7 +3,7 @@
 Each shard streams ITS slice of the packed segment arrays through the
 SAME tag-specialized decode the single-device solvers use
 (``sparse.spmv._decode_gsecsr`` -- the fused CG/PCG steps' decode), then
-sums its rows locally over its row-slot map (``gather_scatter``).  What
+sums its rows locally over its slot-major store (``sum_rows``).  What
 crosses the interconnect is only the boundary x-entries, through the
 tag-aware halo exchange (``distributed.wire.halo_all_gather``): a tag-1
 iteration ships 2-byte GSE heads, tag 2 head+tail1, tag 3 exact float64
@@ -20,8 +20,9 @@ Entry points:
   * ``make_sharded_operator(part)`` -- memoized ``apply(v, tag)`` closure
     (traced tag via ``lax.switch``) usable anywhere the solvers accept an
     operator callable: generic CG/PCG, GMRES, batched, IR.
-  * ``local_matvec``/``shard_mesh`` -- building blocks the fully-sharded
-    solver loop (``solvers.sharded``) reuses inside its own shard_map.
+  * ``local_matvec``/``switched_matvec``/``shard_mesh`` -- building
+    blocks the fully-sharded solver loop (``solvers.sharded``) reuses
+    inside its own shard_map.
 
 Everything runs on forced host CPU devices
 (``XLA_FLAGS=--xla_force_host_platform_device_count=8``) exactly as on a
@@ -42,9 +43,10 @@ from repro.distributed.wire import halo_all_gather
 from repro.obs import trace as OT
 from repro.perf import plan as launch_plan
 from repro.perf.plan import KernelPlan
-from repro.sparse.spmv import _decode_gsecsr, gather_scatter
+from repro.sparse.spmv import _decode_gsecsr, gather_products, sum_rows
 
-__all__ = ["shard_mesh", "local_matvec", "dist_spmv", "dist_spmm",
+__all__ = ["shard_mesh", "local_matvec", "local_products",
+           "switched_matvec", "dist_spmv", "dist_spmm",
            "make_sharded_operator"]
 
 
@@ -69,17 +71,29 @@ def local_matvec(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
                  k: int, rows: int, ei_bit: int,
                  acc_dtype=jnp.float64,
                  slot_tags: jnp.ndarray | None = None) -> jnp.ndarray:
-    """One shard's y-block at a STATIC tag, called inside shard_map.
+    """One shard's y-block at a STATIC tag, called inside shard_map:
+    ``local_products`` and then their row sums (``sum_rows``).
 
     ``blk`` holds this shard's slices (leading axis already dropped):
-    ``colpak/head/tail1/tail2/row_ids/bnd_idx/halo_idx/table/slot_map``.
-    The halo exchange gathers only boundary entries; the decode is the exact
-    single-device ``_decode_gsecsr`` on the shard's segments, and the row
-    reduction never reads the padding entries: the slot map leaves them
-    out, and ``segment_sum`` drops their row id ``rows`` (bit-identical
-    local row sums either way).  Runs under the ``spmv`` scope, the
-    boundary pack, all-gather and halo concatenation under ``halo``.
+    ``colpak/head/tail1/tail2/row_ids/bnd_idx/halo_idx/table``.
+    Padding entries add nothing to the row sums: slot-major padding is
+    +0.0 times the zero past the halo window, and ``segment_sum`` drops
+    the CSR-order padding's row id ``rows`` (bit-identical local row sums
+    either way).
     """
+    prod = local_products(blk, x_sh, tag=tag, wire=wire, k=k, ei_bit=ei_bit,
+                          acc_dtype=acc_dtype, slot_tags=slot_tags)
+    return _local_sum(blk, prod, rows)
+
+
+def local_products(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
+                   k: int, ei_bit: int, acc_dtype=jnp.float64,
+                   slot_tags: jnp.ndarray | None = None) -> jnp.ndarray:
+    """One shard's products ``val * x[col]`` at a STATIC tag: the halo
+    exchange gathers only boundary entries, and the decode is the exact
+    single-device ``_decode_gsecsr`` on the shard's segments.  Runs under
+    the ``spmv`` scope, the boundary pack, all-gather and halo
+    concatenation under ``halo``."""
     with OT.scope(OT.SPMV):
         if blk["bnd_idx"].shape[0] == 0:
             xcat = x_sh  # single shard: every column is local
@@ -92,11 +106,32 @@ def local_matvec(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
                 blk["colpak"], blk["head"], blk["tail1"], blk["tail2"],
                 blk["table"], ei_bit, tag, acc_dtype,
             )
-        # Padding entries carry row id ``rows``: segment_sum drops ids out
-        # of range, so no dummy row (and no slice for XLA to fuse into the
-        # consumer's dot, which would change its summation order).
-        return gather_scatter(val, col, xcat, blk["row_ids"], rows,
-                              acc_dtype, blk["slot_map"])
+        return gather_products(val, col, xcat, acc_dtype)
+
+
+def _local_sum(blk, prod, rows):
+    # CSR-order padding carries row id ``rows``: segment_sum drops ids
+    # out of range, so no dummy row (and no slice for XLA to fuse into
+    # the consumer's dot, which would change its summation order).
+    with OT.scope(OT.SPMV):
+        return sum_rows(prod, blk["row_ids"], rows)
+
+
+def switched_matvec(blk: dict, x_sh: jnp.ndarray, tag, *, wire: str,
+                    k: int, rows: int, ei_bit: int, acc_dtype=jnp.float64):
+    """One shard's y-block at a TRACED tag: ``lax.switch`` over the three
+    static-tag ``local_products``, then one row sum after the switch.
+    Summed inside each branch, a slot-major store's float64 reduce at
+    the root of every branch makes the TPU compiler fail (an internal
+    shape check, where the shards' program rewrites the switch's
+    result)."""
+    branches = [
+        partial(local_products, blk, tag=t, wire=wire, k=k, ei_bit=ei_bit,
+                acc_dtype=acc_dtype)
+        for t in (1, 2, 3)
+    ]
+    prod = jax.lax.switch(jnp.clip(tag - 1, 0, 2), branches, x_sh)
+    return _local_sum(blk, prod, rows)
 
 
 def _halo_extend(blk, x_sh, *, tag, wire, k, slot_tags):
@@ -118,14 +153,13 @@ def _halo_extend(blk, x_sh, *, tag, wire, k, slot_tags):
     return jnp.concatenate([x_sh, flat[blk["halo_idx"]]], axis=0)
 
 
-def _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-         slot_map):
+def _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table):
     """Drop the leading per-device axis shard_map leaves on stacked
     operands and bundle the shard's block for ``local_matvec``."""
     return dict(
         colpak=colpak[0], head=head[0], tail1=tail1[0], tail2=tail2[0],
         row_ids=row_ids[0], bnd_idx=bnd_idx[0], halo_idx=halo_idx[0],
-        table=table, slot_map=None if slot_map is None else slot_map[0],
+        table=table,
     )
 
 
@@ -142,20 +176,16 @@ def _dist_matvec_fn(part: PartitionedGSECSR, wire: str, ndim: int,
     rows, ei, k = part.rows_per_shard, part.ei_bit, int(part.table.size)
 
     def run(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-            slot_map, x, tag):
+            x, tag):
         blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
-                   table, slot_map)
-        branches = [
-            partial(local_matvec, blk, tag=t, wire=wire, k=k, rows=rows,
-                    ei_bit=ei, acc_dtype=acc_dtype)
-            for t in (1, 2, 3)
-        ]
-        return jax.lax.switch(jnp.clip(tag - 1, 0, 2), branches, x)
+                   table)
+        return switched_matvec(blk, x, tag, wire=wire, k=k, rows=rows,
+                               ei_bit=ei, acc_dtype=acc_dtype)
 
     sharded = P(AXIS)
     fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
-        in_specs=(sharded,) * 7 + (P(), sharded, sharded, P()),
+        in_specs=(sharded,) * 7 + (P(), sharded, P()),
         out_specs=sharded,
         check_vma=False,
     ))
@@ -181,9 +211,9 @@ def _dist_matvec_map_fn(part: PartitionedGSECSR, tm: TagMap, wire: str,
     tag = tm.max_tag
 
     def run(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-            slot_map, slot_tags, x):
+            slot_tags, x):
         blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
-                   table, slot_map)
+                   table)
         return local_matvec(blk, x, tag=tag, wire=wire, k=k, rows=rows,
                             ei_bit=ei, acc_dtype=acc_dtype,
                             slot_tags=slot_tags[0])
@@ -191,7 +221,7 @@ def _dist_matvec_map_fn(part: PartitionedGSECSR, tm: TagMap, wire: str,
     sharded = P(AXIS)
     fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
-        in_specs=(sharded,) * 7 + (P(), sharded, sharded, sharded),
+        in_specs=(sharded,) * 7 + (P(), sharded, sharded),
         out_specs=sharded,
         check_vma=False,
     ))
@@ -211,11 +241,11 @@ def _apply_padded(part: PartitionedGSECSR, x: jnp.ndarray, tag,
         st = jnp.asarray(part.bnd_slot_tags(tag).astype(np.int32))
         y = fn(part.colpak, part.head, part.tail1, part.tail2,
                part.row_ids, part.bnd_idx, part.halo_idx, part.table,
-               part.slot_map, st, xp)
+               st, xp)
         return y[:n]
     fn = _dist_matvec_fn(part, wire, x.ndim, acc_dtype)
     y = fn(part.colpak, part.head, part.tail1, part.tail2, part.row_ids,
-           part.bnd_idx, part.halo_idx, part.table, part.slot_map, xp,
+           part.bnd_idx, part.halo_idx, part.table, xp,
            jnp.asarray(tag, jnp.int32))
     return y[:n]
 

@@ -189,8 +189,9 @@ def ell_pack_gsecsr(a: GSECSR, lane: int | None = None,
         rowptr = np.asarray(a.rowptr, np.int64)
         L = int(max(1, np.diff(rowptr).max(initial=0)))
         L = ((L + lane - 1) // lane) * lane
+        c_ord = a.in_csr_order()
         outs, _, _ = scatter_rows(
-            rowptr, [(getattr(a, n), d) for n, d in _SEGMENT_DTYPES], L
+            rowptr, [(getattr(c_ord, n), d) for n, d in _SEGMENT_DTYPES], L
         )
         return tuple(jnp.asarray(o) for o in outs)
 
@@ -284,7 +285,7 @@ def masked_for_tagmap(a, tm: TagMap):
             rowptr=a.rowptr, colpak=a.colpak, head=a.head,
             tail1=jnp.asarray(t1), tail2=jnp.asarray(t2),
             table=a.table, row_ids=a.row_ids, ei_bit=a.ei_bit,
-            shape=a.shape, slot_map=a.slot_map,
+            shape=a.shape,
         )
 
     return _cached_pack(a, ("tagmap", tm.crc32, tm.group_size), build)

@@ -154,7 +154,7 @@ class Tracer:
 SPMV = "spmv"          # one operator application
 DECODE = "decode"      #   GSE-SEM segments to acc_dtype values
 GATHER = "gather"      #   x[col] and the product with the values
-SCATTER = "scatter"    #   the row reduction (slot map or segment_sum)
+SCATTER = "scatter"    #   the row reduction (slot-major sum or segment_sum)
 HALO = "halo"          #   boundary pack and all-gather (sharded only)
 KRYLOV = "krylov"      # the Krylov vector work
 DOT = "dot"            #   inner products, psum included

@@ -36,6 +36,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.precision_table import COLIDX_BYTES, SLOT_BYTES
+from repro.core.tagmap import TagMap
 from repro.perf.plan import DEFAULT_BLOCKS
 from repro.sparse.csr import (
     CSR,
@@ -83,9 +84,10 @@ def spmv_ledger(a, tag=None, layout=None, nrhs: int = 1,
     ``store_dtype``).  ``layout`` selects the byte account: ``None`` (raw
     CSR nnz model), ``"ell"`` (uniform lane-padded), or an
     ``ELLLayout``/``GSESellC`` instance for the exact pack in hand.
-    ``jnp_path=True`` charges the reference decode's extra row-reduction
-    index stream: the ``slot_map`` where the operand has one (W * rows *
-    4 B), else ``segment_sum``'s ``row_ids`` (nnz * 4 B).  The Pallas
+    ``jnp_path=True`` charges what the reference SpMV streams beyond the
+    entries: the padded slots' segments where the operand is stored
+    slot-major (``(W * rows - nnz) * SLOT_BYTES[tag]``, at a tag map's
+    max tag), else ``segment_sum``'s ``row_ids`` (nnz * 4 B).  The Pallas
     kernels derive rows from the grid and pay neither.
     """
     if nrhs < 1:
@@ -116,8 +118,11 @@ def spmv_ledger(a, tag=None, layout=None, nrhs: int = 1,
     else:
         raise ValueError(f"unknown layout {layout!r}")
     if jnp_path:
-        slot_map = getattr(a, "slot_map", None)
-        mat += 4 * (int(a.nnz) if slot_map is None else int(slot_map.size))
+        if not isinstance(a, CSR) and a.slot_major:
+            t = tag.max_tag if isinstance(tag, TagMap) else tag
+            mat += (a.row_ids.size - int(a.nnz)) * SLOT_BYTES[t]
+        else:
+            mat += 4 * int(a.nnz)
     kernel = ("spmv" if nrhs == 1 else "spmm") + "_" + layout_name
     return KernelLedger(
         kernel=kernel, tag=tag, layout=layout_name, nrhs=nrhs,

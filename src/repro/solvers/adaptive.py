@@ -102,6 +102,7 @@ def _inv_diag(a: GSECSR) -> np.ndarray:
     decode (no CSR needed -- ``a`` is all the driver gets)."""
     from repro.kernels import ref
 
+    a = a.in_csr_order()
     rows = np.asarray(a.row_ids, np.int64)
     cols = (np.asarray(a.colpak, np.uint32)
             & np.uint32((1 << (32 - a.ei_bit)) - 1)).astype(np.int64)
@@ -152,6 +153,7 @@ def _abs_neumann_profile(a: GSECSR, b: np.ndarray, hops: int = 1) -> np.ndarray:
     and the explore phase's live iterate is the only sound profile."""
     from repro.kernels import ref
 
+    a = a.in_csr_order()
     rows = np.asarray(a.row_ids, np.int64)
     cols = (np.asarray(a.colpak, np.uint32)
             & np.uint32((1 << (32 - a.ei_bit)) - 1)).astype(np.int64)

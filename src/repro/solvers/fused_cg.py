@@ -49,7 +49,7 @@ def _step_at_tag(a, x, r, p, rs, *, tag: int, acc_dtype, with_denom=False):
     """One fused CG iteration at a fixed precision tag.
 
     ``a`` is a ``GSECSR`` or a SELL-C-σ packed ``GSESellC`` --
-    ``decode_operand`` recovers the same CSR-order values either way, so
+    ``decode_operand`` recovers the same values either way, so
     the layouts share one bit-identical iteration body (DESIGN.md §12).
     Single decoded-value pass: ``val`` is materialized once and feeds both
     the matvec and (via ``ap``) the direction dot; everything downstream of

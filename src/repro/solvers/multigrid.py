@@ -28,8 +28,8 @@ Departures from the reference, each for the chip:
   formed at colour 0's rows alone, the only rows injection keeps.  The
   operand's vectors are permuted into that order and back at each apply.
 - Each level's operator is packed once and split into 8 colour row
-  blocks stacked on a leading axis, GSE-SEM ``GSECSR``s with their own
-  slot maps, streamed at the monitor's tag like the solve's operand; a
+  blocks stacked on a leading axis, GSE-SEM ``GSECSR``s stored
+  slot-major, streamed at the monitor's tag like the solve's operand; a
   symmetric step is one loop of 16 colour steps over them.  The fine
   level is thus held twice: as the solve's operand and in colour blocks.
 """
@@ -45,7 +45,8 @@ from repro.core import gse
 from repro.obs import metrics as OM
 from repro.obs import trace as OT
 from repro.solvers.precond import _TagDispatchPrecond
-from repro.sparse.csr import CSR, GSECSR, from_coo, pack_csr, stack_row_blocks
+from repro.sparse.csr import (CSR, GSECSR, csr_order, from_coo, pack_csr,
+                              stack_row_blocks)
 from repro.sparse.generators import box_stencil
 from repro.sparse.spmv import spmv_operand
 
@@ -288,12 +289,12 @@ class MGPrecond(_TagDispatchPrecond):
         lv = self.levels[lvl]
         layout = level_layouts(self.grids)[lvl]
         out = []
-        for c, nnz in enumerate(np.asarray(lv.ops.rowptr)[:, -1]):
+        for c in range(COLOURS):
             block = jax.tree.map(lambda v: v[c], lv.ops)
             val, col = decode_gsecsr(block, 3, jnp.float64)
-            rows = np.asarray(block.row_ids)[:nnz] + c * lv.rows
-            out.append((layout[rows], layout[np.asarray(col)[:nnz]],
-                        np.asarray(val)[:nnz]))
+            rows = csr_order(block.row_ids, block.rowptr) + c * lv.rows
+            out.append((layout[rows], layout[csr_order(col, block.rowptr)],
+                        csr_order(val, block.rowptr)))
         return tuple(np.concatenate(p) for p in zip(*out))
 
     def bytes_touched(self, tag: int) -> int:

@@ -42,6 +42,7 @@ from repro.kernels.dist_spmv import (
     local_matvec,
     make_sharded_operator,
     shard_mesh,
+    switched_matvec,
 )
 from repro.obs import flight as OF
 from repro.obs import trace as OT
@@ -82,15 +83,11 @@ def _pad_to(x, n_padded):
 def _matvec_dispatch(blk, wire, k, rows, ei):
     """Traced-tag distributed matvec for use inside the sharded loop --
     same ``lax.switch`` discipline as ``fused_cg_step``, with the halo
-    exchange and decode both inside each static-tag branch."""
-    branches = [
-        partial(local_matvec, blk, tag=t, wire=wire, k=k, rows=rows,
-                ei_bit=ei)
-        for t in (1, 2, 3)
-    ]
-
+    exchange and decode both inside each static-tag branch
+    (``switched_matvec``)."""
     def matvec(v, tag):
-        return jax.lax.switch(jnp.clip(tag - 1, 0, 2), branches, v)
+        return switched_matvec(blk, v, tag, wire=wire, k=k, rows=rows,
+                               ei_bit=ei)
 
     return matvec
 
@@ -137,9 +134,9 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
     rows, ei, k = part.rows_per_shard, part.ei_bit, int(part.table.size)
 
     def run(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-            slot_map, m_head, m_tail1, m_tail2, m_table, b, x0, tol, bnorm):
+            m_head, m_tail1, m_tail2, m_table, b, x0, tol, bnorm):
         blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
-                   table, slot_map)
+                   table)
         matvec = _matvec_dispatch(blk, wire, k, rows, ei)
         mon = Prec.init(params, dtype=b.dtype, tag=init_tag)
 
@@ -288,7 +285,7 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
         out_specs = out_specs + (P(),)
     fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
-        in_specs=(sharded,) * 7 + (P(),) + (sharded,) * 4 + (P(),)
+        in_specs=(sharded,) * 7 + (P(),) + (sharded,) * 3 + (P(),)
         + (sharded, sharded, P(), P()),
         out_specs=out_specs,
         check_vma=False,
@@ -331,7 +328,7 @@ def _run_sharded(part, kind, b, x0, tol, maxiter, params, init_tag, wire,
     bnorm = jnp.where(bnorm == 0, 1.0, bnorm)  # it matches single-device
     outs = fn(
         part.colpak, part.head, part.tail1, part.tail2, part.row_ids,
-        part.bnd_idx, part.halo_idx, part.table, part.slot_map,
+        part.bnd_idx, part.halo_idx, part.table,
         m_head, m_tail1, m_tail2, m_table,
         _pad_to(b, part.n_padded), _pad_to(x0, part.n_padded),
         jnp.asarray(tol, b.dtype), bnorm,

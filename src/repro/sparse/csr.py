@@ -41,7 +41,9 @@ __all__ = [
     "ell_layout",
     "iteration_stream_bytes",
     "vector_stream_bytes",
-    "row_slots",
+    "slot_major",
+    "is_slot_major",
+    "csr_order",
     "slot_map_fits",
 ]
 
@@ -52,11 +54,12 @@ __all__ = [
 _SLOT_BYTES = precision_table.SLOT_BYTES
 _GATHERED_X_BYTES = precision_table.GATHERED_X_BYTES
 
-# A row-slot map (``row_slots``) is built only while it holds at most this
-# many slots per stored entry.  On a v5e the emulated float64 scatter-add
-# of ``segment_sum`` costs about 74 ns an entry and a gathered float64 slot
-# about 13 ns (PERF.md §5), so slots win up to about 5.7 per entry; 4
-# leaves room for the (W, rows) temporary the reduction gathers.
+# An operand is stored slot-major (``slot_major``) only while that holds at
+# most this many slots per stored entry.  On a v5e the emulated float64
+# scatter-add of ``segment_sum`` costs about 74 ns an entry, and a padded
+# slot one more ``x`` gather of about 13 ns and 12 B of segments
+# (PERF.md §5, DESIGN.md §19), so slots win up to about 6.7 per entry; 4
+# leaves room for the (W, rows) temporaries of the decode.
 MAX_SLOTS_PER_NNZ = 4
 
 
@@ -97,18 +100,23 @@ class CSR:
 @jax.tree_util.register_pytree_node_class
 @dataclasses.dataclass
 class GSECSR:
-    """CSR with GSE-SEM values; expIdx lives in the top bits of ``col``."""
+    """CSR with GSE-SEM values; expIdx lives in the top bits of ``col``.
+
+    The four segments and ``row_ids`` share one entry order: CSR order,
+    ``(nnz,)``, or slot-major, ``(W, m)`` (``slot_major``; DESIGN.md
+    §19), where entry ``[k, i]`` is row ``i``'s ``k``-th entry and the
+    slots past a row's length are padding entries that decode to +0.0 and
+    read column ``shape[1]``, one zero appended to ``x``."""
 
     rowptr: jnp.ndarray   # (m+1,) int32
-    colpak: jnp.ndarray   # (nnz,) uint32: [expIdx : EI_BIT][col : 32-EI_BIT]
-    head: jnp.ndarray     # (nnz,) uint16: sign(1) | mantissa(15)
-    tail1: jnp.ndarray    # (nnz,) uint16
-    tail2: jnp.ndarray    # (nnz,) uint32
+    colpak: jnp.ndarray   # (nnz,) | (W, m) uint32: [expIdx : EI_BIT][col]
+    head: jnp.ndarray     # (nnz,) | (W, m) uint16: sign(1) | mantissa(15)
+    tail1: jnp.ndarray    # (nnz,) | (W, m) uint16
+    tail2: jnp.ndarray    # (nnz,) | (W, m) uint32
     table: jnp.ndarray    # (k,) int32 biased+1
-    row_ids: jnp.ndarray  # (nnz,) int32
+    row_ids: jnp.ndarray  # (nnz,) | (W, m) int32, in the segments' order
     ei_bit: int
     shape: Tuple[int, int]
-    slot_map: jnp.ndarray | None = None  # (W, m) int32 row slots, or None
 
     @property
     def m_h(self) -> int:
@@ -120,13 +128,29 @@ class GSECSR:
         return self.m_h + 48
 
     @property
+    def slot_major(self) -> bool:
+        """Whether the entries are stored ``(W, m)`` by row slot, not in
+        CSR order (``stack_row_blocks``' block axis leads ``rowptr`` too)."""
+        return is_slot_major(self.colpak, self.rowptr.ndim - 1)
+
+    @property
     def nnz(self) -> int:
+        if self.slot_major:
+            return int(np.asarray(self.rowptr)[..., -1].sum())
         return self.colpak.shape[0]
 
     def nbytes(self, tag: int) -> int:
-        n = self.colpak.shape[0]
         per = precision_table.TAG_VALUE_BYTES[tag]
-        return n * per + self.table.size * 4
+        return self.nnz * per + self.table.size * 4
+
+    def in_csr_order(self) -> "GSECSR":
+        """This operand with its segments and ``row_ids`` in CSR order, as
+        host arrays (``csr_order``): itself where it is stored so."""
+        if not self.slot_major:
+            return self
+        segs = {f: csr_order(getattr(self, f), self.rowptr)
+                for f in ("colpak", "head", "tail1", "tail2", "row_ids")}
+        return dataclasses.replace(self, **segs)
 
     def bytes_per_nnz(self, tag: int) -> int:
         """Modeled matrix-stream bytes per nonzero of the encoding at
@@ -161,9 +185,10 @@ class GSECSR:
             return layout.bytes_touched(tag)
         fixed = self.rowptr.size * 4 + self.table.size * 4
         if isinstance(tag, TagMap):
-            cols = (np.asarray(self.colpak, np.uint32)
+            a = self.in_csr_order()
+            cols = (np.asarray(a.colpak, np.uint32)
                     & np.uint32((1 << (32 - self.ei_bit)) - 1))
-            et = tag.entry_tags(np.asarray(self.row_ids), cols)
+            et = tag.entry_tags(np.asarray(a.row_ids), cols)
             counts = np.bincount(et, minlength=4)
             return fixed + int(sum(
                 int(counts[t]) * self.bytes_per_nnz(t) for t in (1, 2, 3)
@@ -173,13 +198,12 @@ class GSECSR:
     def tree_flatten(self):
         return (
             self.rowptr, self.colpak, self.head, self.tail1, self.tail2,
-            self.table, self.row_ids, self.slot_map,
+            self.table, self.row_ids,
         ), (self.ei_bit, self.shape)
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        *arrays, slot_map = leaves
-        return cls(*arrays, ei_bit=aux[0], shape=aux[1], slot_map=slot_map)
+        return cls(*leaves, ei_bit=aux[0], shape=aux[1])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -248,18 +272,18 @@ class GSESellC:
 
       * ``colpak/head/tail1/tail2`` -- tuples of ``(rows_b, w_b)`` segment
         arrays, one entry per width-bucket (ascending widths);
-      * ``gather``  -- (nnz,) flat index of every CSR-order entry inside the
-        concatenation of the row-major bucket arrays (the packed store IS
-        the value store: the reference/solver paths decode through this
-        gather, bit-identical to the CSR decode);
+      * ``gather``  -- flat index of every entry inside the concatenation
+        of the row-major bucket arrays and one appended padding entry, in
+        the ``GSECSR``'s entry order: ``(nnz,)`` CSR order, or ``(W, m)``
+        slot-major with its padding at index ``slots`` (the packed store
+        IS the value store: the reference/solver paths decode through
+        this gather, bit-identical to the ``GSECSR`` decode);
       * ``perm``    -- (rows_padded,) original row id of each concatenated
         bucket row (-1 for slice-padding rows);
       * ``unperm``  -- (m,) position of each original row in that
         concatenation (``perm[unperm[i]] == i``);
-      * ``row_ids`` -- (nnz,) CSR-order row ids (segment reduction);
-      * ``table``   -- shared-exponent table;
-      * ``slot_map`` -- the ``GSECSR``'s (W, m) row-slot map, or None: the
-        SELL path gathers back to CSR order before the row reduction.
+      * ``row_ids`` -- the ``GSECSR``'s row ids, in ``gather``'s order;
+      * ``table``   -- shared-exponent table.
 
     Static: per-bucket ``widths``, ``c``, ``sigma``, ``lane``, ``ei_bit``,
     ``shape``.  The byte model charges ACTUAL padded slots
@@ -270,10 +294,10 @@ class GSESellC:
     head: tuple     # per-bucket (rows_b, w_b) uint16
     tail1: tuple    # per-bucket (rows_b, w_b) uint16
     tail2: tuple    # per-bucket (rows_b, w_b) uint32
-    gather: jnp.ndarray   # (nnz,) int32
+    gather: jnp.ndarray   # (nnz,) | (W, m) int32
     perm: jnp.ndarray     # (rows_padded,) int32, -1 for padding rows
     unperm: jnp.ndarray   # (m,) int32
-    row_ids: jnp.ndarray  # (nnz,) int32
+    row_ids: jnp.ndarray  # (nnz,) | (W, m) int32
     table: jnp.ndarray    # (k,) int32 biased+1
     widths: Tuple[int, ...]
     c: int
@@ -281,11 +305,17 @@ class GSESellC:
     lane: int
     ei_bit: int
     shape: Tuple[int, int]
-    slot_map: jnp.ndarray | None = None  # (W, m) int32 row slots, or None
+
+    @property
+    def slot_major(self) -> bool:
+        """Whether ``gather`` is in a slot-major operand's entry order."""
+        return is_slot_major(self.gather)
 
     @property
     def nnz(self) -> int:
-        return self.gather.shape[0]
+        if not self.slot_major:
+            return self.gather.shape[0]
+        return int(np.count_nonzero(np.asarray(self.gather) < self.slots))
 
     @property
     def n_buckets(self) -> int:
@@ -322,8 +352,10 @@ class GSESellC:
             [np.asarray(cp, np.uint32).reshape(-1) for cp in self.colpak]
         ) if self.colpak else np.zeros(0, np.uint32)
         gather = np.asarray(self.gather, np.int64)
+        real = gather < cp_flat.size       # slot-major padding: not stored
+        gather = gather[real]
         cols = cp_flat[gather] & np.uint32((1 << (32 - self.ei_bit)) - 1)
-        et = tm.entry_tags(np.asarray(self.row_ids), cols)
+        et = tm.entry_tags(np.asarray(self.row_ids)[real], cols)
         sizes = np.array([cp.size for cp in self.colpak], np.int64)
         offs = np.concatenate([[0], np.cumsum(sizes)])
         bidx = np.searchsorted(offs, gather, side="right") - 1
@@ -356,7 +388,6 @@ class GSESellC:
         leaves = (
             self.colpak, self.head, self.tail1, self.tail2,
             self.gather, self.perm, self.unperm, self.row_ids, self.table,
-            self.slot_map,
         )
         aux = (self.widths, self.c, self.sigma, self.lane, self.ei_bit,
                self.shape)
@@ -364,8 +395,7 @@ class GSESellC:
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        *arrays, slot_map = leaves
-        return cls(*arrays, *aux, slot_map=slot_map)
+        return cls(*leaves, *aux)
 
 
 def from_coo(rows, cols, vals, shape) -> CSR:
@@ -427,100 +457,124 @@ def pack_csr(a: CSR, k: int = 8) -> GSECSR:
 
     col = np.asarray(a.col).astype(np.uint32)
     shift = np.uint32(32 - ei)
-    max_col = int(col.max()) if col.size else 0
+    rowptr = np.asarray(a.rowptr, np.int64)
+    width = int(np.diff(rowptr).max(initial=0))
+    by_slot = slot_map_fits(width, a.shape[0], col.size)
+    # Slot-major padding reads column ``shape[1]``, which must fit too.
+    max_col = a.shape[1] if by_slot else int(col.max()) if col.size else 0
     if max_col >= (1 << (32 - ei)):
         raise ValueError(
             f"column count {max_col} needs > {32 - ei} bits; "
             "use the value-array encoding variant (paper III.C.1)"
         )
     colpak = (exp_idx.astype(np.uint32) << shift) | col
-    rowptr = np.asarray(a.rowptr, np.int64)
-    width = int(np.diff(rowptr).max(initial=0))
-    slot_map = (jnp.asarray(row_slots(rowptr, width, col.size))
-                if slot_map_fits(width, a.shape[0], col.size) else None)
+    segs = dict(colpak=colpak, head=new_head, tail1=new_tail1,
+                tail2=new_tail2)
+    if by_slot:
+        segs = _slot_major_segments(segs, rowptr, width, a.shape[1])
+    else:
+        segs["row_ids"] = a.row_ids
     return GSECSR(
         rowptr=a.rowptr,
-        colpak=jnp.asarray(colpak),
-        head=jnp.asarray(new_head),
-        tail1=jnp.asarray(new_tail1),
-        tail2=jnp.asarray(new_tail2),
         table=jnp.asarray(table, jnp.int32),
-        row_ids=a.row_ids,
         ei_bit=ei,
         shape=a.shape,
-        slot_map=slot_map,
+        **{f: jnp.asarray(v) for f, v in segs.items()},
     )
+
+
+def _slot_major_segments(segs: dict, rowptr, width: int, sentinel: int):
+    """CSR-order ``colpak``/``head``/``tail1``/``tail2`` laid out
+    ``(width, rows)`` by ``slot_major``, padding entries zero segments
+    reading column ``sentinel``, and the ``row_ids`` of that order: row
+    ``i`` in every slot of column ``i``."""
+    rows = np.arange(np.asarray(rowptr).size - 1, dtype=np.int32)
+    out = {f: slot_major(segs[f], rowptr, width,
+                         sentinel if f == "colpak" else 0)
+           for f in ("colpak", "head", "tail1", "tail2")}
+    out["row_ids"] = np.ascontiguousarray(
+        np.broadcast_to(rows, (width, rows.size)))
+    return out
 
 
 def stack_row_blocks(g: GSECSR, rows: int) -> GSECSR:
     """``g``'s rows in blocks of ``rows`` as one ``GSECSR`` whose leaves
     carry a leading block axis: ``jax.tree.map(lambda v: v[i], stacked)``
     is rows ``i * rows:(i + 1) * rows``, a ``(rows, n)`` operand with the
-    columns unchanged.  Each block's entries are padded to the longest
-    block's count, and each block has its own ``(W, rows)`` slot map
-    against that count (W the longest row of ``g``), so padding is never
-    summed; the exponent table is ``g``'s, repeated."""
+    columns unchanged.  Every block is stored slot-major, ``(W, rows)``
+    with W the longest row of ``g``, and holds local row ids and a local
+    row pointer; the exponent table is ``g``'s, repeated."""
     rowptr = np.asarray(g.rowptr, np.int64)
     m = rowptr.size - 1
     if m % rows:
         raise ValueError(f"{m} rows do not split into blocks of {rows}")
     nb = m // rows
-    starts = rowptr[0:m:rows]
-    counts = rowptr[rows::rows] - starts
-    emax = int(counts.max())
-    real = np.arange(emax) < counts[:, None]
-    src = np.where(real, starts[:, None] + np.arange(emax), 0)
-
-    def take(arr):
-        a = np.asarray(arr)
-        return jnp.asarray(np.where(real, a[src], 0).astype(a.dtype))
-
-    local = np.concatenate([rowptr[:-1].reshape(nb, rows),
-                            (starts + counts)[:, None]], axis=1)
-    local -= starts[:, None]
+    c = g.in_csr_order()
     width = int(np.diff(rowptr).max(initial=0))
-    row_ids = np.where(real, np.asarray(g.row_ids)[src]
-                       - rows * np.arange(nb)[:, None], rows)
+    segs = _slot_major_segments(vars(c), rowptr, width, g.shape[1])
+    segs["row_ids"] = segs["row_ids"] % rows
+    starts = rowptr[0:m:rows]
+    local = np.concatenate([rowptr[:-1].reshape(nb, rows),
+                            rowptr[rows::rows][:, None]], axis=1)
+    local -= starts[:, None]
     table = np.asarray(g.table)
     return GSECSR(
         rowptr=jnp.asarray(local, jnp.int32),
-        colpak=take(g.colpak),
-        head=take(g.head),
-        tail1=take(g.tail1),
-        tail2=take(g.tail2),
         table=jnp.asarray(np.broadcast_to(table, (nb,) + table.shape)),
-        row_ids=jnp.asarray(row_ids, jnp.int32),
         ei_bit=g.ei_bit,
         shape=(rows, g.shape[1]),
-        slot_map=jnp.asarray(np.stack([row_slots(r, width, emax)
-                                       for r in local])),
+        **{f: jnp.asarray(np.ascontiguousarray(
+            v.reshape(width, nb, rows).transpose(1, 0, 2)))
+           for f, v in segs.items()},
     )
 
 
 def slot_map_fits(width: int, rows: int, nnz: int) -> bool:
-    """Whether a ``(width, rows)`` row-slot map holds at most
+    """Whether a slot-major store of ``width`` slots a row holds at most
     ``MAX_SLOTS_PER_NNZ`` slots per stored entry (DESIGN.md §19): past
-    that, one long row pads every other row and ``segment_sum`` is the
-    cheaper row reduction."""
+    that, one long row pads every other row and CSR order with
+    ``segment_sum`` is the cheaper row reduction."""
     return width * rows <= MAX_SLOTS_PER_NNZ * nnz
 
 
-def row_slots(rowptr, width: int, sentinel: int) -> np.ndarray:
-    """The ``(width, rows)`` int32 row-slot map of a CSR row pointer.
+def is_slot_major(entries, lead: int = 0) -> bool:
+    """Whether a stored entry array (a segment, ``row_ids``, a SELL
+    ``gather`` or what the decode makes of them) is slot-major,
+    ``(W, rows)`` after ``lead`` leading block or shard axes, and not in
+    CSR order, ``(nnz,)``: the one test of the entry order, for the
+    containers' ``slot_major`` and the SpMV's stages alike."""
+    return entries.ndim - lead == 2
 
-    Entry ``[k, i]`` is ``rowptr[i] + k``, the position of row ``i``'s
-    ``k``-th stored entry, while ``k`` is below the row's length, and
-    ``sentinel`` (the index of one appended zero product) past it.  Rows
-    run along the minor axis, which the TPU tiles as lanes: a
-    ``(rows, width)`` map with a short minor axis would pad it to 128.
-    ``sparse.spmv.gather_scatter`` sums each column of the gathered
-    products from the top, which is ``segment_sum``'s order.
+
+def slot_major(entries, rowptr, width: int, fill) -> np.ndarray:
+    """CSR-order ``entries`` laid out ``(width, rows)`` by row slot.
+
+    Entry ``[k, i]`` is row ``i``'s ``k``-th entry, in CSR order within
+    the row, while ``k`` is below the row's length, and ``fill`` past it.
+    Rows run along the minor axis, which the TPU tiles as lanes: a
+    ``(rows, width)`` store with a short minor axis would pad it to 128.
+    ``sparse.spmv.gather_scatter`` sums each column of the products from
+    the top, which is ``segment_sum``'s order.  ``csr_order`` inverts it.
     """
+    entries = np.asarray(entries)
     rowptr = np.asarray(rowptr, np.int64)
     k = np.arange(width, dtype=np.int64)[:, None]
-    pos = rowptr[None, :-1] + k
-    return np.where(k < np.diff(rowptr)[None, :], pos,
-                    sentinel).astype(np.int32)
+    pos = np.where(k < np.diff(rowptr)[None, :], rowptr[None, :-1] + k,
+                   entries.size)
+    return np.append(entries, np.asarray(fill, entries.dtype))[pos]
+
+
+def csr_order(store, rowptr) -> np.ndarray:
+    """A stored entry array in CSR order, as a host array: the identity on
+    a CSR-order ``(nnz,)`` array, and on a slot-major ``(W, rows)`` one
+    (``slot_major``) each row's entries in slot order, padding dropped.
+    The one way back to CSR order for the host-side packers."""
+    store = np.asarray(store)
+    if not is_slot_major(store):
+        return store
+    lens = np.diff(np.asarray(rowptr, np.int64))
+    real = np.arange(store.shape[0])[:, None] < lens[None, :]
+    return store.T[real.T]
 
 
 def vector_stream_bytes(op, dtype=jnp.float64) -> int:
@@ -726,11 +780,12 @@ def pack_sell(a: GSECSR, c: int = 8, sigma: int | None = None,
     order, bucket_w, sigma_eff = sell_slices(a.rowptr, c=c, sigma=sigma,
                                              lane=lane, bucket=bucket)
     widths = tuple(int(w) for w in sorted(set(bucket_w.tolist())))
+    c_ord = a.in_csr_order()
     segs = [
-        (a.colpak, np.uint32),
-        (a.head, np.uint16),
-        (a.tail1, np.uint16),
-        (a.tail2, np.uint32),
+        (c_ord.colpak, np.uint32),
+        (c_ord.head, np.uint16),
+        (c_ord.tail1, np.uint16),
+        (c_ord.tail2, np.uint32),
     ]
     gather = np.zeros(a.nnz, np.int64)
     perm_parts, flat_off = [], 0
@@ -749,6 +804,11 @@ def pack_sell(a: GSECSR, c: int = 8, sigma: int | None = None,
             else np.zeros(0, np.int64))
     unperm = np.zeros(m, np.int64)
     unperm[perm[perm >= 0]] = np.nonzero(perm >= 0)[0]
+    if a.slot_major:
+        # Straight into the operand's slot-major order; padding reads the
+        # padding entry the decode appends after the buckets.
+        rowptr = np.asarray(a.rowptr, np.int64)
+        gather = slot_major(gather, rowptr, a.colpak.shape[0], flat_off)
     return GSESellC(
         colpak=tuple(jnp.asarray(outs[w][0]) for w in widths),
         head=tuple(jnp.asarray(outs[w][1]) for w in widths),
@@ -759,7 +819,6 @@ def pack_sell(a: GSECSR, c: int = 8, sigma: int | None = None,
         unperm=jnp.asarray(unperm, jnp.int32),
         row_ids=a.row_ids,
         table=a.table,
-        slot_map=a.slot_map,
         widths=widths,
         c=c,
         sigma=int(sigma_eff),

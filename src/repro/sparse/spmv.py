@@ -4,10 +4,11 @@ All variants follow the paper's compute discipline: values are *stored* at
 the target precision but multiply-accumulate happens at high precision
 (f64 on CPU; f32 or two-float on TPU -- ``acc_dtype``).
 
-The jnp implementations sum each row over the operand's static row-slot
-map (``csr.row_slots``): one gather of the products into a ``(W, rows)``
-layout and a sum over its W slots, in ``segment_sum``'s order.  An operand
-without a map (plain ``CSR`` baselines, rows too skewed for one) keeps
+The jnp implementations sum each row of an operand stored slot-major
+(``csr.slot_major``) as a reduce over its W slots: the decode yields the
+``(W, rows)`` values and columns, so ``x[col]`` is the one gather, and
+the sum runs in ``segment_sum``'s order.  An operand stored in CSR order
+(plain ``CSR`` baselines, rows too skewed for slots) keeps
 ``segment_sum`` over precomputed row ids, which XLA lowers to a
 scatter-add (DESIGN.md §19).  The Pallas blocked-ELL kernel
 (``repro.kernels.gse_spmv``) is the TPU-tiled version of the same math.
@@ -25,47 +26,70 @@ import jax.numpy as jnp
 from repro.core import gse
 from repro.obs import metrics as OM
 from repro.obs import trace as OT
-from repro.sparse.csr import CSR, GSECSR, GSESellC
+from repro.sparse.csr import CSR, GSECSR, GSESellC, is_slot_major
 
 __all__ = ["spmv", "spmv_gse", "spmv_ell", "spmm", "spmm_gse",
            "decode_gsecsr", "decode_operand", "gather_scatter",
-           "spmv_operand"]
+           "gather_products", "sum_rows", "spmv_operand"]
 
 
 # Which row reduction each traced SpMV took: counted once a trace, so it
 # says what every compiled program runs at no cost per call.
 ROW_REDUCTION = OM.REGISTRY.counter(
     "spmv_row_reduction_total",
-    "Traced SpMVs by row reduction: the static slot map or segment_sum.",
+    "Traced SpMVs by row reduction: over a slot-major store or segment_sum.",
     labelnames=("path",))
 
 
-def gather_scatter(val, col, x, row_ids, num_rows, acc_dtype,
-                   slot_map=None):
-    """``segment_sum(val * x[col], row_ids)``, the SpMV after its decode,
-    under the ``gather`` and ``scatter`` scopes.  ``x`` is ``(n,)`` or an
-    ``(n, nrhs)`` block.
+def gather_scatter(val, col, x, row_ids, num_rows, acc_dtype):
+    """``segment_sum(val * x[col], row_ids)``, the SpMV after its decode:
+    ``gather_products`` then ``sum_rows``.  ``x`` is ``(n,)`` or an
+    ``(n, nrhs)`` block."""
+    return sum_rows(gather_products(val, col, x, acc_dtype), row_ids,
+                    num_rows)
 
-    With a ``(W, num_rows)`` row-slot map (``csr.row_slots``) the rows are
-    summed without a scatter: the products, one zero appended as the
-    padding slots' sentinel, are gathered into the map's layout and
-    reduced over its slot axis.  XLA's CPU backend runs that reduce from
-    zero down the W slots, each row's terms in CSR order, so it is
-    bitwise ``segment_sum``'s sum; and a reduce, unlike chained adds, is
-    not fused into a consuming dot, whose own summation order would then
-    change.  ``slot_map=None`` keeps ``segment_sum``, which drops entries
-    whose row id is ``num_rows``."""
+
+def gather_products(val, col, x, acc_dtype):
+    """``val * x[col]`` in the decode's entry order, under the ``gather``
+    scope.
+
+    For ``(W, rows)`` values and columns of a slot-major store
+    (``csr.slot_major``), ``x`` gets one zero appended, which the padding
+    entries' column ``n`` reads, and the padding's products (+0.0
+    already) are selected to zero: the select stands between each
+    multiply and the reduce's add, which XLA's CPU backend would
+    otherwise contract into an FMA, unlike ``segment_sum``, which rounds
+    every product first.  On a v5e the select costs nothing measurable
+    (DESIGN.md §19)."""
     with OT.scope(OT.GATHER):
-        xg = x.astype(acc_dtype)[col]
-        prod = val * xg if x.ndim == 1 else val[:, None] * xg
+        xa = x.astype(acc_dtype)
+        if not is_slot_major(col):
+            xg = xa[col]
+            return val * xg if x.ndim == 1 else val[:, None] * xg
+        n = xa.shape[0]
+        xa = jnp.concatenate([xa, jnp.zeros((1,) + xa.shape[1:], acc_dtype)])
+        xg = xa[col]
+        if x.ndim == 1:
+            return jnp.where(col < n, val * xg, 0.0)
+        return jnp.where((col < n)[..., None], val[..., None] * xg, 0.0)
+
+
+def sum_rows(prod, row_ids, num_rows):
+    """Each row's sum of ``gather_products``' terms, under the ``scatter``
+    scope.  ``row_ids`` is in the same entry order: ``(W, rows)`` for a
+    slot-major store, whose terms are reduced over the slot axis; XLA's
+    CPU backend runs that reduce from zero down the W slots, each row's
+    terms in CSR order, so it is bitwise ``segment_sum``'s sum, and a
+    reduce, unlike chained adds, is not fused into a consuming dot, whose
+    own summation order would then change.  CSR-order ``(nnz,)`` terms
+    keep ``segment_sum``, which drops entries whose row id is
+    ``num_rows``."""
     with OT.scope(OT.SCATTER):
-        if slot_map is None:
+        if not is_slot_major(row_ids):
             ROW_REDUCTION.labels(path="segment_sum").inc()
             return jax.ops.segment_sum(prod, row_ids, num_segments=num_rows)
-        ROW_REDUCTION.labels(path="slots").inc()
-        zero = jnp.zeros((1,) + prod.shape[1:], prod.dtype)
-        terms = jnp.concatenate([prod, zero])[slot_map]
-        return jnp.sum(terms, axis=0)
+        ROW_REDUCTION.labels(path="slot_major").inc()
+        return jnp.sum(prod, axis=0)
 
 
 @partial(jax.jit, static_argnames=("store_dtype", "acc_dtype", "num_rows"))
@@ -81,6 +105,18 @@ def spmv(a: CSR, x: jnp.ndarray, store_dtype=jnp.float64, acc_dtype=jnp.float64)
     return _spmv_cast(
         a.row_ids, a.col, a.val, x, store_dtype, acc_dtype, a.shape[0]
     )
+
+
+def _table_lookup(table, idx):
+    """``table[idx]`` for the shared-exponent table, as a chain of
+    selects.  On a v5e a gather from the table over a ``(W, rows)``
+    slot-major index runs element by element, and so does one over the
+    flat index once the table holds 97 entries; the selects are the
+    cheapest read measured at every table size (DESIGN.md §19)."""
+    out = jnp.broadcast_to(table[0], idx.shape)
+    for j in range(1, table.shape[0]):
+        out = jnp.where(idx == j, table[j], out)
+    return out
 
 
 @partial(jax.jit, static_argnames=("ei_bit", "tag", "acc_dtype", "num_rows"))
@@ -107,7 +143,7 @@ def _decode_gsecsr(colpak, head, tail1, tail2, table, ei_bit, tag, acc_dtype,
             + tail2.astype(acc_dtype)
         )
         bits_used = 63
-    e_sh = table[exp_idx].astype(jnp.int32) - 1023
+    e_sh = _table_lookup(table, exp_idx).astype(jnp.int32) - 1023
     pow_ = e_sh - bits_used
     half = pow_ // 2
     sgn = 1.0 - 2.0 * sign.astype(acc_dtype)
@@ -119,37 +155,44 @@ def _decode_gsecsr(colpak, head, tail1, tail2, table, ei_bit, tag, acc_dtype,
 
 
 def decode_gsecsr(a: GSECSR, tag: int, acc_dtype=jnp.float64):
-    """(values, columns) decoded from a GSE-SEM CSR at precision ``tag``."""
+    """(values, columns) decoded from a GSE-SEM CSR at precision ``tag``,
+    in its stored entry order (``csr.csr_order`` gives CSR order)."""
     return _decode_gsecsr(
         a.colpak, a.head, a.tail1, a.tail2, a.table, a.ei_bit, tag, acc_dtype
     )
 
 
-def _sell_csr_segments(a: GSESellC):
-    """CSR-order (colpak, head, tail1, tail2) gathered out of the packed
-    SELL-C-σ bucket arrays.
+def _sell_segments(a: GSESellC):
+    """(colpak, head, tail1, tail2) gathered out of the packed SELL-C-σ
+    bucket arrays in the ``GSECSR``'s entry order.
 
     The packed layout IS the value store: ``gather`` addresses every real
-    entry inside the flattened width-buckets, so the recovered segments
-    are bit-for-bit the ``GSECSR`` arrays and everything downstream of
-    this gather (decode, segment reduction, solver iterations) is exactly
-    the CSR reference arithmetic (DESIGN.md §12).
+    entry inside the flattened width-buckets, and a slot-major gather's
+    padding the one padding entry appended after them (zero segments,
+    column ``shape[1]``), so the recovered segments are bit-for-bit the
+    ``GSECSR`` arrays and everything downstream of this gather (decode,
+    row reduction, solver iterations) is exactly the ``GSECSR`` reference
+    arithmetic (DESIGN.md §12).
     """
-    def take(parts):
-        return jnp.concatenate([p.reshape(-1) for p in parts])[a.gather]
+    def take(parts, pad):
+        flat = [p.reshape(-1) for p in parts]
+        flat.append(jnp.full((1,), pad, parts[0].dtype))
+        return jnp.concatenate(flat)[a.gather]
 
-    return take(a.colpak), take(a.head), take(a.tail1), take(a.tail2)
+    return (take(a.colpak, a.shape[1]), take(a.head, 0), take(a.tail1, 0),
+            take(a.tail2, 0))
 
 
 def decode_operand(a, tag: int, acc_dtype=jnp.float64):
-    """CSR-order ``(values, columns)`` decode of a ``GSECSR`` OR a packed
-    ``GSESellC`` at precision ``tag`` -- the one dispatch point the fused
-    solver steps and the reference SpMV/SpMM share, so every solver path
-    rides whichever layout the caller packed, bit-identically.  Runs
-    under the ``decode`` scope (the SELL segment gather included)."""
+    """``(values, columns)`` decode, in the stored entry order, of a
+    ``GSECSR`` OR a packed ``GSESellC`` at precision ``tag`` -- the one
+    dispatch point the fused solver steps and the reference SpMV/SpMM
+    share, so every solver path rides whichever layout the caller packed,
+    bit-identically.  Runs under the ``decode`` scope (the SELL segment
+    gather included)."""
     with OT.scope(OT.DECODE):
         if isinstance(a, GSESellC):
-            cp, hd, t1, t2 = _sell_csr_segments(a)
+            cp, hd, t1, t2 = _sell_segments(a)
             return _decode_gsecsr(cp, hd, t1, t2, a.table, a.ei_bit, tag,
                                   acc_dtype)
         return _decode_gsecsr(
@@ -159,15 +202,14 @@ def decode_operand(a, tag: int, acc_dtype=jnp.float64):
 
 
 @partial(jax.jit, static_argnames=("tag", "acc_dtype", "num_rows", "ei_bit"))
-def _spmv_gse(colpak, head, tail1, tail2, table, row_ids, slot_map, x,
-              ei_bit, tag, acc_dtype, num_rows):
+def _spmv_gse(colpak, head, tail1, tail2, table, row_ids, x, ei_bit, tag,
+              acc_dtype, num_rows):
     with OT.scope(OT.SPMV):
         with OT.scope(OT.DECODE):
             val, col = _decode_gsecsr(
                 colpak, head, tail1, tail2, table, ei_bit, tag, acc_dtype
             )
-        return gather_scatter(val, col, x, row_ids, num_rows, acc_dtype,
-                              slot_map)
+        return gather_scatter(val, col, x, row_ids, num_rows, acc_dtype)
 
 
 def spmv_operand(a, x, tag: int, acc_dtype=jnp.float64):
@@ -176,8 +218,7 @@ def spmv_operand(a, x, tag: int, acc_dtype=jnp.float64):
     The fused solver steps inline it; ``spmv_gse`` jits it for SELL."""
     with OT.scope(OT.SPMV):
         val, col = decode_operand(a, tag, acc_dtype)
-        return gather_scatter(val, col, x, a.row_ids, a.shape[0], acc_dtype,
-                              a.slot_map)
+        return gather_scatter(val, col, x, a.row_ids, a.shape[0], acc_dtype)
 
 
 _spmv_gse_sell = partial(jax.jit, static_argnames=("tag", "acc_dtype"))(
@@ -189,7 +230,8 @@ def spmv_gse(a, x: jnp.ndarray, tag: int = 1, acc_dtype=jnp.float64):
 
     ``a`` is a ``GSECSR`` or a SELL-C-σ packed ``GSESellC``; the two are
     bit-identical here (the SELL path gathers the SAME segment bits back
-    to CSR order before the shared decode + segment reduction), they
+    to the operand's entry order before the shared decode + row
+    reduction), they
     differ only in what the kernels stream and what the byte model
     charges (``a.bytes_touched(tag)``: nnz-only for ``GSECSR``, actual
     padded slots for ``GSESellC``; DESIGN.md §12).
@@ -206,8 +248,8 @@ def spmv_gse(a, x: jnp.ndarray, tag: int = 1, acc_dtype=jnp.float64):
     if isinstance(a, GSESellC):
         return _spmv_gse_sell(a, x, tag, acc_dtype)
     return _spmv_gse(
-        a.colpak, a.head, a.tail1, a.tail2, a.table, a.row_ids, a.slot_map,
-        x, a.ei_bit, tag, acc_dtype, a.shape[0]
+        a.colpak, a.head, a.tail1, a.tail2, a.table, a.row_ids, x,
+        a.ei_bit, tag, acc_dtype, a.shape[0]
     )
 
 

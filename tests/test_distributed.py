@@ -179,8 +179,9 @@ def test_dist_spmv_bitwise_equals_reference(shards, tag):
 @pytest.mark.parametrize("tag", [1, 3])
 def test_dist_spmv_ragged_rows_bitwise(shards, tag):
     """Rows of 0..7 entries over shard counts that leave padded rows and
-    padding entries: each shard's row-slot map skips both, and the
-    sharded SpMV/SpMM stays bitwise the single-device one."""
+    padding entries: each shard stores its rows slot-major, the padding
+    adding nothing, and the sharded SpMV/SpMM stays bitwise the
+    single-device one."""
     from repro.kernels.dist_spmv import dist_spmm, dist_spmv
     from repro.sparse.csr import from_coo
 
@@ -192,7 +193,7 @@ def test_dist_spmv_ragged_rows_bitwise(shards, tag):
     a = from_coo(rows, cols, rng.standard_normal(rows.size), (n, n))
     g = pack_csr(a, k=8)
     part = partition_gsecsr(g, shards)
-    assert part.slot_map is not None and part.n_padded > n
+    assert part.colpak.ndim == 3 and part.n_padded > n
     assert len(set(part.nnz_per_shard)) > 1
     x = jnp.asarray(rng.normal(size=n))
     assert np.array_equal(np.asarray(spmv_gse(g, x, tag=tag)),

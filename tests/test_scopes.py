@@ -153,7 +153,7 @@ _SHARDED = textwrap.dedent("""
         bp = S._pad_to(b, part.n_padded)
         text = fn.lower(part.colpak, part.head, part.tail1, part.tail2,
                         part.row_ids, part.bnd_idx, part.halo_idx,
-                        part.table, part.slot_map, *diag[kind], bp,
+                        part.table, *diag[kind], bp,
                         jnp.zeros_like(bp),
                         jnp.asarray(1e-8), jnp.linalg.norm(b)
                         ).compile().as_text()

@@ -185,7 +185,8 @@ def test_uniform_identity_ir():
 
 def _per_entry_reference(g, tm):
     """NumPy oracle: every entry decoded at its own symmetric induced
-    tag, straight from the flat packed segments."""
+    tag, straight from the packed segments in CSR order."""
+    g = g.in_csr_order()
     cols = (np.asarray(g.colpak, np.uint32)
             & np.uint32((1 << (32 - g.ei_bit)) - 1)).astype(np.int64)
     et = tm.entry_tags(np.asarray(g.row_ids), cols)
@@ -202,7 +203,7 @@ def _per_entry_reference(g, tm):
 def test_masked_decode_matches_per_entry_numpy(lo, hi):
     _, g, _ = _sys(seed=7)
     tm = _mixed_map(int(g.shape[0]), lo=lo, hi=hi)
-    masked = ops.masked_for_tagmap(g, tm)
+    masked = ops.masked_for_tagmap(g, tm).in_csr_order()
     got = np.asarray(ref.decode_csr_ref(
         masked.colpak, masked.head, masked.tail1, masked.tail2,
         masked.table, masked.ei_bit, tm.max_tag), np.float64)
@@ -222,7 +223,8 @@ def test_masked_matvec_matches_per_entry_numpy():
                                 jnp.int32(tm.max_tag)))
     vals, cols = _per_entry_reference(g, tm)
     want = np.zeros(m, np.float64)
-    np.add.at(want, np.asarray(g.row_ids, np.int64), vals * x[cols])
+    np.add.at(want, np.asarray(g.in_csr_order().row_ids, np.int64),
+              vals * x[cols])
     np.testing.assert_allclose(got, want, rtol=1e-13, atol=1e-13)
 
 
@@ -232,7 +234,8 @@ def test_masked_operand_stays_symmetric():
     _, g, _ = _sys(seed=9)
     m = int(g.shape[0])
     tm = _mixed_map(m, lo=1, hi=3, period=2)
-    masked = ops.masked_for_tagmap(g, tm)
+    masked = ops.masked_for_tagmap(g, tm).in_csr_order()
+    g = g.in_csr_order()
     vals = np.asarray(ref.decode_csr_ref(
         masked.colpak, masked.head, masked.tail1, masked.tail2,
         masked.table, masked.ei_bit, tm.max_tag), np.float64)
@@ -256,9 +259,10 @@ def test_bytes_touched_blend_gsecsr():
         assert g.bytes_touched(TagMap.for_rows(m, t)) == g.bytes_touched(t)
     # A mixed map blends per symmetric induced entry tag, exactly.
     tm = _mixed_map(m)
-    cols = (np.asarray(g.colpak, np.uint32)
+    c = g.in_csr_order()
+    cols = (np.asarray(c.colpak, np.uint32)
             & np.uint32((1 << (32 - g.ei_bit)) - 1)).astype(np.int64)
-    et = tm.entry_tags(np.asarray(g.row_ids), cols)
+    et = tm.entry_tags(np.asarray(c.row_ids), cols)
     per_nnz = {1: 6, 2: 8, 3: 12}
     fixed = (np.asarray(g.rowptr).size + np.asarray(g.table).size) * 4
     want = fixed + sum(per_nnz[t] * int((et == t).sum()) for t in (1, 2, 3))
@@ -434,7 +438,7 @@ def test_masked_decode_parity_random_maps_property():
                     min_size=ng, max_size=ng))
     def check(tags):
         tm = TagMap(np.asarray(tags, np.uint8))
-        masked = ops.masked_for_tagmap(g, tm)
+        masked = ops.masked_for_tagmap(g, tm).in_csr_order()
         got = np.asarray(ref.decode_csr_ref(
             masked.colpak, masked.head, masked.tail1, masked.tail2,
             masked.table, masked.ei_bit, tm.max_tag), np.float64)

@@ -24,7 +24,6 @@ from repro.kernels.gse_spmv import gse_spmv_call, gse_spmv_sell_call
 from repro.sparse.spmv import _spmv_gse
 
 N = 128 ** 3                      # poisson3d(128) unknowns
-NNZ = 7 * N - 6 * 128 ** 2        # its 7-point stencil's nonzeros
 L = 128                           # ELL width (7 per row, lane-padded)
 K = 8                             # shared-exponent table entries
 EI_BIT = 3
@@ -121,16 +120,17 @@ def test_sell_bucket_compiles_for_v5e(one_chip):
 
 @pytest.mark.parametrize("tag", [1, 3])
 def test_f64_jnp_spmv_compiles_for_v5e(one_chip, tag):
-    """The decode + row-slot reduction SpMV the stepped solvers run, in
-    float64 (emulated on the v5e), at poisson3d(128)'s CSR shapes and its
-    (7, rows) slot map."""
+    """The decode + slot-major row reduction SpMV the stepped solvers run,
+    in float64 (emulated on the v5e), at poisson3d(128)'s shapes: its
+    segments and row ids stored (7, rows) by row slot."""
     s = one_chip
 
-    def spmv(cp, hd, t1, t2, table, row_ids, slot_map, x):
-        return _spmv_gse(cp, hd, t1, t2, table, row_ids, slot_map, x, EI_BIT,
-                         tag, jnp.float64, N)
+    def spmv(cp, hd, t1, t2, table, row_ids, x):
+        return _spmv_gse(cp, hd, t1, t2, table, row_ids, x, EI_BIT, tag,
+                         jnp.float64, N)
 
-    _compile(spmv, _spec(s, (NNZ,), jnp.uint32), _spec(s, (NNZ,), jnp.uint16),
-             _spec(s, (NNZ,), jnp.uint16), _spec(s, (NNZ,), jnp.uint32),
-             _spec(s, (K,), jnp.int32), _spec(s, (NNZ,), jnp.int32),
-             _spec(s, (7, N), jnp.int32), _spec(s, (N,), jnp.float64))
+    slots = (7, N)
+    _compile(spmv, _spec(s, slots, jnp.uint32), _spec(s, slots, jnp.uint16),
+             _spec(s, slots, jnp.uint16), _spec(s, slots, jnp.uint32),
+             _spec(s, (K,), jnp.int32), _spec(s, slots, jnp.int32),
+             _spec(s, (N,), jnp.float64))

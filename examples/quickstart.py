@@ -54,10 +54,9 @@ def main():
                      for t in (1, 2, 3)))
 
     # --- 3. stepped mixed-precision CG (the paper's algorithm) -----------
-    # Passing the GSECSR directly (instead of make_gse_operator(g))
-    # selects the fused iteration path: one decoded-value pass per step
-    # with the dots/axpys folded around the SpMV -- bit-identical
-    # trajectory, fewer kernel launches (DESIGN.md §4).
+    # Each iteration is one step at the monitor's tag: one decoded-value
+    # pass with the dots/axpys folded around the SpMV (DESIGN.md §4).  The
+    # GSECSR rides the loop as a packed operand.
     x_true = rng.normal(size=a.shape[1])
     from repro.sparse.spmv import spmv
 
@@ -66,21 +65,21 @@ def main():
         g, b, tol=1e-8, maxiter=3000,
         params=MonitorParams(t=40, l=60, m=30),
     )
-    print(f"\nstepped CG (fused): converged={bool(res.converged)} "
+    print(f"\nstepped CG: converged={bool(res.converged)} "
           f"iters={int(res.iters)} final tag={int(res.tag)} "
           f"relres={float(res.relres):.2e} "
           f"switches at {res.switch_iters.tolist()}")
     err = np.abs(np.asarray(res.x) - x_true).max()
     print(f"solution max abs error vs truth: {err:.2e}")
 
-    # The generic-operator path produces the same trajectory:
+    # The same operator as a callable runs the same loop, bit-identically:
     res2 = solve_cg(
         make_gse_operator(g), b, tol=1e-8, maxiter=3000,
         params=MonitorParams(t=40, l=60, m=30),
     )
     agrees = (int(res2.iters) == int(res.iters)
               and float(res2.relres) == float(res.relres))
-    print(f"unfused path agrees: {agrees} (iters={int(res2.iters)}, "
+    print(f"callable operator agrees: {agrees} (iters={int(res2.iters)}, "
           f"relres={float(res2.relres):.2e})")
 
     # --- 4. preconditioned stepped CG on an ill-conditioned system ------

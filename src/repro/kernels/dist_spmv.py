@@ -2,7 +2,7 @@
 
 Each shard streams ITS slice of the packed segment arrays through the
 SAME tag-specialized decode the single-device solvers use
-(``sparse.spmv._decode_gsecsr`` -- the fused CG/PCG steps' decode), then
+(``sparse.spmv._decode_gsecsr`` -- the CG/PCG step's decode), then
 sums its rows locally over its slot-major store (``sum_rows``).  What
 crosses the interconnect is only the boundary x-entries, through the
 tag-aware halo exchange (``distributed.wire.halo_all_gather``): a tag-1
@@ -19,7 +19,7 @@ Entry points:
     the decode is shared) -- asserted in tests/test_distributed.py.
   * ``make_sharded_operator(part)`` -- memoized ``apply(v, tag)`` closure
     (traced tag via ``lax.switch``) usable anywhere the solvers accept an
-    operator callable: generic CG/PCG, GMRES, batched, IR.
+    operator callable: CG/PCG, GMRES, batched, IR.
   * ``local_matvec``/``switched_matvec``/``shard_mesh`` -- building
     blocks the fully-sharded solver loop (``solvers.sharded``) reuses
     inside its own shard_map.
@@ -317,9 +317,9 @@ def make_sharded_operator(part: PartitionedGSECSR, wire: str = "exact",
                           plan: KernelPlan | None = None):
     """Tag-dispatched ``apply(v, tag)`` over the partition, memoized on the
     instance (the closure is a static jit argument in the solvers -- the
-    sharded twin of ``solvers.cg._gsecsr_operator``).  Accepts ``(n,)``
+    sharded twin of ``solvers.operators.make_gse_operator``).  Accepts ``(n,)``
     vectors and ``(n, nrhs)`` blocks; usable as the operator callable in
-    every solver path (generic CG/PCG, GMRES, batched, IR)."""
+    every solver path (CG/PCG, GMRES, batched, IR)."""
     key = ("_sharded_operator", wire, jnp.dtype(acc_dtype).name, plan)
     op = part.__dict__.get(key)
     if op is None:

@@ -263,7 +263,7 @@ def masked_for_tagmap(a, tm: TagMap):
     the max-tag power-of-two scale equals the lower-tag decode exactly
     (``m_head * 2^48 * 2^(e_sh-63) == m_head * 2^(e_sh-15)``; both
     factors are exact powers of two and every partial mantissa fits f64).
-    So every existing tag-specialized pipeline -- fused solver steps, ELL
+    So every existing tag-specialized pipeline -- the solver step, ELL
     and SELL kernels, the reference decode -- applies a non-uniform map
     with NO new kernel bodies.
 

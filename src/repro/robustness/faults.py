@@ -195,8 +195,8 @@ def make_tag_fault_operator(a, mode: str = "indefinite",
     if mode not in ("indefinite", "nan"):
         raise ValueError(f"mode must be 'indefinite' or 'nan', got {mode!r}")
     if isinstance(a, GSECSR):
-        from repro.solvers.cg import _gsecsr_operator
-        base = _gsecsr_operator(a)
+        from repro.solvers.operators import make_gse_operator
+        base = make_gse_operator(a)
     else:
         base = a
 

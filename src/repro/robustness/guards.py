@@ -10,7 +10,7 @@ breakdown used to mean one of two silent failure modes:
 
 The guard runs alongside the update (never inside it -- the update
 arithmetic is bit-identical with guards on or off, which is what keeps
-the fused/unfused, SELL-vs-CSR and 1-shard-vs-``solve_cg`` contracts
+the operand-vs-callable, SELL-vs-CSR and 1-shard-vs-``solve_cg`` contracts
 intact).  Each iteration classifies the new state into one of five
 health codes and the loop condition adds ``health == OK``, so a tripped
 guard stops the loop at the trip iteration instead of burning budget.

@@ -18,8 +18,9 @@ The solver entry points grew three hooks for this (DESIGN.md §17):
 
 The drivers here wrap those hooks per solver family:
 
-  * :class:`SolveChunks` -- single-RHS CG/PCG (fused, generic, or the
-    row-sharded operator via the generic body).
+  * :class:`SolveChunks` -- single-RHS CG/PCG: the single-chip loop
+    (``cg._loop``) over a packed operand, a callable, or the row-sharded
+    operator as a callable.
   * :class:`BatchedChunks` -- the batched multi-RHS loop, plus
     ``join``/``drop``: a column added at a chunk boundary starts from
     the exact init a solo solve would run, and runs the exact per-column
@@ -44,22 +45,12 @@ import numpy as np
 from repro.checkpoint import ckpt as CK
 from repro.core import precision as P
 from repro.robustness.guards import HEALTH_OK, GuardParams
-from repro.sparse.csr import GSECSR, GSESellC
 from repro.solvers.batched import (
     _maybe_sharded,
     _normalize_block,
-    _solve_cg_batched,
-    _solve_cg_batched_fused,
-    _solve_pcg_batched,
-    _solve_pcg_batched_fused,
+    _solve_batched,
 )
-from repro.solvers.cg import (
-    _gsecsr_operator,
-    _solve_cg,
-    _solve_cg_fused,
-    _solve_pcg,
-    _solve_pcg_fused,
-)
+from repro.solvers.cg import _loop
 from repro.solvers.ir import _ir_active, _ir_result, _ir_setup, _ir_step
 
 __all__ = ["SolveChunks", "BatchedChunks", "IRChunks"]
@@ -97,24 +88,9 @@ class SolveChunks:
         self.guards = guards
         self.init_tag = init_tag
         op = _maybe_sharded(op, wire)
-        fused = isinstance(op, (GSECSR, GSESellC))
-        if precond is None:
-            entry = _solve_cg_fused if fused else _solve_cg
-            self._call = lambda **kw: entry(
-                op, self.b, self.x0, self.tol, self.maxiter, self.params,
-                init_tag=self.init_tag, guards=self.guards, **kw)
-        elif fused and hasattr(precond, "apply_at"):
-            self._call = lambda **kw: _solve_pcg_fused(
-                op, precond, self.b, self.x0, self.tol, self.maxiter,
-                self.params, init_tag=self.init_tag, guards=self.guards,
-                **kw)
-        else:
-            apply_m = precond if callable(precond) else precond.apply
-            apply_a = _gsecsr_operator(op) if fused else op
-            self._call = lambda **kw: _solve_pcg(
-                apply_a, apply_m, self.b, self.x0, self.tol, self.maxiter,
-                self.params, init_tag=self.init_tag, guards=self.guards,
-                **kw)
+        self._call = lambda **kw: _loop(
+            op, precond, self.b, self.x0, self.tol, self.maxiter,
+            self.params, init_tag=self.init_tag, guards=self.guards, **kw)
         self._state = None
         self.res = None
         self.ckpt = None
@@ -222,23 +198,9 @@ class BatchedChunks:
         self.init_tag = init_tag
         self.precond = precond
         op = _maybe_sharded(op, wire)
-        fused = isinstance(op, (GSECSR, GSESellC))
-        if precond is None:
-            entry = _solve_cg_batched_fused if fused else _solve_cg_batched
-            self._call = lambda b_, x0_, **kw: entry(
-                op, b_, x0_, self.tol, self.maxiter, self.params,
-                init_tag=self.init_tag, guards=self.guards, **kw)
-        elif fused and hasattr(precond, "apply_at"):
-            self._call = lambda b_, x0_, **kw: _solve_pcg_batched_fused(
-                op, precond, b_, x0_, self.tol, self.maxiter, self.params,
-                init_tag=self.init_tag, guards=self.guards, **kw)
-        else:
-            apply_m = precond if callable(precond) else precond.apply
-            apply_a = _gsecsr_operator(op) if fused else op
-            self._call = lambda b_, x0_, **kw: _solve_pcg_batched(
-                apply_a, apply_m, b_, x0_, self.tol, self.maxiter,
-                self.params, init_tag=self.init_tag, guards=self.guards,
-                **kw)
+        self._call = lambda b_, x0_, **kw: _solve_batched(
+            op, precond, b_, x0_, self.tol, self.maxiter, self.params,
+            init_tag=self.init_tag, guards=self.guards, **kw)
         # Initialize every column WITHOUT iterating (per-column stop_at=0):
         # the same trick join uses, so first-wave and joined columns get
         # identical init treatment.

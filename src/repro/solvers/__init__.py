@@ -2,7 +2,7 @@
 
 Beyond-paper subsystem (DESIGN.md §10): GSE-packed preconditioners that
 ride the operator's tag schedule (``precond``), preconditioned CG
-(``solve_pcg``, with a fused iteration path) and right-preconditioned
+(``solve_pcg``, the same iteration as CG, ``cg_step``) and right-preconditioned
 GMRES (``solve_gmres(..., precond=...)``), plus a stepped
 iterative-refinement driver (``solve_ir``).
 
@@ -42,7 +42,7 @@ from repro.solvers.batched import (
 )
 from repro.solvers.adaptive import AdaptiveResult, solve_adaptive
 from repro.solvers.cg import CGResult, solve_cg, solve_pcg
-from repro.solvers.fused_cg import fused_cg_step, fused_pcg_step, gse_matvec
+from repro.solvers.fused_cg import cg_step
 from repro.solvers.gmres import GMRESResult, solve_gmres
 from repro.solvers.ir import IRResult, solve_ir
 from repro.solvers.multigrid import MGPrecond, make_mg
@@ -78,9 +78,7 @@ __all__ = [
     "solve_ir_batched",
     "solve_cg_sharded",
     "solve_pcg_sharded",
-    "fused_cg_step",
-    "fused_pcg_step",
-    "gse_matvec",
+    "cg_step",
     "GMRESResult",
     "solve_gmres",
     "IRResult",

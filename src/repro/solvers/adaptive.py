@@ -197,9 +197,9 @@ def solve_adaptive(
 
     ``a`` must be a packed ``GSECSR`` (the floor model reads the flat
     packed segments; pass the CSR pack even if you normally solve
-    through a SELL view -- the masked operand rides the same fused
+    through a SELL view -- the masked operand rides the same
     iteration).  ``precond`` selects PCG for the MAIN solve: a
-    ``solvers.precond`` object (fused path) or any callable
+    ``solvers.precond`` object or any callable
     ``apply_m(r, tag)``; the optional planning probe always uses its
     own host-built Jacobi regardless.
 
@@ -227,10 +227,8 @@ def solve_adaptive(
     reactively from the current iterate and restarts.
     """
     from repro.kernels.ops import masked_for_tagmap
-    from repro.solvers.cg import (_gsecsr_operator, _normalize_b_x0,
-                                  _pin_params, _solve_cg_fused, _solve_pcg,
-                                  _solve_pcg_fused)
-    from repro.solvers.fused_cg import gse_matvec
+    from repro.solvers.cg import _loop, _normalize_b_x0, _pin_params
+    from repro.solvers.operators import make_gse_operator
 
     if not isinstance(a, GSECSR):
         raise TypeError(
@@ -277,10 +275,9 @@ def solve_adaptive(
                 0, int((tm.tags > 1).sum()), tm.min_tag, tm.max_tag,
                 tm.crc32))
         elif profile == "probe":
-            pr = _solve_pcg(_gsecsr_operator(a), _probe_jacobi(a), b, x,
-                            jnp.asarray(0.0, b.dtype), max(int(probe_iters), 1),
-                            _pin_params(params, 1), init_tag=1,
-                            guards=None, flight=None)
+            pr = _loop(a, _probe_jacobi(a), b, x, jnp.asarray(0.0, b.dtype),
+                       max(int(probe_iters), 1), _pin_params(params, 1),
+                       init_tag=1, guards=None, flight=None)
             probe_done = int(pr.iters)
             bytes_ += (probe_done + 1) * a.bytes_touched(1)
             xh = np.abs(np.asarray(pr.x))
@@ -297,32 +294,14 @@ def solve_adaptive(
             tm = TagMap.for_rows(m, 1, group_size)
             planned = False
 
-        if precond is None:
-            def run_chunk(a_eff, x_start, state, stop, pinned, itag, st):
-                return _solve_cg_fused(a_eff, b, x_start, st, maxiter,
-                                       pinned, init_tag=itag, guards=None,
-                                       flight=None, resume=state,
-                                       stop_at=stop, return_state=True)
-        elif hasattr(precond, "apply_at"):
-            def run_chunk(a_eff, x_start, state, stop, pinned, itag, st):
-                return _solve_pcg_fused(a_eff, precond, b, x_start, st,
-                                        maxiter, pinned, init_tag=itag,
-                                        guards=None, flight=None,
-                                        resume=state, stop_at=stop,
-                                        return_state=True)
-        else:
-            apply_m = precond if callable(precond) else precond.apply
-
-            def run_chunk(a_eff, x_start, state, stop, pinned, itag, st):
-                return _solve_pcg(_gsecsr_operator(a_eff), apply_m, b,
-                                  x_start, st, maxiter, pinned,
-                                  init_tag=itag, guards=None, flight=None,
-                                  resume=state, stop_at=stop,
-                                  return_state=True)
+        def run_chunk(a_eff, x_start, state, stop, pinned, itag, st):
+            return _loop(a_eff, precond, b, x_start, st, maxiter, pinned,
+                         init_tag=itag, guards=None, flight=None,
+                         resume=state, stop_at=stop, return_state=True)
 
         def true_relres(xv) -> float:
-            return float(jnp.linalg.norm(b - gse_matvec(a, xv, jnp.int32(3)))
-                         / bnorm)
+            ax = make_gse_operator(a)(xv, jnp.int32(3))
+            return float(jnp.linalg.norm(b - ax) / bnorm)
 
         def replan(tm, xv, rel, glob, force):
             """(Re)plan from the live iterate: its magnitudes ARE the

@@ -14,9 +14,8 @@ switch-iteration log, and deactivates independently on convergence.
 Bit-identity contract (the subsystem's acceptance bar): column ``j`` of a
 batched solve runs EXACTLY the op sequence of an independent
 ``solve_cg``/``solve_pcg`` on ``b[:, j]`` -- the batch body unrolls the
-same per-column ``fused_cg_step``/``fused_pcg_step`` (or generic-body
-ops) at each column's own traced tag via the same ``lax.switch``
-dispatch, and converged columns are frozen behind a per-column
+same per-column ``fused_cg.cg_step`` at each column's own traced tag,
+and converged columns are frozen behind a per-column
 ``lax.cond`` -- they skip their SpMV/decode entirely instead of being
 dragged further.  Columns that share a tag share one decoded-value pass
 under XLA CSE (the in-jaxpr form of "tag-bucketed sub-batches"); columns
@@ -51,7 +50,9 @@ from repro.robustness.guards import (
     guard_step,
 )
 from repro.sparse.csr import GSECSR, GSESellC, iteration_stream_bytes
-from repro.solvers.cg import _record_switch
+from repro.solvers.cg import _as_arg, _record_switch
+from repro.solvers.fused_cg import cg_step, kdot, start, static_apply
+from repro.solvers.operators import make_gse_operator
 
 __all__ = [
     "BatchedCGResult",
@@ -127,8 +128,8 @@ class BatchedIRResult(NamedTuple):
 
 def _maybe_sharded(apply_a, wire: str):
     """Swap a ``PartitionedGSECSR`` operand for its memoized distributed
-    operator closure (generic-path callable); anything else passes
-    through untouched."""
+    operator closure (a callable operator); anything else passes through
+    untouched."""
     from repro.distributed.partition import PartitionedGSECSR
 
     if isinstance(apply_a, PartitionedGSECSR):
@@ -167,8 +168,8 @@ def _batched_krylov_loop(b, x0, tol, maxiter, params, init_tag,
 
     ``init_col(b_j, x0_j, tag) -> dict`` builds one column's Krylov state
     (must contain ``rr`` = squared residual norm driving the monitor);
-    ``step_col(col_state, tag) -> dict`` runs ONE iteration of the
-    single-RHS solver body at a traced per-column tag.  Everything else
+    ``step_col(col_state, tag) -> dict`` runs ONE iteration, the
+    single-RHS ``cg_step``, at a traced per-column tag.  Everything else
     (monitor record/update, switch logging, convergence masking, per-
     column iteration counts) is identical across CG and PCG.
 
@@ -205,7 +206,6 @@ def _batched_krylov_loop(b, x0, tol, maxiter, params, init_tag,
         for j in range(nrhs):
             mon = P.init(params, dtype=b.dtype, tag=init_tag)
             c = init_col(b[:, j], x0[:, j], mon.tag)
-            c.pop("denom", None)
             if guards is not None:
                 c["g"] = guard_init(jnp.sqrt(jnp.abs(c["rr"])) / bnorms[j])
             if flight is not None:
@@ -236,7 +236,7 @@ def _batched_krylov_loop(b, x0, tol, maxiter, params, init_tag,
     def step_one(j):
         def run(c):
             stepped = step_col(c, c["mon"].tag)
-            denom = stepped.pop("denom", None)
+            denom = stepped.pop("denom")
             relres_new = jnp.sqrt(jnp.abs(stepped["rr"])) / bnorms[j]
             if guards is not None:
                 breakdown = False
@@ -318,66 +318,43 @@ def _batched_krylov_loop(b, x0, tol, maxiter, params, init_tag,
 
 
 # ---------------------------------------------------------------------------
-# Batched CG
+# Batched CG / PCG
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("maxiter", "params", "init_tag", "guards",
                                    "flight", "return_state"))
-def _solve_cg_batched_fused(a, b, x0, tol, maxiter, params, init_tag=1,
-                            guards=None, flight=None, resume=None,
-                            stop_at=None, return_state=False):
-    from repro.solvers.fused_cg import (fused_cg_step, fused_cg_step_g,
-                                        gse_matvec)
+def _batched_jit(a, m, b, x0, tol, maxiter, params, init_tag=1, guards=None,
+                 flight=None, resume=None, stop_at=None, return_state=False):
+    matvec, precond = static_apply(a), static_apply(m)
 
     def init_col(bj, xj, tag):
-        r0 = bj - gse_matvec(a, xj, tag)
-        rs = jnp.vdot(r0, r0)
-        return dict(x=xj, r=r0, p=r0, rr=rs)
+        r0, p0, rz0, rr0 = start(matvec, precond, kdot, bj, xj, tag)
+        c = dict(x=xj, r=r0, p=p0, rr=rr0)
+        if precond is not None:
+            c["rz"] = rz0
+        return c
 
     def step_col(c, tag):
-        if guards is None and flight is None:
-            x, r, p, rs = fused_cg_step(a, c["x"], c["r"], c["p"],
-                                        c["rr"], tag)
-            return dict(x=x, r=r, p=p, rr=rs)
-        x, r, p, rs, denom = fused_cg_step_g(a, c["x"], c["r"], c["p"],
-                                             c["rr"], tag)
-        return dict(x=x, r=r, p=p, rr=rs, denom=denom)
-
-    return _batched_krylov_loop(b, x0, tol, maxiter, params, init_tag,
-                                init_col, step_col, guards, flight,
-                                resume=resume, stop_at=stop_at,
-                                return_state=return_state)
-
-
-@partial(jax.jit, static_argnames=("apply_a", "maxiter", "params", "init_tag",
-                                   "guards", "flight", "return_state"))
-def _solve_cg_batched(apply_a, b, x0, tol, maxiter, params, init_tag=1,
-                      guards=None, flight=None, resume=None, stop_at=None,
-                      return_state=False):
-    def init_col(bj, xj, tag):
-        r0 = bj - apply_a(xj, tag)
-        rs = jnp.vdot(r0, r0)
-        return dict(x=xj, r=r0, p=r0, rr=rs)
-
-    def step_col(c, tag):
-        # EXACTLY the _solve_cg body ops, in order (bit-identity contract).
-        ap = apply_a(c["p"], tag)
-        denom = jnp.vdot(c["p"], ap)
-        alpha = c["rr"] / jnp.where(denom == 0, 1.0, denom)
-        x = c["x"] + alpha * c["p"]
-        r = c["r"] - alpha * ap
-        rs_new = jnp.vdot(r, r)
-        beta = rs_new / jnp.where(c["rr"] == 0, 1.0, c["rr"])
-        p = r + beta * c["p"]
-        out = dict(x=x, r=r, p=p, rr=rs_new)
-        if guards is not None or flight is not None:
-            out["denom"] = denom
+        x, r, p, rz, rr, denom = cg_step(matvec, precond, kdot, c["x"],
+                                         c["r"], c["p"],
+                                         c.get("rz", c["rr"]), tag)
+        out = dict(x=x, r=r, p=p, rr=rr, denom=denom)
+        if precond is not None:
+            out["rz"] = rz
         return out
 
     return _batched_krylov_loop(b, x0, tol, maxiter, params, init_tag,
                                 init_col, step_col, guards, flight,
                                 resume=resume, stop_at=stop_at,
                                 return_state=return_state)
+
+
+def _solve_batched(op, precond, b, x0, tol, maxiter, params, **kw):
+    """The batched loop over any operator and preconditioner form
+    (``precond=None`` is CG); ``kw`` as in ``cg._loop``, with per-column
+    ``stop_at``."""
+    return _batched_jit(_as_arg(op), _as_arg(precond), b, x0, tol, maxiter,
+                        params, **kw)
 
 
 def solve_cg_batched(
@@ -401,13 +378,13 @@ def solve_cg_batched(
     parameters -- same iterates, same iteration count, same switch
     iterations (tested in tests/test_batched.py).
 
-    Passing a ``GSECSR`` selects the fused per-column iteration
-    (``fused_cg_step``), exactly as in single-RHS ``solve_cg``.  Passing a
-    ``PartitionedGSECSR`` rides the row-sharded distributed operator
+    ``apply_a`` is a ``GSECSR``/``GSESellC`` or a callable, as in
+    single-RHS ``solve_cg``.  A ``PartitionedGSECSR`` rides the
+    row-sharded distributed operator
     (``kernels.dist_spmv.make_sharded_operator``; ``wire`` picks the halo
-    wire format, DESIGN.md §13) through the generic per-column body --
-    column ``j`` stays bit-identical to the sharded single-RHS solve's
-    operator applications.  The modeled per-iteration traffic of the
+    wire format, DESIGN.md §13) as a callable -- column ``j`` stays
+    bit-identical to the sharded single-RHS solve's operator
+    applications.  The modeled per-iteration traffic of the
     batch is ``iteration_stream_bytes(a, tag, nrhs=n_active)`` -- matrix
     bytes once, vector bytes per active column; ``batched_run_bytes``
     accounts a whole run from the per-column results.
@@ -434,83 +411,8 @@ def solve_cg_batched(
     apply_a = _maybe_sharded(apply_a, wire)
     with OT.span("solve.cg_batched", n=int(b.shape[0]),
                  nrhs=int(b.shape[1]), tol=float(tol)):
-        if isinstance(apply_a, (GSECSR, GSESellC)):
-            return _solve_cg_batched_fused(apply_a, b, x0, tol_, maxiter,
-                                           params, init_tag=init_tag,
-                                           guards=guards, flight=flight)
-        return _solve_cg_batched(apply_a, b, x0, tol_, maxiter, params,
-                                 init_tag=init_tag, guards=guards,
-                                 flight=flight)
-
-
-# ---------------------------------------------------------------------------
-# Batched PCG
-# ---------------------------------------------------------------------------
-
-@partial(jax.jit, static_argnames=("maxiter", "params", "init_tag", "guards",
-                                   "flight", "return_state"))
-def _solve_pcg_batched_fused(a, m, b, x0, tol, maxiter, params, init_tag=1,
-                             guards=None, flight=None, resume=None,
-                             stop_at=None, return_state=False):
-    from repro.solvers.fused_cg import (fused_pcg_step, fused_pcg_step_g,
-                                        gse_matvec)
-
-    def init_col(bj, xj, tag):
-        r0 = bj - gse_matvec(a, xj, tag)
-        z0 = m.apply(r0, tag)
-        return dict(x=xj, r=r0, p=z0, rz=jnp.vdot(r0, z0),
-                    rr=jnp.vdot(r0, r0))
-
-    def step_col(c, tag):
-        if guards is None and flight is None:
-            x, r, p, rz, rr = fused_pcg_step(
-                a, m, c["x"], c["r"], c["p"], c["rz"], tag
-            )
-            return dict(x=x, r=r, p=p, rz=rz, rr=rr)
-        x, r, p, rz, rr, denom = fused_pcg_step_g(
-            a, m, c["x"], c["r"], c["p"], c["rz"], tag
-        )
-        return dict(x=x, r=r, p=p, rz=rz, rr=rr, denom=denom)
-
-    return _batched_krylov_loop(b, x0, tol, maxiter, params, init_tag,
-                                init_col, step_col, guards, flight,
-                                resume=resume, stop_at=stop_at,
-                                return_state=return_state)
-
-
-@partial(jax.jit, static_argnames=("apply_a", "apply_m", "maxiter", "params",
-                                   "init_tag", "guards", "flight",
-                                   "return_state"))
-def _solve_pcg_batched(apply_a, apply_m, b, x0, tol, maxiter, params,
-                       init_tag=1, guards=None, flight=None, resume=None,
-                       stop_at=None, return_state=False):
-    def init_col(bj, xj, tag):
-        r0 = bj - apply_a(xj, tag)
-        z0 = apply_m(r0, tag)
-        return dict(x=xj, r=r0, p=z0, rz=jnp.vdot(r0, z0),
-                    rr=jnp.vdot(r0, r0))
-
-    def step_col(c, tag):
-        # EXACTLY the _solve_pcg body ops, in order (bit-identity contract).
-        ap = apply_a(c["p"], tag)
-        denom = jnp.vdot(c["p"], ap)
-        alpha = c["rz"] / jnp.where(denom == 0, 1.0, denom)
-        x = c["x"] + alpha * c["p"]
-        r = c["r"] - alpha * ap
-        z = apply_m(r, tag)
-        rz_new = jnp.vdot(r, z)
-        rr_new = jnp.vdot(r, r)
-        beta = rz_new / jnp.where(c["rz"] == 0, 1.0, c["rz"])
-        p = z + beta * c["p"]
-        out = dict(x=x, r=r, p=p, rz=rz_new, rr=rr_new)
-        if guards is not None or flight is not None:
-            out["denom"] = denom
-        return out
-
-    return _batched_krylov_loop(b, x0, tol, maxiter, params, init_tag,
-                                init_col, step_col, guards, flight,
-                                resume=resume, stop_at=stop_at,
-                                return_state=return_state)
+        return _solve_batched(apply_a, None, b, x0, tol_, maxiter, params,
+                              init_tag=init_tag, guards=guards, flight=flight)
 
 
 def solve_pcg_batched(
@@ -549,20 +451,8 @@ def solve_pcg_batched(
     apply_a = _maybe_sharded(apply_a, wire)
     with OT.span("solve.pcg_batched", n=int(b.shape[0]),
                  nrhs=int(b.shape[1]), tol=float(tol)):
-        if isinstance(apply_a, (GSECSR, GSESellC)) and hasattr(precond,
-                                                               "apply_at"):
-            return _solve_pcg_batched_fused(apply_a, precond, b, x0, tol_,
-                                            maxiter, params,
-                                            init_tag=init_tag,
-                                            guards=guards, flight=flight)
-        apply_m = precond if callable(precond) else precond.apply
-        if isinstance(apply_a, (GSECSR, GSESellC)):
-            from repro.solvers.cg import _gsecsr_operator
-
-            apply_a = _gsecsr_operator(apply_a)
-        return _solve_pcg_batched(apply_a, apply_m, b, x0, tol_, maxiter,
-                                  params, init_tag=init_tag, guards=guards,
-                                  flight=flight)
+        return _solve_batched(apply_a, precond, b, x0, tol_, maxiter, params,
+                              init_tag=init_tag, guards=guards, flight=flight)
 
 
 # ---------------------------------------------------------------------------
@@ -608,12 +498,8 @@ def solve_ir_batched(
     nrhs = b.shape[1]
 
     apply_a = _maybe_sharded(apply_a, wire)
-    if isinstance(apply_a, (GSECSR, GSESellC)):
-        from repro.solvers.cg import _gsecsr_operator
-
-        apply_tagged = _gsecsr_operator(apply_a)
-    else:
-        apply_tagged = apply_a
+    apply_tagged = (make_gse_operator(apply_a)
+                    if isinstance(apply_a, (GSECSR, GSESellC)) else apply_a)
 
     def apply3_block(x_block):
         # Per-column tag-3 reads: identical arithmetic to solve_ir's apply3.

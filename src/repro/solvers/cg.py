@@ -1,18 +1,27 @@
 """Conjugate Gradient with stepped mixed precision (paper Alg. 3 + Sec IV).
 
-Pure ``lax.while_loop``; the operator is called with the current precision
+Pure ``lax.while_loop``; the operator is applied at the current precision
 tag each iteration, and the residual monitor (core.precision) steps the tag
 up when convergence stalls.  Faithful to the paper: the switch happens
 in-place (no restart, no residual recomputation at the switch), matching
 Algorithm 3.
 
-Two equivalent hot paths (bit-identical trajectories):
+One iteration, one loop, one entry (DESIGN.md §4):
 
-  * generic: ``apply_a(x, tag)`` is any callable (fixed-precision
-    baselines, dense operators, preconditioned wrappers);
-  * fused:   pass a ``GSECSR`` directly as the operator and each iteration
-    runs ``solvers.fused_cg.fused_cg_step`` -- one decoded-value pass with
-    the dots/axpys folded around the SpMV (DESIGN.md §4).
+  * ``fused_cg.cg_step`` is the iteration, the tag switch around all of it;
+  * ``krylov_loop`` is the ``while_loop`` around it, with the monitor, the
+    switch log, the guards, the flight recorder and the chunk hooks -- the
+    single-chip loop here and the sharded body inside ``shard_map``
+    (``solvers.sharded``) both run it;
+  * ``_drive`` is the entry behind ``solve_cg``, ``solve_pcg``,
+    ``solve_cg_sharded`` and ``solve_pcg_sharded``: the ``tags=``
+    hand-off, the sharded dispatch, recovery and the final correction.
+
+The operator is a packed ``GSECSR``/``GSESellC`` or any callable
+``apply(x, tag)`` (fixed-precision baselines, dense operators, the
+distributed operator); the preconditioner a ``solvers.precond`` /
+``solvers.multigrid`` object or any callable ``apply_m(r, tag)``.  Either
+form runs the same loop.
 """
 from __future__ import annotations
 
@@ -37,10 +46,11 @@ from repro.robustness.guards import (
     run_with_recovery,
     run_with_recovery_map,
 )
-from repro.solvers.fused_cg import kdot
+from repro.solvers.fused_cg import cg_step, kdot, start, static_apply
+from repro.solvers.operators import make_gse_operator
 from repro.sparse.csr import GSECSR, GSESellC
 
-__all__ = ["CGResult", "solve_cg", "solve_pcg"]
+__all__ = ["CGResult", "krylov_loop", "solve_cg", "solve_pcg"]
 
 
 def _normalize_tag_axis(tags, apply_a, m):
@@ -142,165 +152,6 @@ class CGResult(NamedTuple):
     correction_iters: object = None
 
 
-def _guarded_init(state, relres0, guards):
-    """Attach guard state + last-finite checkpoint to a loop state dict."""
-    if guards is not None:
-        state["g"] = guard_init(relres0)
-        state["ckpt"] = state["x"]
-    return state
-
-
-def _guarded_cond(s, ok, guards):
-    """AND the guard's health into a loop condition (no-op with guards off)."""
-    if guards is not None:
-        ok = ok & (s["g"]["health"] == HEALTH_OK)
-    return ok
-
-
-def _guarded_body(s, out, relres_new, guards, *, denom=None, breakdown=False,
-                  finite_aux=()):
-    """Run the guard over an iteration's new state and roll the checkpoint.
-
-    Called AFTER the update arithmetic (which is identical with guards on
-    or off -- the bit-identity contracts); records health/trip and keeps
-    ``ckpt`` at the last state the guard judged healthy, which is what
-    tag-escalation recovery rolls back to.
-    """
-    if guards is None:
-        return out
-    g = guard_step(s["g"], s["it"], relres_new, guards, denom=denom,
-                   breakdown=breakdown, finite_aux=finite_aux)
-    out["g"] = g
-    out["ckpt"] = jnp.where(g["health"] == HEALTH_OK, out["x"], s["ckpt"])
-    return out
-
-
-def _guarded_result(out, relres, tol, guards, make):
-    """Finalize health/trip and build ``(result, ckpt)`` from a loop exit."""
-    conv = relres <= tol
-    g = out.get("g") if guards is not None else None
-    health, trip = finalize_health(g, conv, relres)
-    res = make(conv, health, trip)
-    ckpt = out["ckpt"] if guards is not None else out["x"]
-    return res, ckpt
-
-
-def _flight_init(state, flight, dtype):
-    """Attach a flight-recorder ring buffer to a loop state dict."""
-    if flight is not None:
-        state["fl"] = OF.flight_init(flight, dtype)
-    return state
-
-
-def _flight_body(s, out, relres_new, flight, a0=None, a1=None, a2=None):
-    """Append this iteration's flight row (pure observation, after the
-    guard ran so the row carries the guard's verdict on this iteration).
-
-    Same discipline as ``_guarded_body``: nothing here feeds back into the
-    solver recurrence, so recorder-on stays bit-identical to recorder-off.
-    """
-    if flight is None:
-        return out
-    g = out.get("g")
-    out["fl"] = OF.flight_record(
-        s["fl"],
-        it=s["it"],
-        relres=relres_new,
-        tag=s["mon"].tag,
-        health=g["health"] if g is not None else None,
-        a0=a0, a1=a1, a2=a2,
-    )
-    return out
-
-
-@partial(jax.jit, static_argnames=("apply_a", "maxiter", "params", "init_tag",
-                                   "guards", "flight", "return_ckpt",
-                                   "return_state"))
-def _solve_cg(apply_a, b, x0, tol, maxiter, params: P.MonitorParams,
-              init_tag: int = 1, guards: GuardParams | None = None,
-              flight: OF.FlightParams | None = None,
-              return_ckpt: bool = False, resume=None, stop_at=None,
-              return_state: bool = False):
-    dtype = b.dtype
-    bnorm = jnp.linalg.norm(b)
-    bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
-
-    def relres(s):
-        return jnp.sqrt(jnp.abs(s["rs"])) / bnorm
-
-    # ``resume`` (DESIGN.md §17) carries a previous chunk's loop state
-    # verbatim: the init section is skipped entirely, so a resumed loop
-    # continues the EXACT op sequence the unchunked loop would have run.
-    if resume is not None:
-        state = resume
-    else:
-        mon = P.init(params, dtype=dtype, tag=init_tag)
-        r0 = _residual(b, apply_a(x0, mon.tag))
-        state = dict(
-            x=x0,
-            r=r0,
-            p=r0,
-            rs=kdot(r0, r0),
-            it=jnp.int32(0),
-            mon=mon,
-            switches=jnp.full((2,), -1, jnp.int32),
-        )
-        state = _guarded_init(state, relres(state), guards)
-        state = _flight_init(state, flight, dtype)
-
-    def cond(s):
-        ok = (relres(s) > tol) & (s["it"] < maxiter)
-        if stop_at is not None:
-            # Chunk boundary: a pure extra exit condition -- the body
-            # arithmetic is untouched, so chunked == unchunked bitwise.
-            ok = ok & (s["it"] < stop_at)
-        return _guarded_cond(s, ok, guards)
-
-    def body(s):
-        tag = s["mon"].tag
-        ap = apply_a(s["p"], tag)
-        denom = kdot(s["p"], ap)
-        with OT.scope(OT.KRYLOV, OT.UPDATE):
-            alpha = s["rs"] / jnp.where(denom == 0, 1.0, denom)
-            x = s["x"] + alpha * s["p"]
-            r = s["r"] - alpha * ap
-        rs_new = kdot(r, r)
-        with OT.scope(OT.MONITOR):
-            mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rs_new)) / bnorm)
-            mon2 = P.update_tag(mon, params)
-            switches = _record_switch(s["switches"], mon, mon2, s["it"])
-        with OT.scope(OT.KRYLOV, OT.UPDATE):
-            beta = rs_new / jnp.where(s["rs"] == 0, 1.0, s["rs"])
-            p = r + beta * s["p"]
-        out = dict(
-            x=x, r=r, p=p, rs=rs_new, it=s["it"] + 1, mon=mon2, switches=switches
-        )
-        with OT.scope(OT.MONITOR):
-            out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
-                                guards, denom=denom)
-            return _flight_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
-                                flight, a0=alpha, a1=beta, a2=denom)
-
-    out = jax.lax.while_loop(cond, body, state)
-    res, ckpt = _guarded_result(
-        out, relres(out), tol, guards,
-        lambda conv, health, trip: CGResult(
-            x=out["x"],
-            iters=out["it"],
-            relres=relres(out),
-            tag=out["mon"].tag,
-            switch_iters=out["switches"],
-            converged=conv,
-            health=health,
-            trip_iter=trip,
-            flight=out.get("fl"),
-        ),
-    )
-    if return_state:
-        return res, ckpt, out
-    return (res, ckpt) if return_ckpt else res
-
-
 def _record_switch(switches, mon, mon2, it):
     """Log the iteration of a tag step-up into its slot (0: ->2, 1: ->3).
 
@@ -314,308 +165,148 @@ def _record_switch(switches, mon, mon2, it):
     return jnp.where(stepped, switches.at[slot].set(it + 1), switches)
 
 
-def _residual(b, ax):
-    """``b - A x`` of the loop's start, under the ``krylov/update`` scope
-    (the SpMV that made ``ax`` carries its own)."""
-    with OT.scope(OT.KRYLOV, OT.UPDATE):
-        return b - ax
+def _relres(s, bnorm):
+    """A loop state's recursive relative residual: ``sqrt(r.r)/||b||``
+    (``rr`` under PCG, ``rs`` under CG)."""
+    return jnp.sqrt(jnp.abs(s["rr"] if "rr" in s else s["rs"])) / bnorm
+
+
+def krylov_loop(matvec, precond, dot, b, x0, tol, maxiter, params, bnorm, *,
+                init_tag=1, guards: GuardParams | None = None,
+                flight: OF.FlightParams | None = None, resume=None,
+                stop_at=None, apply0=None):
+    """The stepped CG (``precond=None``) or PCG ``while_loop`` around
+    ``fused_cg.cg_step``; returns the loop's exit state.
+
+    ``matvec``/``precond``/``dot``/``apply0`` are as in ``cg_step`` and
+    ``fused_cg.start``.  The state carries, once for every loop:
+
+      * the residual monitor ``mon`` and its switch log ``switches``;
+      * with ``guards`` (DESIGN.md §14) the guard state ``g`` and
+        ``ckpt``, the last iterate the guard judged healthy, which
+        tag-escalation recovery rolls back to;
+      * with ``flight`` (DESIGN.md §16) the flight-recorder ring ``fl``.
+
+    Guards and recorder run after the update arithmetic, on scalars the
+    step already produced, so neither changes the trajectory.  Chunk
+    hooks (DESIGN.md §17): ``resume`` is a previous chunk's state,
+    carried verbatim (the start is skipped, so a resumed loop continues
+    the exact op sequence), and ``stop_at`` an extra iteration bound
+    ANDed into the condition.  The state's keys are CG's
+    ``x r p rs it mon switches`` and PCG's ``x r p rz rr it mon
+    switches``, plus ``g``/``ckpt``/``fl``: the chunk checkpoints of
+    ``serve.chunked`` restore into them.
+    """
+    pcg = precond is not None
+    rz_key = "rz" if pcg else "rs"
+
+    if resume is not None:
+        state = resume
+    else:
+        mon = P.init(params, dtype=b.dtype, tag=init_tag)
+        r0, p0, rz0, rr0 = start(matvec, precond, dot, b, x0, mon.tag,
+                                 apply0)
+        state = dict(x=x0, r=r0, p=p0, it=jnp.int32(0), mon=mon,
+                     switches=jnp.full((2,), -1, jnp.int32))
+        state.update(dict(rz=rz0, rr=rr0) if pcg else dict(rs=rr0))
+        if guards is not None:
+            state["g"] = guard_init(_relres(state, bnorm))
+            state["ckpt"] = x0
+        if flight is not None:
+            state["fl"] = OF.flight_init(flight, b.dtype)
+
+    def cond(s):
+        ok = (_relres(s, bnorm) > tol) & (s["it"] < maxiter)
+        if stop_at is not None:
+            ok = ok & (s["it"] < stop_at)
+        if guards is not None:
+            ok = ok & (s["g"]["health"] == HEALTH_OK)
+        return ok
+
+    def body(s):
+        rz_old = s[rz_key]
+        x, r, p, rz, rr, denom = cg_step(matvec, precond, dot, s["x"],
+                                         s["r"], s["p"], rz_old,
+                                         s["mon"].tag)
+        with OT.scope(OT.MONITOR):
+            rel = jnp.sqrt(jnp.abs(rr)) / bnorm
+            mon = P.record(s["mon"], rel)
+            mon2 = P.update_tag(mon, params)
+            out = dict(x=x, r=r, p=p, it=s["it"] + 1, mon=mon2,
+                       switches=_record_switch(s["switches"], mon, mon2,
+                                               s["it"]))
+            out.update(dict(rz=rz, rr=rr) if pcg else dict(rs=rr))
+            if guards is not None:
+                # z.r < 0 breaks PCG's M-SPD contract: an extra breakdown
+                # predicate.
+                out["g"] = guard_step(
+                    s["g"], s["it"], rel, guards, denom=denom,
+                    breakdown=(rz < 0) if pcg else False,
+                    finite_aux=(rz,) if pcg else ())
+                out["ckpt"] = jnp.where(out["g"]["health"] == HEALTH_OK, x,
+                                        s["ckpt"])
+            if flight is not None:
+                out["fl"] = OF.flight_record(
+                    s["fl"], it=s["it"], relres=rel, tag=s["mon"].tag,
+                    health=out["g"]["health"] if guards is not None else None,
+                    a0=rz_old / jnp.where(denom == 0, 1.0, denom),
+                    a1=rz / jnp.where(rz_old == 0, 1.0, rz_old), a2=denom)
+        return out
+
+    return jax.lax.while_loop(cond, body, state)
+
+
+def _loop_result(out, bnorm, tol):
+    """``(CGResult, ckpt)`` of a loop's exit state; ``ckpt`` is the last
+    healthy iterate (``x`` itself with guards off)."""
+    relres = _relres(out, bnorm)
+    conv = relres <= tol
+    health, trip = finalize_health(out.get("g"), conv, relres)
+    res = CGResult(x=out["x"], iters=out["it"], relres=relres,
+                   tag=out["mon"].tag, switch_iters=out["switches"],
+                   converged=conv, health=health, trip_iter=trip,
+                   flight=out.get("fl"))
+    return res, out.get("ckpt", out["x"])
 
 
 @partial(jax.jit, static_argnames=("maxiter", "params", "init_tag", "guards",
                                    "flight", "return_ckpt", "return_state"))
-def _solve_cg_fused(a, b, x0, tol, maxiter, params: P.MonitorParams,
-                    init_tag: int = 1, guards: GuardParams | None = None,
-                    flight: OF.FlightParams | None = None,
-                    return_ckpt: bool = False, resume=None, stop_at=None,
-                    return_state: bool = False):
-    """Fused-path CG over a ``GSECSR`` operand (DESIGN.md §4).
-
-    Same trajectory as ``_solve_cg`` with the GSE operator -- each
-    iteration is one ``fused_cg_step``: the values are decoded once at the
-    monitor's current tag and the dots/axpys/residual norm ride the same
-    sweep as the SpMV.  With guards or the flight recorder the step also
-    surfaces the curvature ``p.Ap`` it already computed
-    (``fused_cg_step_g``) -- the update arithmetic is unchanged either way.
-
-    ``resume``/``stop_at``/``return_state``: chunked execution hooks
-    (DESIGN.md §17), as in :func:`_solve_cg`.
-    """
-    from repro.solvers.fused_cg import fused_cg_step, fused_cg_step_g, gse_matvec
-
-    dtype = b.dtype
+def _loop_jit(a, m, b, x0, tol, maxiter, params: P.MonitorParams,
+              init_tag: int = 1, guards: GuardParams | None = None,
+              flight: OF.FlightParams | None = None,
+              return_ckpt: bool = False, resume=None, stop_at=None,
+              return_state: bool = False):
     bnorm = jnp.linalg.norm(b)
     bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
-
-    def relres(s):
-        return jnp.sqrt(jnp.abs(s["rs"])) / bnorm
-
-    if resume is not None:
-        state = resume
-    else:
-        mon = P.init(params, dtype=dtype, tag=init_tag)
-        r0 = _residual(b, gse_matvec(a, x0, mon.tag))
-        state = dict(
-            x=x0,
-            r=r0,
-            p=r0,
-            rs=kdot(r0, r0),
-            it=jnp.int32(0),
-            mon=mon,
-            switches=jnp.full((2,), -1, jnp.int32),
-        )
-        state = _guarded_init(state, relres(state), guards)
-        state = _flight_init(state, flight, dtype)
-
-    def cond(s):
-        ok = (relres(s) > tol) & (s["it"] < maxiter)
-        if stop_at is not None:
-            ok = ok & (s["it"] < stop_at)
-        return _guarded_cond(s, ok, guards)
-
-    def body(s):
-        if guards is None and flight is None:
-            x, r, p, rs_new = fused_cg_step(
-                a, s["x"], s["r"], s["p"], s["rs"], s["mon"].tag
-            )
-            denom = None
-        else:
-            x, r, p, rs_new, denom = fused_cg_step_g(
-                a, s["x"], s["r"], s["p"], s["rs"], s["mon"].tag
-            )
-        with OT.scope(OT.MONITOR):
-            mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rs_new)) / bnorm)
-            mon2 = P.update_tag(mon, params)
-            switches = _record_switch(s["switches"], mon, mon2, s["it"])
-            out = dict(
-                x=x, r=r, p=p, rs=rs_new, it=s["it"] + 1, mon=mon2,
-                switches=switches
-            )
-            out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
-                                guards, denom=denom)
-            if flight is not None:
-                # Observation-only recomputation of the step scalars from
-                # the surfaced curvature (the fused step consumed them
-                # internally).
-                alpha = s["rs"] / jnp.where(denom == 0, 1.0, denom)
-                beta = rs_new / jnp.where(s["rs"] == 0, 1.0, s["rs"])
-                out = _flight_body(s, out, jnp.sqrt(jnp.abs(rs_new)) / bnorm,
-                                   flight, a0=alpha, a1=beta, a2=denom)
-        return out
-
-    out = jax.lax.while_loop(cond, body, state)
-    res, ckpt = _guarded_result(
-        out, relres(out), tol, guards,
-        lambda conv, health, trip: CGResult(
-            x=out["x"],
-            iters=out["it"],
-            relres=relres(out),
-            tag=out["mon"].tag,
-            switch_iters=out["switches"],
-            converged=conv,
-            health=health,
-            trip_iter=trip,
-            flight=out.get("fl"),
-        ),
-    )
+    out = krylov_loop(static_apply(a), static_apply(m), kdot, b, x0, tol,
+                      maxiter, params, bnorm, init_tag=init_tag,
+                      guards=guards, flight=flight, resume=resume,
+                      stop_at=stop_at)
+    res, ckpt = _loop_result(out, bnorm, tol)
     if return_state:
         return res, ckpt, out
     return (res, ckpt) if return_ckpt else res
 
 
-@partial(jax.jit, static_argnames=("apply_a", "apply_m", "maxiter", "params",
-                                   "init_tag", "guards", "flight",
-                                   "return_ckpt", "return_state"))
-def _solve_pcg(apply_a, apply_m, b, x0, tol, maxiter, params: P.MonitorParams,
-               init_tag: int = 1, guards: GuardParams | None = None,
-               flight: OF.FlightParams | None = None,
-               return_ckpt: bool = False, resume=None, stop_at=None,
-               return_state: bool = False):
-    """Preconditioned CG: ``z = M^{-1} r`` at the monitor's current tag.
-
-    The recurrence runs on ``rz = r.z``; the monitor sees the plain
-    residual norm ``sqrt(r.r)/||b||`` -- the same quantity the paper's
-    controller watches in unpreconditioned CG.
-    """
-    dtype = b.dtype
-    bnorm = jnp.linalg.norm(b)
-    bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
-
-    def relres(s):
-        return jnp.sqrt(jnp.abs(s["rr"])) / bnorm
-
-    if resume is not None:
-        state = resume
-    else:
-        mon = P.init(params, dtype=dtype, tag=init_tag)
-        r0 = _residual(b, apply_a(x0, mon.tag))
-        with OT.scope(OT.PRECOND):
-            z0 = apply_m(r0, mon.tag)
-        state = dict(
-            x=x0,
-            r=r0,
-            p=z0,
-            rz=kdot(r0, z0),
-            rr=kdot(r0, r0),
-            it=jnp.int32(0),
-            mon=mon,
-            switches=jnp.full((2,), -1, jnp.int32),
-        )
-        state = _guarded_init(state, relres(state), guards)
-        state = _flight_init(state, flight, dtype)
-
-    def cond(s):
-        ok = (relres(s) > tol) & (s["it"] < maxiter)
-        if stop_at is not None:
-            ok = ok & (s["it"] < stop_at)
-        return _guarded_cond(s, ok, guards)
-
-    def body(s):
-        tag = s["mon"].tag
-        ap = apply_a(s["p"], tag)
-        denom = kdot(s["p"], ap)
-        with OT.scope(OT.KRYLOV, OT.UPDATE):
-            alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
-            x = s["x"] + alpha * s["p"]
-            r = s["r"] - alpha * ap
-        with OT.scope(OT.PRECOND):
-            z = apply_m(r, tag)
-        rz_new = kdot(r, z)
-        rr_new = kdot(r, r)
-        with OT.scope(OT.MONITOR):
-            mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rr_new)) / bnorm)
-            mon2 = P.update_tag(mon, params)
-            switches = _record_switch(s["switches"], mon, mon2, s["it"])
-        with OT.scope(OT.KRYLOV, OT.UPDATE):
-            beta = rz_new / jnp.where(s["rz"] == 0, 1.0, s["rz"])
-            p = z + beta * s["p"]
-        out = dict(
-            x=x, r=r, p=p, rz=rz_new, rr=rr_new, it=s["it"] + 1, mon=mon2,
-            switches=switches,
-        )
-        with OT.scope(OT.MONITOR):
-            # z.r < 0 breaks PCG's M-SPD contract: an extra breakdown
-            # predicate.
-            out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
-                                guards, denom=denom, breakdown=rz_new < 0,
-                                finite_aux=(rz_new,))
-            return _flight_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
-                                flight, a0=alpha, a1=beta, a2=denom)
-
-    out = jax.lax.while_loop(cond, body, state)
-    res, ckpt = _guarded_result(
-        out, relres(out), tol, guards,
-        lambda conv, health, trip: CGResult(
-            x=out["x"],
-            iters=out["it"],
-            relres=relres(out),
-            tag=out["mon"].tag,
-            switch_iters=out["switches"],
-            converged=conv,
-            health=health,
-            trip_iter=trip,
-            flight=out.get("fl"),
-        ),
-    )
-    if return_state:
-        return res, ckpt, out
-    return (res, ckpt) if return_ckpt else res
+def _as_arg(f):
+    """An operator or preconditioner as an argument of a jitted loop:
+    packed operands and preconditioner objects are pytrees and pass as
+    they are; a callable rides as a leafless ``jax.tree_util.Partial``,
+    so its identity is part of the compile-cache key (the memoized
+    operator closures keep it from one solve to the next)."""
+    if (f is None or isinstance(f, (GSECSR, GSESellC))
+            or hasattr(f, "apply_at")):
+        return f
+    return jax.tree_util.Partial(f if callable(f) else f.apply)
 
 
-@partial(jax.jit, static_argnames=("maxiter", "params", "init_tag", "guards",
-                                   "flight", "return_ckpt", "return_state"))
-def _solve_pcg_fused(a, m, b, x0, tol, maxiter, params: P.MonitorParams,
-                     init_tag: int = 1, guards: GuardParams | None = None,
-                     flight: OF.FlightParams | None = None,
-                     return_ckpt: bool = False, resume=None, stop_at=None,
-                     return_state: bool = False):
-    """Fused-path PCG over a ``GSECSR`` operand and a pytree preconditioner.
-
-    Each iteration is one ``fused_pcg_step``: operator decode and
-    preconditioner apply ride the same tag branch (DESIGN.md §10), with
-    the exact arithmetic of ``_solve_pcg`` -- bit-identical trajectories.
-    """
-    from repro.solvers.fused_cg import (fused_pcg_step, fused_pcg_step_g,
-                                        gse_matvec)
-
-    dtype = b.dtype
-    bnorm = jnp.linalg.norm(b)
-    bnorm = jnp.where(bnorm == 0, 1.0, bnorm)
-
-    def relres(s):
-        return jnp.sqrt(jnp.abs(s["rr"])) / bnorm
-
-    if resume is not None:
-        state = resume
-    else:
-        mon = P.init(params, dtype=dtype, tag=init_tag)
-        r0 = _residual(b, gse_matvec(a, x0, mon.tag))
-        with OT.scope(OT.PRECOND):
-            z0 = m.apply(r0, mon.tag)
-        state = dict(
-            x=x0,
-            r=r0,
-            p=z0,
-            rz=kdot(r0, z0),
-            rr=kdot(r0, r0),
-            it=jnp.int32(0),
-            mon=mon,
-            switches=jnp.full((2,), -1, jnp.int32),
-        )
-        state = _guarded_init(state, relres(state), guards)
-        state = _flight_init(state, flight, dtype)
-
-    def cond(s):
-        ok = (relres(s) > tol) & (s["it"] < maxiter)
-        if stop_at is not None:
-            ok = ok & (s["it"] < stop_at)
-        return _guarded_cond(s, ok, guards)
-
-    def body(s):
-        if guards is None and flight is None:
-            x, r, p, rz_new, rr_new = fused_pcg_step(
-                a, m, s["x"], s["r"], s["p"], s["rz"], s["mon"].tag
-            )
-            denom = None
-        else:
-            x, r, p, rz_new, rr_new, denom = fused_pcg_step_g(
-                a, m, s["x"], s["r"], s["p"], s["rz"], s["mon"].tag
-            )
-        with OT.scope(OT.MONITOR):
-            mon = P.record(s["mon"], jnp.sqrt(jnp.abs(rr_new)) / bnorm)
-            mon2 = P.update_tag(mon, params)
-            switches = _record_switch(s["switches"], mon, mon2, s["it"])
-            out = dict(
-                x=x, r=r, p=p, rz=rz_new, rr=rr_new, it=s["it"] + 1, mon=mon2,
-                switches=switches,
-            )
-            out = _guarded_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
-                                guards, denom=denom, breakdown=rz_new < 0,
-                                finite_aux=(rz_new,))
-            if flight is not None:
-                alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
-                beta = rz_new / jnp.where(s["rz"] == 0, 1.0, s["rz"])
-                out = _flight_body(s, out, jnp.sqrt(jnp.abs(rr_new)) / bnorm,
-                                   flight, a0=alpha, a1=beta, a2=denom)
-        return out
-
-    out = jax.lax.while_loop(cond, body, state)
-    res, ckpt = _guarded_result(
-        out, relres(out), tol, guards,
-        lambda conv, health, trip: CGResult(
-            x=out["x"],
-            iters=out["it"],
-            relres=relres(out),
-            tag=out["mon"].tag,
-            switch_iters=out["switches"],
-            converged=conv,
-            health=health,
-            trip_iter=trip,
-            flight=out.get("fl"),
-        ),
-    )
-    if return_state:
-        return res, ckpt, out
-    return (res, ckpt) if return_ckpt else res
+def _loop(op, precond, b, x0, tol, maxiter, params, **kw):
+    """The single-chip stepped CG (``precond=None``) / PCG loop over any
+    operator and preconditioner form.  ``kw``: ``init_tag``, ``guards``,
+    ``flight``, ``return_ckpt``, and the chunk hooks ``resume``,
+    ``stop_at``, ``return_state`` (DESIGN.md §17)."""
+    return _loop_jit(_as_arg(op), _as_arg(precond), b, x0, tol, maxiter,
+                     params, **kw)
 
 
 def _finish_with_correction(res, b, tol, maxiter, apply3, resume):
@@ -682,67 +373,112 @@ def _pack_map_flight(res, tme: TagMap):
         res.flight, tme.min_tag, tme.max_tag))
 
 
-def _tagmap_run_cg(a, b, tol_, params, guards, flight, tm: TagMap):
-    """Build the ``run(x_start, budget, floor)`` closure the per-group
-    recovery ladder drives for CG: mask the operand at the floored map,
-    decode at its max tag, monitor pinned (DESIGN.md §18)."""
+def _tagmap_run(a, precond, b, tol_, params, guards, flight, tm: TagMap):
+    """The ``run(x_start, budget, floor)`` closure the per-group recovery
+    ladder drives: mask the operand at the floored map, decode at its max
+    tag, monitor pinned (DESIGN.md §18).  A preconditioner runs at the
+    map's MAX tag (the conservative charge ``iteration_stream_bytes``
+    models)."""
     from repro.kernels.ops import masked_for_tagmap
 
     def run(x_start, budget, floor):
         tme = tm.floored(floor)
-        res, ckpt = _solve_cg_fused(
-            masked_for_tagmap(a, tme), b, x_start, tol_, budget,
-            _pin_params(params, tme.max_tag), init_tag=tme.max_tag,
-            guards=guards, flight=flight, return_ckpt=True)
+        res, ckpt = _loop(masked_for_tagmap(a, tme), precond, b, x_start,
+                          tol_, budget, _pin_params(params, tme.max_tag),
+                          init_tag=tme.max_tag, guards=guards, flight=flight,
+                          return_ckpt=True)
         return _pack_map_flight(res, tme), ckpt
 
     return run
 
 
-def _tagmap_run_pcg(a, precond, b, tol_, params, guards, flight,
-                    fused: bool, tm: TagMap):
-    """PCG twin of :func:`_tagmap_run_cg` -- the preconditioner stream
-    runs at the map's MAX tag (the conservative charge
-    ``iteration_stream_bytes`` models)."""
-    from repro.kernels.ops import masked_for_tagmap
+def _drive(op, b, precond, *, x0, tol, maxiter, params, final_correction,
+           wire, guards, recover, init_tag, flight, tags=None) -> CGResult:
+    """The one entry behind ``solve_cg``/``solve_pcg`` (``precond=None``
+    is CG) and ``solve_cg_sharded``/``solve_pcg_sharded``.
 
-    if fused:
-        def run(x_start, budget, floor):
-            tme = tm.floored(floor)
-            res, ckpt = _solve_pcg_fused(
-                masked_for_tagmap(a, tme), precond, b, x_start, tol_,
-                budget, _pin_params(params, tme.max_tag),
-                init_tag=tme.max_tag, guards=guards, flight=flight,
-                return_ckpt=True)
-            return _pack_map_flight(res, tme), ckpt
+    In order: the ``tags=`` hand-off (``"adaptive"``, an int or uniform
+    map overriding ``init_tag``, a non-uniform map's masked-operand run);
+    the sharded dispatch for a ``PartitionedGSECSR``; the run under
+    tag-escalation recovery; and the final correction, all under the host
+    span ``solve.cg``, ``solve.pcg``, ``solve.cg_sharded`` or
+    ``solve.pcg_sharded``.
+    """
+    from repro.distributed.partition import PartitionedGSECSR
+
+    if isinstance(tags, str):
+        if tags != "adaptive":
+            raise ValueError(
+                f"tags= accepts an int tag, a TagMap, or 'adaptive'; "
+                f"got {tags!r}")
+        from repro.solvers.adaptive import solve_adaptive
+
+        return solve_adaptive(op, b, precond=precond, x0=x0, tol=tol,
+                              maxiter=maxiter, params=params)
+    t_override, tm = _normalize_tag_axis(tags, op,
+                                         int(jnp.asarray(b).shape[0]))
+    if t_override is not None:
+        init_tag = t_override
+    b, x0, orig_shape = _normalize_b_x0(b, x0)
+    if x0 is None:
+        x0 = jnp.zeros_like(b)
+    if params is None:
+        params = P.MonitorParams.for_cg()
+    kind = "cg" if precond is None else "pcg"
+    attrs = dict(n=int(b.shape[0]), tol=float(tol))
+
+    sharded = isinstance(op, PartitionedGSECSR)
+    if sharded:
+        from repro.kernels.dist_spmv import make_sharded_operator
+        from repro.solvers.precond import DiagGSEPrecond
+
+        op3 = make_sharded_operator(op, wire)
+        if precond is not None and not isinstance(precond, DiagGSEPrecond):
+            # Only a diagonal preconditioner shards with the operator;
+            # any other applies to full vectors over the distributed
+            # operator, on the single-chip loop.
+            op, sharded = op3, False
     else:
-        apply_m = precond if callable(precond) else precond.apply
+        op3 = (make_gse_operator(op) if isinstance(op, (GSECSR, GSESellC))
+               else op)
 
-        def run(x_start, budget, floor):
-            tme = tm.floored(floor)
-            res, ckpt = _solve_pcg(
-                _gsecsr_operator(masked_for_tagmap(a, tme)), apply_m, b,
-                x_start, tol_, budget, _pin_params(params, tme.max_tag),
-                init_tag=tme.max_tag, guards=guards, flight=flight,
-                return_ckpt=True)
-            return _pack_map_flight(res, tme), ckpt
+    if sharded:
+        from repro.solvers.sharded import _run_sharded
 
-    return run
+        span = f"solve.{kind}_sharded"
+        attrs.update(wire=wire, shards=int(op.n_shards))
 
+        def run(x_start, budget, tag):
+            return _run_sharded(op, kind, b, x_start, tol, budget, params,
+                                tag, wire, precond=precond, guards=guards,
+                                flight=flight, return_ckpt=True)
+    elif tm is not None:
+        span = f"solve.{kind}"
+        attrs.update(init_tag=tm.max_tag)
+        run = _tagmap_run(op, precond, b, jnp.asarray(tol, b.dtype), params,
+                          guards, flight, tm)
+    else:
+        span = f"solve.{kind}"
+        attrs.update(init_tag=init_tag)
+        tol_ = jnp.asarray(tol, b.dtype)
 
-def _gsecsr_operator(a) -> Callable:
-    """Tag-dispatched operator view of a GSECSR/GSESellC, memoized on the instance
-    so repeated solves reuse one closure (the closure is a static jit
-    argument -- a fresh one per call would retrace the whole solver)."""
-    op = a.__dict__.get("_tag_operator")
-    if op is None:
-        from repro.solvers.fused_cg import gse_matvec
+        def run(x_start, budget, tag):
+            return _loop(op, precond, b, x_start, tol_, budget, params,
+                         init_tag=tag, guards=guards, flight=flight,
+                         return_ckpt=True)
 
-        def op(v, tag):
-            return gse_matvec(a, v, tag)
-
-        a.__dict__["_tag_operator"] = op
-    return op
+    recover = recover and guards is not None
+    with OT.span(span, **attrs):
+        if tm is not None:
+            res = run_with_recovery_map(run, x0, maxiter, tm, recover=recover)
+        else:
+            res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
+                                    recover=recover)
+        if final_correction:
+            res = _finish_with_correction(
+                res, b, tol, maxiter, lambda v: op3(v, jnp.int32(3)),
+                lambda xr, budget: run(xr, budget, 3)[0])
+    return _restore_shape(res, orig_shape)
 
 
 def solve_pcg(
@@ -766,12 +502,12 @@ def solve_pcg(
     ``precond`` is a preconditioner from :mod:`repro.solvers.precond`
     (exposing ``apply``/``apply_at``) or any callable ``apply_m(r, tag)``.
     Both the operator and the preconditioner are applied at the monitor's
-    current tag, so the preconditioner stream follows the same precision
-    schedule without a second stored copy.
+    current tag, inside one branch of the step's tag switch, so the
+    preconditioner stream follows the same precision schedule without a
+    second stored copy.
 
-    Passing a ``GSECSR`` as ``apply_a`` together with a precond *object*
-    selects the fused iteration path (``fused_pcg_step``) -- bit-identical
-    to the generic path, fewer kernel launches.  Passing a
+    ``apply_a`` is a ``GSECSR``/``GSESellC`` or any callable
+    ``apply_a(x, tag)``; both run the same loop.  Passing a
     ``PartitionedGSECSR`` selects the fully-sharded distributed loop
     (``solvers.sharded``; ``wire`` picks the halo wire format and is
     ignored otherwise).
@@ -800,96 +536,10 @@ def solve_pcg(
     ``b``/``x0`` may be ``(n,)`` or ``(n, 1)``; the solution comes back in
     ``b``'s layout.
     """
-    from repro.distributed.partition import PartitionedGSECSR
-
-    if isinstance(tags, str):
-        if tags != "adaptive":
-            raise ValueError(
-                f"tags= accepts an int tag, a TagMap, or 'adaptive'; "
-                f"got {tags!r}")
-        from repro.solvers.adaptive import solve_adaptive
-
-        return solve_adaptive(apply_a, b, precond=precond, x0=x0, tol=tol,
-                              maxiter=maxiter, params=params)
-    t_override, tm = _normalize_tag_axis(tags, apply_a,
-                                         int(jnp.asarray(b).shape[0]))
-    if t_override is not None:
-        init_tag = t_override
-
-    if isinstance(apply_a, PartitionedGSECSR):
-        from repro.solvers.sharded import solve_pcg_sharded
-
-        return solve_pcg_sharded(apply_a, b, precond, x0=x0, tol=tol,
-                                 maxiter=maxiter, params=params, wire=wire,
-                                 final_correction=final_correction,
-                                 guards=guards, recover=recover,
-                                 init_tag=init_tag, flight=flight)
-    b, x0, orig_shape = _normalize_b_x0(b, x0)
-    if x0 is None:
-        x0 = jnp.zeros_like(b)
-    if params is None:
-        params = P.MonitorParams.for_cg()
-    tol_ = jnp.asarray(tol, b.dtype)
-    fused = (isinstance(apply_a, (GSECSR, GSESellC))
-             and hasattr(precond, "apply_at"))
-
-    if tm is not None:
-        run = _tagmap_run_pcg(apply_a, precond, b, tol_, params, guards,
-                              flight, fused, tm)
-        with OT.span("solve.pcg", n=int(b.shape[0]), tol=float(tol),
-                     init_tag=tm.max_tag, fused=fused):
-            res = run_with_recovery_map(
-                run, x0, maxiter, tm,
-                recover=recover and guards is not None)
-            if not final_correction:
-                return _restore_shape(res, orig_shape)
-            apply3_op = _gsecsr_operator(apply_a)
-
-            def apply3(v):
-                return apply3_op(v, jnp.int32(3))
-
-            def resume(xr, budget):
-                return run(xr, budget, 3)[0]
-
-            return _restore_shape(
-                _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-                orig_shape,
-            )
-
-    if fused:
-        def run(x_start, budget, tag):
-            return _solve_pcg_fused(apply_a, precond, b, x_start, tol_,
-                                    budget, params, init_tag=tag,
-                                    guards=guards, flight=flight,
-                                    return_ckpt=True)
-    else:
-        apply_m = precond if callable(precond) else precond.apply
-        if isinstance(apply_a, (GSECSR, GSESellC)):
-            apply_a = _gsecsr_operator(apply_a)
-
-        def run(x_start, budget, tag):
-            return _solve_pcg(apply_a, apply_m, b, x_start, tol_, budget,
-                              params, init_tag=tag, guards=guards,
-                              flight=flight, return_ckpt=True)
-
-    with OT.span("solve.pcg", n=int(b.shape[0]), tol=float(tol),
-                 init_tag=init_tag, fused=fused):
-        res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
-                                recover=recover and guards is not None)
-        if not final_correction:
-            return _restore_shape(res, orig_shape)
-        apply3_op = _gsecsr_operator(apply_a) if fused else apply_a
-
-        def apply3(v):
-            return apply3_op(v, jnp.int32(3))
-
-        def resume(xr, budget):
-            return run(xr, budget, 3)[0]
-
-        return _restore_shape(
-            _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-            orig_shape,
-        )
+    return _drive(apply_a, b, precond, x0=x0, tol=tol, maxiter=maxiter,
+                  params=params, final_correction=final_correction,
+                  wire=wire, guards=guards, recover=recover,
+                  init_tag=init_tag, flight=flight, tags=tags)
 
 
 def solve_cg(
@@ -907,16 +557,14 @@ def solve_cg(
     flight: OF.FlightParams | None = None,
     tags=None,
 ) -> CGResult:
-    """CG for SPD systems.  ``apply_a(x, tag)`` is the (possibly multi-
-    precision) operator; fixed-precision baselines ignore ``tag``.
-
-    Passing a ``GSECSR`` directly as ``apply_a`` selects the fused
-    iteration path (``fused_cg_step``): one decoded-value pass per
-    iteration with the vector ops folded around the SpMV.  Trajectories
-    are bit-identical to ``solve_cg(make_gse_operator(a), ...)``; only the
-    kernel-launch structure differs.  Passing a ``PartitionedGSECSR``
-    selects the fully-sharded distributed loop (``solvers.sharded``;
-    ``wire`` picks the halo wire format and is ignored otherwise).
+    """CG for SPD systems.  ``apply_a`` is a ``GSECSR``/``GSESellC`` or a
+    (possibly multi-precision) operator ``apply_a(x, tag)``;
+    fixed-precision baselines ignore ``tag``.  Both run the same loop,
+    bit-identically: ``solve_cg(a, ...)`` and
+    ``solve_cg(make_gse_operator(a), ...)`` give the same trajectory.
+    Passing a ``PartitionedGSECSR`` selects the fully-sharded distributed
+    loop (``solvers.sharded``; ``wire`` picks the halo wire format and is
+    ignored otherwise).
 
     ``final_correction`` (beyond-paper safeguard): the recursive residual of
     a stepped run converges against the *perturbed* low-precision operator;
@@ -933,81 +581,7 @@ def solve_cg(
     ``b``/``x0`` may be ``(n,)`` or ``(n, 1)``; the solution comes back in
     ``b``'s layout.
     """
-    from repro.distributed.partition import PartitionedGSECSR
-
-    if isinstance(tags, str):
-        if tags != "adaptive":
-            raise ValueError(
-                f"tags= accepts an int tag, a TagMap, or 'adaptive'; "
-                f"got {tags!r}")
-        from repro.solvers.adaptive import solve_adaptive
-
-        return solve_adaptive(apply_a, b, x0=x0, tol=tol, maxiter=maxiter,
-                              params=params)
-    t_override, tm = _normalize_tag_axis(tags, apply_a,
-                                         int(jnp.asarray(b).shape[0]))
-    if t_override is not None:
-        init_tag = t_override
-
-    if isinstance(apply_a, PartitionedGSECSR):
-        from repro.solvers.sharded import solve_cg_sharded
-
-        return solve_cg_sharded(apply_a, b, x0=x0, tol=tol, maxiter=maxiter,
-                                params=params, wire=wire,
-                                final_correction=final_correction,
-                                guards=guards, recover=recover,
-                                init_tag=init_tag, flight=flight)
-    b, x0, orig_shape = _normalize_b_x0(b, x0)
-    if x0 is None:
-        x0 = jnp.zeros_like(b)
-    if params is None:
-        params = P.MonitorParams.for_cg()
-    tol_ = jnp.asarray(tol, b.dtype)
-    fused = isinstance(apply_a, (GSECSR, GSESellC))
-    solve = _solve_cg_fused if fused else _solve_cg
-
-    if tm is not None:
-        run = _tagmap_run_cg(apply_a, b, tol_, params, guards, flight, tm)
-        with OT.span("solve.cg", n=int(b.shape[0]), tol=float(tol),
-                     init_tag=tm.max_tag, fused=True):
-            res = run_with_recovery_map(
-                run, x0, maxiter, tm,
-                recover=recover and guards is not None)
-            if not final_correction:
-                return _restore_shape(res, orig_shape)
-            apply3_op = _gsecsr_operator(apply_a)
-
-            def apply3(v):
-                return apply3_op(v, jnp.int32(3))
-
-            def resume(xr, budget):
-                return run(xr, budget, 3)[0]
-
-            return _restore_shape(
-                _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-                orig_shape,
-            )
-
-    def run(x_start, budget, tag):
-        return solve(apply_a, b, x_start, tol_, budget, params,
-                     init_tag=tag, guards=guards, flight=flight,
-                     return_ckpt=True)
-
-    with OT.span("solve.cg", n=int(b.shape[0]), tol=float(tol),
-                 init_tag=init_tag, fused=fused):
-        res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
-                                recover=recover and guards is not None)
-        if not final_correction:
-            return _restore_shape(res, orig_shape)
-        apply3_op = _gsecsr_operator(apply_a) if fused else apply_a
-
-        def apply3(v):
-            return apply3_op(v, jnp.int32(3))
-
-        def resume(xr, budget):
-            return run(xr, budget, 3)[0]
-
-        return _restore_shape(
-            _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-            orig_shape,
-        )
+    return _drive(apply_a, b, None, x0=x0, tol=tol, maxiter=maxiter,
+                  params=params, final_correction=final_correction,
+                  wire=wire, guards=guards, recover=recover,
+                  init_tag=init_tag, flight=flight, tags=tags)

@@ -85,8 +85,7 @@ def solve_ir(
 ) -> IRResult:
     """Iterative refinement with a stepped inner solver.
 
-    ``apply_a`` is a tag-dispatched operator or a ``GSECSR`` (the inner CG
-    then takes the fused path).  ``inner`` selects ``"cg"`` or ``"gmres"``;
+    ``apply_a`` is a tag-dispatched operator or a ``GSECSR``.  ``inner`` selects ``"cg"`` or ``"gmres"``;
     ``precond`` (a :mod:`repro.solvers.precond` object or callable) turns
     the inner solve into PCG / right-preconditioned GMRES.  ``params``
     parameterizes the inner residual monitor (``MonitorParams``); each
@@ -142,11 +141,11 @@ def _ir_setup(apply_a, b, *, tol, max_outer, inner, inner_tol, inner_maxiter,
     # (ignored for non-partitioned operands, like the batched solvers).
     apply_a = _maybe_sharded(apply_a, wire)
     if isinstance(apply_a, (GSECSR, GSESellC)):
-        from repro.solvers.cg import _gsecsr_operator
+        from repro.solvers.operators import make_gse_operator
 
         # Memoized on the GSECSR instance: GMRES treats the operator as a
         # static jit arg, so a fresh closure per call would retrace.
-        apply_tagged = _gsecsr_operator(apply_a)
+        apply_tagged = make_gse_operator(apply_a)
     else:
         apply_tagged = apply_a
 

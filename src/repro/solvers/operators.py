@@ -1,17 +1,19 @@
 """Linear-operator factories with precision-tag dispatch (paper Alg. 3).
 
 An *operator* is ``apply(x, tag) -> A @ x`` where ``tag`` is a traced int32
-in {1,2,3}.  GSE-SEM operators dispatch via ``lax.switch`` to the three
-SpMV precisions; fixed-format baselines ignore the tag.
+or a static Python int in {1,2,3} (the solver step applies it at a static
+tag inside its tag switch).  GSE-SEM operators dispatch via
+``fused_cg.switched`` to the three SpMV precisions; fixed-format
+baselines ignore the tag.
 """
 from __future__ import annotations
 
 from typing import Callable
 
-import jax
 import jax.numpy as jnp
 
-from repro.sparse.csr import CSR, GSECSR
+from repro.solvers.fused_cg import switched
+from repro.sparse.csr import CSR
 from repro.sparse.spmv import spmv, spmv_gse
 
 __all__ = [
@@ -25,22 +27,23 @@ __all__ = [
 def make_gse_operator(a, acc_dtype=jnp.float64) -> Callable:
     """Three-precision operator over one stored copy (the paper's A1/A2/A3).
 
-    ``a`` is a ``GSECSR`` or a SELL-C-σ packed ``GSESellC``;
-    ``spmv_gse`` dispatches on the layout and the two are bit-identical
-    (DESIGN.md §12)."""
+    ``a`` is a ``GSECSR`` or a SELL-C-σ packed ``GSESellC``; the two are
+    bit-identical (DESIGN.md §12).  The closure is memoized on the
+    instance: solvers take it as a static jit argument, and a fresh one
+    per call would retrace the whole solver.  Its branches call the
+    jitted ``spmv_gse``: an eager caller (the final correction's check)
+    retraces the switch on every call, and the jitted SpMV keeps that
+    retrace cheap to lower."""
+    key = ("_tag_operator", jnp.dtype(acc_dtype).name)
+    op = a.__dict__.get(key)
+    if op is None:
+        def op(x, tag):
+            return switched(
+                lambda v, t: spmv_gse(a, v, tag=t, acc_dtype=acc_dtype), tag,
+                x)
 
-    def apply(x, tag):
-        return jax.lax.switch(
-            jnp.clip(tag - 1, 0, 2),
-            [
-                lambda v: spmv_gse(a, v, tag=1, acc_dtype=acc_dtype),
-                lambda v: spmv_gse(a, v, tag=2, acc_dtype=acc_dtype),
-                lambda v: spmv_gse(a, v, tag=3, acc_dtype=acc_dtype),
-            ],
-            x,
-        )
-
-    return apply
+        a.__dict__[key] = op
+    return op
 
 
 def make_fixed_operator(a: CSR, store_dtype=jnp.float64, acc_dtype=jnp.float64):

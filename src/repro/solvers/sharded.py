@@ -6,8 +6,9 @@ solve -- per-iteration traffic is the tag-aware halo exchange plus three
 scalar ``psum`` reductions (the CG dots), never a full-vector gather.
 The residual monitor (``core.precision``) runs replicated from the
 psum'd residual norm, so every shard steps the SAME tag at the same
-iteration -- one ``MonitorParams`` schedule drives all shards, exactly as
-it drives the single-device fused path.
+iteration -- one ``MonitorParams`` schedule drives all shards.  The
+per-device body is the single-chip loop itself (``cg.krylov_loop`` around
+``fused_cg.cg_step``), with the shard's local matvec and psum'd dots.
 
 Contracts (tests/test_distributed.py):
 
@@ -22,12 +23,11 @@ Contracts (tests/test_distributed.py):
     (the monitor sees a slightly stronger low-tag perturbation, which is
     exactly the regime the stepped controller is built for).
 
-``solve_cg``/``solve_pcg``/``solve_cg_batched``/``solve_pcg_batched``
-dispatch here when handed a ``PartitionedGSECSR``.
+``solve_cg``/``solve_pcg`` dispatch here when handed a
+``PartitionedGSECSR`` (through the shared entry ``cg._drive``); the
+batched solvers ride ``make_sharded_operator`` instead.
 """
 from __future__ import annotations
-
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -40,29 +40,13 @@ from repro.kernels.dist_spmv import (
     AXIS,
     _blk,
     local_matvec,
-    make_sharded_operator,
     shard_mesh,
     switched_matvec,
 )
 from repro.obs import flight as OF
 from repro.obs import trace as OT
-from repro.robustness.guards import (
-    DEFAULT_GUARDS,
-    GuardParams,
-    finalize_health,
-    run_with_recovery,
-)
-from repro.solvers.cg import (
-    CGResult,
-    _finish_with_correction,
-    _guarded_body,
-    _guarded_cond,
-    _guarded_init,
-    _normalize_b_x0,
-    _record_switch,
-    _residual,
-    _restore_shape,
-)
+from repro.robustness.guards import DEFAULT_GUARDS, GuardParams
+from repro.solvers.cg import CGResult, _drive, _loop_result, krylov_loop
 
 __all__ = ["solve_cg_sharded", "solve_pcg_sharded"]
 
@@ -80,37 +64,18 @@ def _pad_to(x, n_padded):
     return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad else x
 
 
-def _matvec_dispatch(blk, wire, k, rows, ei):
-    """Traced-tag distributed matvec for use inside the sharded loop --
-    same ``lax.switch`` discipline as ``fused_cg_step``, with the halo
-    exchange and decode both inside each static-tag branch
-    (``switched_matvec``)."""
-    def matvec(v, tag):
-        return switched_matvec(blk, v, tag, wire=wire, k=k, rows=rows,
-                               ei_bit=ei)
-
-    return matvec
-
-
-def _diag_apply_dispatch(m_parts, ei_bit_m, frac_bits_m):
-    """Traced-tag diagonal-preconditioner apply on this shard's slice of
+def _diag_apply_at(m_parts, ei_bit_m, frac_bits_m):
+    """Static-tag diagonal-preconditioner apply on this shard's slice of
     the packed ``M^{-1}`` diagonal -- elementwise, so the sliced decode is
     bitwise the slice of the full-vector decode (``DiagGSEPrecond``)."""
     m_head, m_tail1, m_tail2, m_table = m_parts
 
-    def apply_at(r, tag: int, acc_dtype=jnp.float64):
+    def apply_at(r, tag: int):
         d = gse._decode_jnp(m_table, m_head, m_tail1, m_tail2, ei_bit_m,
-                            frac_bits_m, tag, acc_dtype)
-        return d * r.astype(acc_dtype)
+                            frac_bits_m, tag, jnp.float64)
+        return d * r.astype(jnp.float64)
 
-    def apply(r, tag):
-        return jax.lax.switch(
-            jnp.clip(tag - 1, 0, 2),
-            [partial(apply_at, tag=t) for t in (1, 2, 3)],
-            r,
-        )
-
-    return apply, apply_at
+    return apply_at
 
 
 def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
@@ -118,12 +83,13 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
                      precond_meta=None, guards=None, flight=None):
     """Build (and memoize on the partition) the jitted shard_map solver.
 
-    The per-device body mirrors ``_solve_cg_fused``/``_solve_pcg_fused``
-    op for op; only the dots go through ``psum`` and the operator is the
-    shard's local block + halo.  The guard state (DESIGN.md §14) runs on
-    the psum'd replicated scalars -- every shard latches the SAME health
-    code at the same iteration -- while the last-finite checkpoint stays
-    row-sharded alongside x.
+    The per-device body is ``cg.krylov_loop`` -- the single-chip loop,
+    its step ``fused_cg.cg_step`` -- over the shard's ``local_matvec``
+    (local block + halo, at the step's static tag) with the dots
+    ``psum``'d.  The guard state (DESIGN.md §14) and the flight ring run
+    on the psum'd replicated scalars -- every shard latches the SAME
+    health code at the same iteration and writes the same ring -- while
+    the last-finite checkpoint stays row-sharded alongside x.
     """
     key = ("_sharded_solve", kind, wire, maxiter, params, init_tag,
            precond_meta, guards, flight)
@@ -137,145 +103,25 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
             m_head, m_tail1, m_tail2, m_table, b, x0, tol, bnorm):
         blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
                    table)
-        matvec = _matvec_dispatch(blk, wire, k, rows, ei)
-        mon = Prec.init(params, dtype=b.dtype, tag=init_tag)
 
-        def relres(rs):
-            return jnp.sqrt(jnp.abs(rs)) / bnorm
+        def matvec(v, tag):
+            return local_matvec(blk, v, tag=tag, wire=wire, k=k, rows=rows,
+                                ei_bit=ei)
 
-        if kind == "cg":
-            r0 = _residual(b, matvec(x0, mon.tag))
-            state = dict(x=x0, r=r0, p=r0, rs=_pdot(r0, r0),
-                         it=jnp.int32(0), mon=mon,
-                         switches=jnp.full((2,), -1, jnp.int32))
-            state = _guarded_init(state, relres(state["rs"]), guards)
-            if flight is not None:
-                state["fl"] = OF.flight_init(flight, b.dtype)
+        def apply0(v, tag):
+            # The start's traced-tag apply sums its rows after the switch
+            # (``switched_matvec`` says why).
+            return switched_matvec(blk, v, tag, wire=wire, k=k, rows=rows,
+                                   ei_bit=ei)
 
-            def body(s):
-                # EXACTLY fused_cg_step's op order, dots psum'd.
-                tag = s["mon"].tag
-                ap = matvec(s["p"], tag)
-                denom = _pdot(s["p"], ap)
-                with OT.scope(OT.KRYLOV, OT.UPDATE):
-                    alpha = s["rs"] / jnp.where(denom == 0, 1.0, denom)
-                    x = s["x"] + alpha * s["p"]
-                    r = s["r"] - alpha * ap
-                rs2 = _pdot(r, r)
-                with OT.scope(OT.MONITOR):
-                    mon1 = Prec.record(s["mon"], relres(rs2))
-                    mon2 = Prec.update_tag(mon1, params)
-                    sw = _record_switch(s["switches"], mon1, mon2, s["it"])
-                with OT.scope(OT.KRYLOV, OT.UPDATE):
-                    beta = rs2 / jnp.where(s["rs"] == 0, 1.0, s["rs"])
-                    p = r + beta * s["p"]
-                out = dict(x=x, r=r, p=p, rs=rs2, it=s["it"] + 1,
-                           mon=mon2, switches=sw)
-                with OT.scope(OT.MONITOR):
-                    out = _guarded_body(s, out, relres(rs2), guards,
-                                        denom=denom)
-                    if flight is not None:
-                        # The recorded scalars are all psum'd/replicated,
-                        # so every shard writes the SAME ring (out_spec
-                        # P()).
-                        g = out.get("g")
-                        out["fl"] = OF.flight_record(
-                            s["fl"], it=s["it"], relres=relres(rs2), tag=tag,
-                            health=g["health"] if g is not None else None,
-                            a0=alpha, a1=beta, a2=denom)
-                return out
-
-            def cond(s):
-                return _guarded_cond(
-                    s, (relres(s["rs"]) > tol) & (s["it"] < maxiter), guards
-                )
-
-            out = jax.lax.while_loop(cond, body, state)
-            final_rel = relres(out["rs"])
-        else:  # pcg
-            m_apply, m_apply_at = _diag_apply_dispatch(
-                (m_head, m_tail1, m_tail2, m_table), *precond_meta
-            )
-            r0 = _residual(b, matvec(x0, mon.tag))
-            with OT.scope(OT.PRECOND):
-                z0 = m_apply(r0, mon.tag)
-            state = dict(x=x0, r=r0, p=z0, rz=_pdot(r0, z0),
-                         rr=_pdot(r0, r0), it=jnp.int32(0), mon=mon,
-                         switches=jnp.full((2,), -1, jnp.int32))
-            state = _guarded_init(state, relres(state["rr"]), guards)
-            if flight is not None:
-                state["fl"] = OF.flight_init(flight, b.dtype)
-
-            def step_at(s, tag: int):
-                # EXACTLY _pcg_step_at_tag's op order, dots psum'd; the
-                # operator decode, halo exchange and preconditioner apply
-                # all ride the same static-tag branch.
-                ap = local_matvec(blk, s["p"], tag=tag, wire=wire, k=k,
-                                  rows=rows, ei_bit=ei)
-                denom = _pdot(s["p"], ap)
-                with OT.scope(OT.KRYLOV, OT.UPDATE):
-                    alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
-                    x = s["x"] + alpha * s["p"]
-                    r = s["r"] - alpha * ap
-                with OT.scope(OT.PRECOND):
-                    z = m_apply_at(r, tag)
-                rz2 = _pdot(r, z)
-                rr2 = _pdot(r, r)
-                with OT.scope(OT.KRYLOV, OT.UPDATE):
-                    beta = rz2 / jnp.where(s["rz"] == 0, 1.0, s["rz"])
-                    p = z + beta * s["p"]
-                stepped = dict(x=x, r=r, p=p, rz=rz2, rr=rr2)
-                if guards is not None or flight is not None:
-                    stepped["denom"] = denom
-                return stepped
-
-            def body(s):
-                krylov = {k_: s[k_] for k_ in ("x", "r", "p", "rz", "rr")}
-                stepped = jax.lax.switch(
-                    jnp.clip(s["mon"].tag - 1, 0, 2),
-                    [partial(step_at, tag=t) for t in (1, 2, 3)],
-                    krylov,
-                )
-                denom = stepped.pop("denom", None)
-                with OT.scope(OT.MONITOR):
-                    mon1 = Prec.record(s["mon"], relres(stepped["rr"]))
-                    mon2 = Prec.update_tag(mon1, params)
-                    sw = _record_switch(s["switches"], mon1, mon2, s["it"])
-                    rz2 = stepped["rz"]
-                    stepped.update(it=s["it"] + 1, mon=mon2, switches=sw)
-                    out = _guarded_body(s, stepped, relres(stepped["rr"]),
-                                        guards, denom=denom,
-                                        breakdown=rz2 < 0, finite_aux=(rz2,))
-                    if flight is not None:
-                        # Observation-only recompute (bit-identity
-                        # contract).
-                        alpha = s["rz"] / jnp.where(denom == 0, 1.0, denom)
-                        beta = rz2 / jnp.where(s["rz"] == 0, 1.0, s["rz"])
-                        g = out.get("g")
-                        out["fl"] = OF.flight_record(
-                            s["fl"], it=s["it"],
-                            relres=relres(stepped["rr"]), tag=s["mon"].tag,
-                            health=g["health"] if g is not None else None,
-                            a0=alpha, a1=beta, a2=denom)
-                return out
-
-            def cond(s):
-                return _guarded_cond(
-                    s, (relres(s["rr"]) > tol) & (s["it"] < maxiter), guards
-                )
-
-            out = jax.lax.while_loop(cond, body, state)
-            final_rel = relres(out["rr"])
-
-        conv = final_rel <= tol
-        g = out.get("g") if guards is not None else None
-        health, trip = finalize_health(g, conv, final_rel)
-        ckpt = out["ckpt"] if guards is not None else out["x"]
-        outs = (out["x"], out["it"], final_rel, out["mon"].tag,
-                out["switches"], conv, health, trip, ckpt)
-        if flight is not None:
-            outs = outs + (out["fl"],)
-        return outs
+        precond = (None if kind == "cg" else _diag_apply_at(
+            (m_head, m_tail1, m_tail2, m_table), *precond_meta))
+        out = krylov_loop(matvec, precond, _pdot, b, x0, tol, maxiter,
+                          params, bnorm, init_tag=init_tag, guards=guards,
+                          flight=flight, apply0=apply0)
+        res, ckpt = _loop_result(out, bnorm, tol)
+        outs = tuple(res[:8]) + (ckpt,)
+        return outs + (res.flight,) if flight is not None else outs
 
     sharded = P(AXIS)
     out_specs = (sharded, P(), P(), P(), P(), P(), P(), P(), sharded)
@@ -309,8 +155,8 @@ def _run_sharded(part, kind, b, x0, tol, maxiter, params, init_tag, wire,
         pk = precond.packed
         if pk.frac_bits != 52 or pk.tail2.size != pk.head.size:
             # Mirror gse.decode_jnp's guard: an f32-source pack (pack32,
-            # no tail2) supports tags 1/2 only -- the single-device fused
-            # path raises at trace time, and the sharded tag-3 branch
+            # no tail2) supports tags 1/2 only -- the single-chip loop
+            # raises at trace time, and the sharded tag-3 branch
             # would otherwise decode garbage silently.
             raise ValueError(
                 "sharded PCG needs an f64-source packed diagonal "
@@ -370,35 +216,10 @@ def solve_cg_sharded(
     escalation restarts the whole sharded loop from the gathered
     checkpoint at the promoted tag.
     """
-    b, x0, orig_shape = _normalize_b_x0(b, x0)
-    if x0 is None:
-        x0 = jnp.zeros_like(b)
-    if params is None:
-        params = Prec.MonitorParams.for_cg()
-
-    def run(x_start, budget, tag):
-        return _run_sharded(part, "cg", b, x_start, tol, budget, params,
-                            tag, wire, guards=guards, flight=flight,
-                            return_ckpt=True)
-
-    with OT.span("solve.cg_sharded", n=int(b.shape[0]), tol=float(tol),
-                 wire=wire, shards=int(part.n_shards)):
-        res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
-                                recover=recover and guards is not None)
-        if not final_correction:
-            return _restore_shape(res, orig_shape)
-        op = make_sharded_operator(part, wire)
-
-        def apply3(v):
-            return op(v, jnp.int32(3))
-
-        def resume(xr, budget):
-            return run(xr, budget, 3)[0]
-
-        return _restore_shape(
-            _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-            orig_shape,
-        )
+    return _drive(part, b, None, x0=x0, tol=tol, maxiter=maxiter,
+                  params=params, final_correction=final_correction,
+                  wire=wire, guards=guards, recover=recover,
+                  init_tag=init_tag, flight=flight)
 
 
 def solve_pcg_sharded(
@@ -418,48 +239,12 @@ def solve_pcg_sharded(
 ) -> CGResult:
     """Distributed stepped PCG.  Diagonal GSE preconditioners (Jacobi /
     SPAI-0) shard with the operator -- each device decodes its slice of
-    the packed ``M^{-1}`` diagonal at the monitor's tag, inside the same
-    branch as the operator decode (the sharded twin of
-    ``fused_pcg_step``).  Non-diagonal preconditioners fall back to the
-    generic path over ``make_sharded_operator`` (full-vector apply).
+    the packed ``M^{-1}`` diagonal at the step's tag, inside the same
+    branch as the operator decode.  Other preconditioners run the
+    single-chip loop over ``make_sharded_operator`` (full-vector apply).
     ``guards``/``recover``/``init_tag``: see :func:`solve_cg_sharded`.
     """
-    from repro.solvers.precond import DiagGSEPrecond
-
-    b, x0, orig_shape = _normalize_b_x0(b, x0)
-    if x0 is None:
-        x0 = jnp.zeros_like(b)
-    if params is None:
-        params = Prec.MonitorParams.for_cg()
-    if not isinstance(precond, DiagGSEPrecond):
-        from repro.solvers.cg import solve_pcg
-
-        op = make_sharded_operator(part, wire)
-        return solve_pcg(op, b.reshape(orig_shape), precond, x0=x0, tol=tol,
-                         maxiter=maxiter, params=params,
-                         final_correction=final_correction, guards=guards,
-                         recover=recover, init_tag=init_tag, flight=flight)
-
-    def run(x_start, budget, tag):
-        return _run_sharded(part, "pcg", b, x_start, tol, budget, params,
-                            tag, wire, precond=precond, guards=guards,
-                            flight=flight, return_ckpt=True)
-
-    with OT.span("solve.pcg_sharded", n=int(b.shape[0]), tol=float(tol),
-                 wire=wire, shards=int(part.n_shards)):
-        res = run_with_recovery(run, x0, maxiter, init_tag=init_tag,
-                                recover=recover and guards is not None)
-        if not final_correction:
-            return _restore_shape(res, orig_shape)
-        op = make_sharded_operator(part, wire)
-
-        def apply3(v):
-            return op(v, jnp.int32(3))
-
-        def resume(xr, budget):
-            return run(xr, budget, 3)[0]
-
-        return _restore_shape(
-            _finish_with_correction(res, b, tol, maxiter, apply3, resume),
-            orig_shape,
-        )
+    return _drive(part, b, precond, x0=x0, tol=tol, maxiter=maxiter,
+                  params=params, final_correction=final_correction,
+                  wire=wire, guards=guards, recover=recover,
+                  init_tag=init_tag, flight=flight)

@@ -186,7 +186,7 @@ def _sell_segments(a: GSESellC):
 def decode_operand(a, tag: int, acc_dtype=jnp.float64):
     """``(values, columns)`` decode, in the stored entry order, of a
     ``GSECSR`` OR a packed ``GSESellC`` at precision ``tag`` -- the one
-    dispatch point the fused solver steps and the reference SpMV/SpMM
+    dispatch point the solver step and the reference SpMV/SpMM
     share, so every solver path rides whichever layout the caller packed,
     bit-identically.  Runs under the ``decode`` scope (the SELL segment
     gather included)."""
@@ -215,7 +215,8 @@ def _spmv_gse(colpak, head, tail1, tail2, table, row_ids, x, ei_bit, tag,
 def spmv_operand(a, x, tag: int, acc_dtype=jnp.float64):
     """``A @ x`` of a ``GSECSR`` or ``GSESellC`` at a static ``tag``, under
     the ``spmv`` scope: one ``decode_operand``, then ``gather_scatter``.
-    The fused solver steps inline it; ``spmv_gse`` jits it for SELL."""
+    The solver step (``fused_cg.cg_step``) inlines it; ``spmv_gse`` jits
+    it for SELL."""
     with OT.scope(OT.SPMV):
         val, col = decode_operand(a, tag, acc_dtype)
         return gather_scatter(val, col, x, a.row_ids, a.shape[0], acc_dtype)
@@ -240,10 +241,9 @@ def spmv_gse(a, x: jnp.ndarray, tag: int = 1, acc_dtype=jnp.float64):
     4 per nnz of packed colidx -- vs 8+4 for FP64 CSR.  The TPU-tiled
     equivalents (``kernels/ops.gse_spmv_ell`` / ``gse_spmv_sell``)
     dispatch to tag-specialized Pallas kernels that provably stream only
-    those segments (DESIGN.md §2.4).  Inside CG prefer passing the
-    operand straight to ``solvers.solve_cg`` -- the fused iteration path
-    decodes the values once per step and folds the vector ops around this
-    SpMV (DESIGN.md §4).
+    those segments (DESIGN.md §2.4).  Inside CG the step decodes the
+    values once per iteration and folds the vector ops around this SpMV
+    in one tag branch (DESIGN.md §4).
     """
     if isinstance(a, GSESellC):
         return _spmv_gse_sell(a, x, tag, acc_dtype)

@@ -371,7 +371,7 @@ def test_solve_cg_batched_sharded_parity(nrhs):
 @multidevice
 def test_gmres_over_sharded_operator_parity():
     """make_sharded_operator is a drop-in operator callable: exact-wire
-    applications match gse_matvec (standalone calls are bitwise equal;
+    applications match make_gse_operator (standalone calls are bitwise equal;
     inlined into GMRES's larger jitted program the scatter-add
     accumulation order may differ in the last ulp across compilations),
     so GMRES trajectories track the single-device run to ~machine

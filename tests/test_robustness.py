@@ -184,8 +184,8 @@ def test_adversarial_rhs_never_unflagged_nonfinite(solver, kind):
         res = solve_pcg(g, b, make_jacobi(csr), tol=1e-8, maxiter=500,
                         params=_PARAMS)
     else:
-        from repro.solvers.cg import _gsecsr_operator
-        res = solve_gmres(_gsecsr_operator(g), b, tol=1e-8, restart=20,
+        from repro.solvers import make_gse_operator
+        res = solve_gmres(make_gse_operator(g), b, tol=1e-8, restart=20,
                           maxiter=200, params=_PARAMS)
     if kind == "zero":
         # ||b|| = 0: the zero solution satisfies the system immediately
@@ -224,8 +224,8 @@ def test_indefinite_operator_flagged_via_generic_path():
     _, g, b = _sys()
 
     def indefinite(v, tag):
-        from repro.solvers.cg import _gsecsr_operator
-        return -_gsecsr_operator(g)(v, tag)
+        from repro.solvers import make_gse_operator
+        return -make_gse_operator(g)(v, tag)
 
     res = solve_cg(indefinite, b, tol=1e-8, maxiter=500, params=_PARAMS)
     assert not bool(res.converged)

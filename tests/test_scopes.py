@@ -16,8 +16,9 @@ import pytest
 
 from repro.core import precision as P
 from repro.obs import trace as OT
-from repro.solvers import solve_cg
-from repro.solvers.fused_cg import fused_cg_step, fused_pcg_step
+from repro.solvers import make_gse_operator, make_precond_operator, solve_cg
+from repro.solvers.cg import _as_arg, _loop
+from repro.solvers.fused_cg import cg_step, kdot, static_apply
 from repro.solvers.precond import make_jacobi
 from repro.sparse import generators as G
 from repro.sparse.csr import pack_csr, pack_sell
@@ -54,18 +55,34 @@ def op():
     return a, pack_csr(a, k=8), v
 
 
-@pytest.mark.parametrize("layout", ["csr", "sell"])
-def test_fused_cg_step_scopes(op, layout):
-    _, g, v = op
-    g = pack_sell(g) if layout == "sell" else g
-    assert _paths(_hlo(fused_cg_step, g, v, v, v, 1.0, 1)) >= SPMV | KRYLOV
+def _forms(kind, form, a, g):
+    """The operator and preconditioner of a ``kind`` (cg/pcg) solve as a
+    loop takes them: packed (``csr``/``sell``) or as callables."""
+    m = make_jacobi(a) if kind == "pcg" else None
+    if form == "sell":
+        g = pack_sell(g)
+    if form == "callable":
+        g = make_gse_operator(g)
+        m = m if m is None else make_precond_operator(m)
+    return _as_arg(g), _as_arg(m)
 
 
-def test_fused_pcg_step_scopes(op):
+def _step(a, m, x, r, p, rz, tag):
+    return cg_step(static_apply(a), static_apply(m), kdot, x, r, p, rz, tag)
+
+
+@pytest.mark.parametrize("form", ["csr", "sell", "callable"])
+@pytest.mark.parametrize("kind", ["cg", "pcg"])
+def test_step_scopes(op, kind, form):
+    """The one step names the SpMV and Krylov stages, and the
+    preconditioner under PCG, whatever form the operator takes."""
     a, g, v = op
-    m = make_jacobi(a)
-    found = _paths(_hlo(fused_pcg_step, g, m, v, v, v, 1.0, 1))
-    assert found >= SPMV | KRYLOV | {"precond"}
+    ga, m = _forms(kind, form, a, g)
+    found = _paths(_hlo(_step, ga, m, v, v, v, 1.0, 1))
+    want = SPMV | KRYLOV | ({"precond"} if kind == "pcg" else set())
+    assert found >= want
+    if kind == "cg":
+        assert "precond" not in found
 
 
 MG = {"precond/smooth", "precond/residual", "precond/transfer"}
@@ -87,7 +104,7 @@ def test_mg_pcg_step_scopes(mg_op):
     """A lowered MG-PCG iteration names the V-cycle's stages under
     ``precond``, beside the CG SpMV and the Krylov work."""
     g, m, v, _ = mg_op
-    found = _paths(_hlo(fused_pcg_step, g, m, v, v, v, 1.0, 1))
+    found = _paths(_hlo(_step, g, m, v, v, v, 1.0, 1))
     assert found >= SPMV | KRYLOV | MG
 
 
@@ -117,13 +134,14 @@ def test_spmv_gse_scopes(op, tag):
     assert not found & (KRYLOV | {"monitor", "precond"})
 
 
-def test_solve_loop_scopes(op):
-    """The fused loop adds the monitor around the step's scopes."""
-    from repro.solvers.cg import _solve_cg_fused
-
-    _, g, v = op
-    text = _hlo(lambda b: _solve_cg_fused(g, b, jnp.zeros_like(b), 1e-8, 50,
-                                          P.MonitorParams.for_cg()), v)
+@pytest.mark.parametrize("form", ["csr", "callable"])
+@pytest.mark.parametrize("kind", ["cg", "pcg"])
+def test_solve_loop_scopes(op, kind, form):
+    """The loop adds the monitor around the step's scopes."""
+    a, g, v = op
+    ga, m = _forms(kind, form, a, g)
+    text = _hlo(lambda b: _loop(ga, m, b, jnp.zeros_like(b), 1e-8, 50,
+                                P.MonitorParams.for_cg()), v)
     assert _paths(text) >= SPMV | KRYLOV | {"monitor"}
 
 

@@ -256,6 +256,41 @@ def test_ckpt_roundtrip_resume_bit_identical(tmp_path):
     assert int(drv2.res.iters) == int(ref.iters)
 
 
+def test_loop_state_keys_and_cg_state_roundtrip(tmp_path):
+    """The one loop keeps each kind's state keys, which chunk checkpoints
+    restore into, and a CG chunk state round-trips through
+    ``save_state``/``restore_state`` leaf for leaf."""
+    import jax
+
+    a = G.poisson2d(12)
+    g = pack_csr(a, k=8)
+    b = _rhs(a, 4)
+    common = {"x", "r", "p", "it", "mon", "switches"}
+    want = {"cg": common | {"rs"}, "pcg": common | {"rz", "rr"}}
+    for kind, precond in (("cg", None), ("pcg", make_jacobi(a, k=8))):
+        for guards, extra in ((None, set()),
+                              (DEFAULT_GUARDS, {"g", "ckpt"})):
+            drv = SolveChunks(g, b, tol=1e-8, maxiter=2000,
+                              params=_params(), guards=guards,
+                              precond=precond)
+            assert set(drv.init_state()) == want[kind] | extra, kind
+
+    path = str(tmp_path / "ck")
+    drv = SolveChunks(g, b, tol=1e-8, maxiter=2000, params=_params(),
+                      guards=DEFAULT_GUARDS)
+    drv.run_chunk(5)
+    drv.save_state(path)
+    drv2 = SolveChunks(g, b, tol=1e-8, maxiter=2000, params=_params(),
+                       guards=DEFAULT_GUARDS)
+    assert drv2.restore_state(path) == []
+    got, want_leaves = (jax.tree_util.tree_flatten(d._state)
+                        for d in (drv2, drv))
+    assert got[1] == want_leaves[1]
+    for x, y in zip(got[0], want_leaves[0]):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    assert drv2.iters == 5
+
+
 def test_ckpt_corrupt_falls_back_to_previous_good(tmp_path):
     a = G.poisson2d(12)
     g = pack_csr(a, k=8)

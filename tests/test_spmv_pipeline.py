@@ -261,8 +261,6 @@ def test_fused_cg_final_correction():
     b = _b_for(a, seed=4)
     res = solve_cg(g, b, tol=1e-6, maxiter=6000, params=_fast_params(),
                    final_correction=True)
-    from repro.solvers import gse_matvec
-
-    true_rel = jnp.linalg.norm(b - gse_matvec(g, res.x, jnp.int32(3)))
+    true_rel = jnp.linalg.norm(b - make_gse_operator(g)(res.x, jnp.int32(3)))
     true_rel = float(true_rel / jnp.linalg.norm(b))
     assert true_rel < 5e-6

@@ -212,15 +212,15 @@ def test_masked_decode_matches_per_entry_numpy(lo, hi):
 
 
 def test_masked_matvec_matches_per_entry_numpy():
-    from repro.solvers.fused_cg import gse_matvec
+    from repro.solvers import make_gse_operator
 
     _, g, _ = _sys(seed=8)
     m = int(g.shape[0])
     tm = _mixed_map(m)
     masked = ops.masked_for_tagmap(g, tm)
     x = np.random.default_rng(8).normal(size=m)
-    got = np.asarray(gse_matvec(masked, jnp.asarray(x),
-                                jnp.int32(tm.max_tag)))
+    got = np.asarray(make_gse_operator(masked)(jnp.asarray(x),
+                                               jnp.int32(tm.max_tag)))
     vals, cols = _per_entry_reference(g, tm)
     want = np.zeros(m, np.float64)
     np.add.at(want, np.asarray(g.in_csr_order().row_ids, np.int64),

@@ -6,7 +6,8 @@ cannot show that the kernels run on the chip.  These tests hand the TPU
 compiler the shapes of ``poisson3d(128)`` -- 2,097,152 rows, ELL width
 128 -- for ``topo.devices[0]`` of a ``v5e:2x2`` topology and compile:
 the ELL SpMV at tags 1-3, the ELL SpMM, one SELL width bucket, and the
-float64 jnp SpMV every solver runs.  x64 stays on, as ``conftest.py``
+float64 jnp SpMV every solver runs; and the sharded CG/PCG loop over all
+four chips of the topology, whose tag switch wraps the whole step.  x64 stays on, as ``conftest.py``
 sets it.  The topology is described in a fixture, never at import, so
 every test worker collects the same tests; where it cannot be described
 the tests skip.
@@ -32,7 +33,7 @@ HBM_BYTES = 16 * 2 ** 30          # one v5e
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
 
@@ -47,8 +48,13 @@ def one_chip():
         except Exception as e:  # no TPU compiler in this installation
             jax.config.update("jax_enable_compilation_cache", was)
             pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-        yield SingleDeviceSharding(topo.devices[0])
+        yield topo
         jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _spec(sharding, shape, dtype):
@@ -134,3 +140,47 @@ def test_f64_jnp_spmv_compiles_for_v5e(one_chip, tag):
              _spec(s, slots, jnp.uint16), _spec(s, slots, jnp.uint32),
              _spec(s, (K,), jnp.int32), _spec(s, slots, jnp.int32),
              _spec(s, (N,), jnp.float64))
+
+
+@pytest.mark.parametrize("kind", ["cg", "pcg"])
+def test_sharded_loop_compiles_for_v5e_2x2(topo, kind):
+    """The whole sharded loop, its step's tag switch around each shard's
+    row sum and psum'd dots, compiles for the four chips (poisson3d(24)
+    in z-slabs; the compiler once refused a float64 slot-major reduce at
+    the root of a switch branch)."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import precision as Prec
+    from repro.distributed.partition import AXIS, partition_gsecsr
+    from repro.robustness.guards import DEFAULT_GUARDS
+    from repro.solvers import make_jacobi, sharded as S
+    from repro.sparse import generators as G
+    from repro.sparse.csr import pack_csr
+
+    a = G.poisson3d(24)
+    part = partition_gsecsr(pack_csr(a, k=8), 4)
+    mesh = Mesh(np.array(topo.devices[:4]), (AXIS,))
+    part.__dict__["_mesh"] = mesh
+    sh, rep = NamedSharding(mesh, P(AXIS)), NamedSharding(mesh, P())
+    if kind == "cg":
+        diag, meta = S._empty_diag(part), None
+    else:
+        pk = make_jacobi(a).packed
+        diag = tuple(S._pad_to(t, part.n_padded)
+                     for t in (pk.head, pk.tail1, pk.tail2)) + (pk.table,)
+        meta = (pk.ei_bit, pk.frac_bits)
+    fn = S._sharded_loop_fn(part, kind, "exact", 5000,
+                            Prec.MonitorParams.for_cg(), 1, meta,
+                            DEFAULT_GUARDS)
+    arrays = (part.colpak, part.head, part.tail1, part.tail2, part.row_ids,
+              part.bnd_idx, part.halo_idx)
+    vec = _spec(sh, (part.n_padded,), jnp.float64)
+    scalar = _spec(rep, (), jnp.float64)
+    args = ([_spec(sh, x.shape, x.dtype) for x in arrays]
+            + [_spec(rep, part.table.shape, part.table.dtype)]
+            + [_spec(sh, x.shape, x.dtype) for x in diag[:3]]
+            + [_spec(rep, diag[3].shape, diag[3].dtype)]
+            + [vec, vec, scalar, scalar])
+    text = fn.lower(*args).compile().as_text()
+    assert "all-reduce" in text and "conditional" in text

@@ -142,15 +142,14 @@ class Smoke:
         return a, ref, g
 
     def build(self):
-        from repro.sparse.csr import csr_order
         from repro.sparse.spmv import decode_gsecsr
 
         self.rng = np.random.default_rng(self.seed)
         self.a, self.ref, self.g = self.operator(self.solve_grid)
         self.b = self.ref.matvec(self.rng.standard_normal(self.a.shape[0]))
         self.b_dev = jnp.asarray(self.b)
-        val, _ = decode_gsecsr(self.g, 3)
-        val = csr_order(val, self.g.rowptr)
+        val, _ = decode_gsecsr(self.g.in_csr_order(), 3)
+        val = np.asarray(val)
         diff = float(np.max(np.abs(val - self.ref.val)))
         print(f"tag-3 device decode vs host float64 values: "
               f"max_abs_diff={diff:.3e}", flush=True)

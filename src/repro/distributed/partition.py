@@ -6,9 +6,10 @@ split the byte stream across devices.  ``partition_gsecsr`` cuts a
 :class:`~repro.sparse.csr.GSECSR` into ``n_shards`` contiguous row blocks:
 
   * each shard keeps its row slice of the packed segment streams
-    (``colpak/head/tail1/tail2``), stored like the operand's: slot-major
-    ``(W, R)`` blocks, or in CSR order padded to the max per-shard nnz,
-    so the shards stack into ``(n_shards, ...)`` device arrays for
+    (``colpak/head/tail1/tail2``), stored slot-major ``(W, R)`` where the
+    slots fit (ranked by the shard's column offsets where those fit,
+    ``csr.offset_slots``), or in CSR order padded to the max per-shard
+    nnz, so the shards stack into ``(n_shards, ...)`` device arrays for
     ``shard_map``;
   * column indices are REMAPPED to index the shard's local x window
     ``concat(x_shard, x_halo)`` -- columns owned by the shard index the
@@ -59,8 +60,11 @@ from repro.sparse.csr import (
     csr_order,
     is_slot_major,
     iteration_stream_bytes,
+    offset_slots,
+    row_slots,
     slot_major,
     slot_map_fits,
+    stored_slots,
     vector_stream_bytes,
 )
 
@@ -90,11 +94,14 @@ class PartitionedGSECSR:
     to uniform extents (max boundary ``B``, max halo ``H``) so
     ``shard_map`` can split them along the mesh axis; with enough devices
     visible, row ``i`` of each lives on device ``i`` of the ``AXIS`` mesh.
-    Where the operand is stored slot-major (``GSECSR.slot_major``) each
-    shard's entries are too, ``(W, R)`` with one width ``W`` for all, its
-    padded rows holding padding entries only; padding entries decode to
-    +0.0 and read the sentinel column ``R + H``, one zero past the halo
-    window.  Otherwise each shard's entries are in CSR order, padded to
+    Where a slot-major store fits (``csr.slot_map_fits``) each shard's
+    entries are ``(W, R)`` with one width ``W`` for all, its padded rows
+    holding padding entries only; padding entries decode to +0.0 and
+    read the sentinel column ``R + H``, one zero past the halo window.
+    Where every shard's column offsets in its window fit W slots
+    (``csr.offset_slots``), ``slot_map`` holds each shard's ``(W,)``
+    offsets and the SpMV reads the window in slices (DESIGN.md §21).
+    Otherwise each shard's entries are in CSR order, padded to
     the max nnz ``E`` with entries that decode to +0.0 and carry the
     out-of-range row id ``R``, which the local segment sum drops.  Either
     way padding perturbs nothing; padded boundary slots (``bnd_idx ==
@@ -127,6 +134,8 @@ class PartitionedGSECSR:
     rows_real: Tuple[int, ...]       # real rows owned by each shard
     bnd_counts: Tuple[int, ...]      # real boundary entries each shard sends
     halo_counts: Tuple[int, ...]     # real halo entries each shard gathers
+    # -- column offset of each slot, where offsets rank the slots ----------
+    slot_map: jnp.ndarray | None = None  # (s, W) int32
 
     # -- sizes -------------------------------------------------------------
 
@@ -164,10 +173,13 @@ class PartitionedGSECSR:
 
     def _shard_entries(self, i: int, field: str) -> np.ndarray:
         """Shard ``i``'s real entries of ``field`` in CSR order
-        (``csr_order`` over the shard's local row pointer)."""
-        rp = np.asarray(self.rowptr)[i]
+        (``csr_order``; padding reads the sentinel ``R + H``)."""
         arr = np.asarray(getattr(self, field))[i]
-        return csr_order(arr if self.slot_major else arr[:rp[-1]], rp)
+        if not self.slot_major:
+            return arr[:int(np.asarray(self.rowptr)[i, -1])]
+        stored = stored_slots(np.asarray(self.colpak)[i], self.ei_bit,
+                              self.rows_per_shard + self.halo_idx.shape[1])
+        return csr_order(arr, stored)
 
     def _global_entries(self):
         """Per-shard (global_rows, global_cols) of the REAL entries, int64.
@@ -319,7 +331,7 @@ class PartitionedGSECSR:
     def tree_flatten(self):
         leaves = (self.colpak, self.head, self.tail1, self.tail2,
                   self.row_ids, self.rowptr, self.bnd_idx, self.halo_idx,
-                  self.table)
+                  self.table, self.slot_map)
         aux = (self.ei_bit, self.shape, self.n_shards, self.rows_per_shard,
                self.nnz_per_shard, self.rows_real, self.bnd_counts,
                self.halo_counts)
@@ -327,7 +339,7 @@ class PartitionedGSECSR:
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(*leaves, *aux)
+        return cls(*leaves[:9], *aux, slot_map=leaves[9])
 
 
 def partition_gsecsr(a: GSECSR, n_shards: int) -> PartitionedGSECSR:
@@ -440,20 +452,32 @@ def partition_gsecsr(a: GSECSR, n_shards: int) -> PartitionedGSECSR:
     local_rp = np.stack([np.pad(rowptr[lo:hi + 1] - rowptr[lo],
                                 (0, r_blk - (hi - lo)), mode="edge")
                          for lo, hi in zip(starts[:-1], starts[1:])])
+    # One width for every shard: the most column offsets of any shard
+    # where they fit, else the longest row anywhere.
+    cmask = np.uint32((1 << (32 - ei)) - 1)
+    plan = offset_slots([(local_rp[i], s_colpak[i, :nnz_per_shard[i]]
+                          & cmask) for i in range(n_shards)], r_blk)
     W = int(np.diff(rowptr).max(initial=0))
-    if slot_map_fits(W, n_shards * r_blk, int(rowptr[-1])):
-        # One width for every shard, the longest row anywhere.
+    slot_map = stored = None
+    if plan is not None:
+        slot_map, stored = plan
+    elif slot_map_fits(W, n_shards * r_blk, int(rowptr[-1])):
+        stored = np.stack([row_slots(rp, W) for rp in local_rp])
+    if stored is not None:
         fills = (max_local, 0, 0, 0)    # padding reads past the halo
         blocks = tuple(
-            np.stack([slot_major(blk[i, :nnz_per_shard[i]], local_rp[i], W,
-                                 fill) for i in range(n_shards)])
+            np.stack([slot_major(blk[i, :nnz_per_shard[i]], stored[i], fill)
+                      for i in range(n_shards)])
             for blk, fill in zip(blocks[:4], fills))
         blocks += (np.ascontiguousarray(np.broadcast_to(
-            np.arange(r_blk, dtype=np.int32), (n_shards, W, r_blk))),)
+            np.arange(r_blk, dtype=np.int32), stored.shape)),)
     stacked, mesh = _place(
         blocks + (local_rp.astype(np.int32), s_bnd, s_halo), n_shards)
+    if slot_map is not None:
+        slot_map = _place([slot_map], n_shards)[0][0]
     part = PartitionedGSECSR(
         *stacked,
+        slot_map=slot_map,
         table=a.table,
         ei_bit=ei,
         shape=a.shape,
@@ -522,10 +546,9 @@ def unshard(part: PartitionedGSECSR, a_template: GSECSR) -> GSECSR:
             "tail2": t2_parts}
     segs = {f: np.concatenate(v) for f, v in segs.items()}
     if a_template.slot_major:
-        rowptr = np.asarray(a_template.rowptr, np.int64)
-        width = a_template.colpak.shape[0]
-        segs = {f: slot_major(v, rowptr, width,
-                              part.shape[1] if f == "colpak" else 0)
+        stored = a_template.stored
+        segs = {f: slot_major(v, stored, part.shape[1] if f == "colpak"
+                              else 0)
                 for f, v in segs.items()}
     return GSECSR(
         rowptr=a_template.rowptr,
@@ -533,5 +556,6 @@ def unshard(part: PartitionedGSECSR, a_template: GSECSR) -> GSECSR:
         row_ids=a_template.row_ids,
         ei_bit=ei,
         shape=part.shape,
+        slot_map=a_template.slot_map,
         **{f: jnp.asarray(v) for f, v in segs.items()},
     )

@@ -47,7 +47,12 @@ from repro.sparse.spmv import _decode_gsecsr, gather_products, sum_rows
 
 __all__ = ["shard_mesh", "local_matvec", "local_products",
            "switched_matvec", "dist_spmv", "dist_spmm",
-           "make_sharded_operator"]
+           "make_sharded_operator", "BLOCK_FIELDS", "block_arrays"]
+
+# The partition's stacked per-shard arrays a shard's matvec reads, in the
+# order ``block_arrays`` passes them into ``shard_map``.
+BLOCK_FIELDS = ("colpak", "head", "tail1", "tail2", "row_ids", "bnd_idx",
+                "halo_idx", "slot_map")
 
 
 def shard_mesh(part: PartitionedGSECSR) -> Mesh:
@@ -74,8 +79,8 @@ def local_matvec(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
     """One shard's y-block at a STATIC tag, called inside shard_map:
     ``local_products`` and then their row sums (``sum_rows``).
 
-    ``blk`` holds this shard's slices (leading axis already dropped):
-    ``colpak/head/tail1/tail2/row_ids/bnd_idx/halo_idx/table``.
+    ``blk`` holds this shard's slices of ``BLOCK_FIELDS`` (leading axis
+    already dropped) and the ``table``.
     Padding entries add nothing to the row sums: slot-major padding is
     +0.0 times the zero past the halo window, and ``segment_sum`` drops
     the CSR-order padding's row id ``rows`` (bit-identical local row sums
@@ -90,8 +95,10 @@ def local_products(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
                    k: int, ei_bit: int, acc_dtype=jnp.float64,
                    slot_tags: jnp.ndarray | None = None) -> jnp.ndarray:
     """One shard's products ``val * x[col]`` at a STATIC tag: the halo
-    exchange gathers only boundary entries, and the decode is the exact
-    single-device ``_decode_gsecsr`` on the shard's segments.  Runs under
+    exchange gathers only boundary entries, the decode is the exact
+    single-device ``_decode_gsecsr`` on the shard's segments, and the
+    window of ``x`` is read in slices where the shard has a
+    ``slot_map`` (``gather_products``).  Runs under
     the ``spmv`` scope, the boundary pack, all-gather and halo
     concatenation under ``halo``."""
     with OT.scope(OT.SPMV):
@@ -106,7 +113,7 @@ def local_products(blk: dict, x_sh: jnp.ndarray, *, tag: int, wire: str,
                 blk["colpak"], blk["head"], blk["tail1"], blk["tail2"],
                 blk["table"], ei_bit, tag, acc_dtype,
             )
-        return gather_products(val, col, xcat, acc_dtype)
+        return gather_products(val, col, xcat, acc_dtype, blk["slot_map"])
 
 
 def _local_sum(blk, prod, rows):
@@ -153,14 +160,19 @@ def _halo_extend(blk, x_sh, *, tag, wire, k, slot_tags):
     return jnp.concatenate([x_sh, flat[blk["halo_idx"]]], axis=0)
 
 
-def _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table):
-    """Drop the leading per-device axis shard_map leaves on stacked
-    operands and bundle the shard's block for ``local_matvec``."""
-    return dict(
-        colpak=colpak[0], head=head[0], tail1=tail1[0], tail2=tail2[0],
-        row_ids=row_ids[0], bnd_idx=bnd_idx[0], halo_idx=halo_idx[0],
-        table=table,
-    )
+def block_arrays(part: PartitionedGSECSR) -> tuple:
+    """The partition's ``BLOCK_FIELDS``, each split over the mesh axis
+    inside ``shard_map`` (``slot_map`` may be ``None``)."""
+    return tuple(getattr(part, f) for f in BLOCK_FIELDS)
+
+
+def _blk(arrays, table):
+    """Drop the leading per-device axis shard_map leaves on the stacked
+    ``block_arrays`` and bundle the shard's block for ``local_matvec``."""
+    blk = {f: None if v is None else v[0]
+           for f, v in zip(BLOCK_FIELDS, arrays)}
+    blk["table"] = table
+    return blk
 
 
 def _dist_matvec_fn(part: PartitionedGSECSR, wire: str, ndim: int,
@@ -175,17 +187,15 @@ def _dist_matvec_fn(part: PartitionedGSECSR, wire: str, ndim: int,
     mesh = shard_mesh(part)
     rows, ei, k = part.rows_per_shard, part.ei_bit, int(part.table.size)
 
-    def run(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-            x, tag):
-        blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
-                   table)
+    def run(arrays, table, x, tag):
+        blk = _blk(arrays, table)
         return switched_matvec(blk, x, tag, wire=wire, k=k, rows=rows,
                                ei_bit=ei, acc_dtype=acc_dtype)
 
     sharded = P(AXIS)
     fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
-        in_specs=(sharded,) * 7 + (P(), sharded, P()),
+        in_specs=(sharded, P(), sharded, P()),
         out_specs=sharded,
         check_vma=False,
     ))
@@ -210,10 +220,8 @@ def _dist_matvec_map_fn(part: PartitionedGSECSR, tm: TagMap, wire: str,
     rows, ei, k = part.rows_per_shard, part.ei_bit, int(part.table.size)
     tag = tm.max_tag
 
-    def run(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-            slot_tags, x):
-        blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
-                   table)
+    def run(arrays, table, slot_tags, x):
+        blk = _blk(arrays, table)
         return local_matvec(blk, x, tag=tag, wire=wire, k=k, rows=rows,
                             ei_bit=ei, acc_dtype=acc_dtype,
                             slot_tags=slot_tags[0])
@@ -221,7 +229,7 @@ def _dist_matvec_map_fn(part: PartitionedGSECSR, tm: TagMap, wire: str,
     sharded = P(AXIS)
     fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
-        in_specs=(sharded,) * 7 + (P(), sharded, sharded),
+        in_specs=(sharded, P(), sharded, sharded),
         out_specs=sharded,
         check_vma=False,
     ))
@@ -239,14 +247,10 @@ def _apply_padded(part: PartitionedGSECSR, x: jnp.ndarray, tag,
     if isinstance(tag, TagMap):
         fn = _dist_matvec_map_fn(part, tag, wire, x.ndim, acc_dtype)
         st = jnp.asarray(part.bnd_slot_tags(tag).astype(np.int32))
-        y = fn(part.colpak, part.head, part.tail1, part.tail2,
-               part.row_ids, part.bnd_idx, part.halo_idx, part.table,
-               st, xp)
+        y = fn(block_arrays(part), part.table, st, xp)
         return y[:n]
     fn = _dist_matvec_fn(part, wire, x.ndim, acc_dtype)
-    y = fn(part.colpak, part.head, part.tail1, part.tail2, part.row_ids,
-           part.bnd_idx, part.halo_idx, part.table, xp,
-           jnp.asarray(tag, jnp.int32))
+    y = fn(block_arrays(part), part.table, xp, jnp.asarray(tag, jnp.int32))
     return y[:n]
 
 

@@ -285,7 +285,7 @@ def masked_for_tagmap(a, tm: TagMap):
             rowptr=a.rowptr, colpak=a.colpak, head=a.head,
             tail1=jnp.asarray(t1), tail2=jnp.asarray(t2),
             table=a.table, row_ids=a.row_ids, ei_bit=a.ei_bit,
-            shape=a.shape,
+            shape=a.shape, slot_map=a.slot_map,
         )
 
     return _cached_pack(a, ("tagmap", tm.crc32, tm.group_size), build)

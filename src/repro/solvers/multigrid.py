@@ -45,7 +45,7 @@ from repro.core import gse
 from repro.obs import metrics as OM
 from repro.obs import trace as OT
 from repro.solvers.precond import _TagDispatchPrecond
-from repro.sparse.csr import (CSR, GSECSR, csr_order, from_coo, pack_csr,
+from repro.sparse.csr import (CSR, GSECSR, from_coo, pack_csr,
                               stack_row_blocks)
 from repro.sparse.generators import box_stencil
 from repro.sparse.spmv import spmv_operand
@@ -290,11 +290,11 @@ class MGPrecond(_TagDispatchPrecond):
         layout = level_layouts(self.grids)[lvl]
         out = []
         for c in range(COLOURS):
-            block = jax.tree.map(lambda v: v[c], lv.ops)
+            block = jax.tree.map(lambda v: v[c], lv.ops).in_csr_order()
             val, col = decode_gsecsr(block, 3, jnp.float64)
-            rows = csr_order(block.row_ids, block.rowptr) + c * lv.rows
-            out.append((layout[rows], layout[csr_order(col, block.rowptr)],
-                        csr_order(val, block.rowptr)))
+            rows = np.asarray(block.row_ids) + c * lv.rows
+            out.append((layout[rows], layout[np.asarray(col)],
+                        np.asarray(val)))
         return tuple(np.concatenate(p) for p in zip(*out))
 
     def bytes_touched(self, tag: int) -> int:

@@ -39,6 +39,7 @@ from repro.distributed.partition import PartitionedGSECSR
 from repro.kernels.dist_spmv import (
     AXIS,
     _blk,
+    block_arrays,
     local_matvec,
     shard_mesh,
     switched_matvec,
@@ -99,10 +100,9 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
     mesh = shard_mesh(part)
     rows, ei, k = part.rows_per_shard, part.ei_bit, int(part.table.size)
 
-    def run(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx, table,
-            m_head, m_tail1, m_tail2, m_table, b, x0, tol, bnorm):
-        blk = _blk(colpak, head, tail1, tail2, row_ids, bnd_idx, halo_idx,
-                   table)
+    def run(arrays, table, m_head, m_tail1, m_tail2, m_table, b, x0, tol,
+            bnorm):
+        blk = _blk(arrays, table)
 
         def matvec(v, tag):
             return local_matvec(blk, v, tag=tag, wire=wire, k=k, rows=rows,
@@ -131,7 +131,7 @@ def _sharded_loop_fn(part: PartitionedGSECSR, kind: str, wire: str,
         out_specs = out_specs + (P(),)
     fn = jax.jit(jax.shard_map(
         run, mesh=mesh,
-        in_specs=(sharded,) * 7 + (P(),) + (sharded,) * 3 + (P(),)
+        in_specs=(sharded, P()) + (sharded,) * 3 + (P(),)
         + (sharded, sharded, P(), P()),
         out_specs=out_specs,
         check_vma=False,
@@ -173,8 +173,7 @@ def _run_sharded(part, kind, b, x0, tol, maxiter, params, init_tag, wire,
     bnorm = jnp.linalg.norm(b)           # computed on the FULL vector so
     bnorm = jnp.where(bnorm == 0, 1.0, bnorm)  # it matches single-device
     outs = fn(
-        part.colpak, part.head, part.tail1, part.tail2, part.row_ids,
-        part.bnd_idx, part.halo_idx, part.table,
+        block_arrays(part), part.table,
         m_head, m_tail1, m_tail2, m_table,
         _pad_to(b, part.n_padded), _pad_to(x0, part.n_padded),
         jnp.asarray(tol, b.dtype), bnorm,

@@ -17,6 +17,7 @@ onto dense (rows x lanes) tiles (DESIGN.md section 2).
 from __future__ import annotations
 
 import dataclasses
+import heapq
 from typing import Tuple
 
 import jax
@@ -45,6 +46,9 @@ __all__ = [
     "is_slot_major",
     "csr_order",
     "slot_map_fits",
+    "row_slots",
+    "offset_slots",
+    "stored_slots",
 ]
 
 # Matrix-stream bytes one padded slot (or one nnz) costs at each GSE tag:
@@ -104,9 +108,13 @@ class GSECSR:
 
     The four segments and ``row_ids`` share one entry order: CSR order,
     ``(nnz,)``, or slot-major, ``(W, m)`` (``slot_major``; DESIGN.md
-    §19), where entry ``[k, i]`` is row ``i``'s ``k``-th entry and the
-    slots past a row's length are padding entries that decode to +0.0 and
-    read column ``shape[1]``, one zero appended to ``x``."""
+    §19), where column ``i`` holds row ``i``'s entries down its slots, in
+    CSR order, and the other slots padding entries that decode to +0.0
+    and read column ``shape[1]``, one zero appended to ``x``.  Where
+    ``slot_map`` is set, slot ``w`` holds the entry at column ``i +
+    slot_map[w]`` of every row ``i`` that has one (``offset_slots``;
+    DESIGN.md §21), so the SpMV reads ``x`` as W contiguous windows;
+    otherwise slot ``k`` holds each row's ``k``-th entry."""
 
     rowptr: jnp.ndarray   # (m+1,) int32
     colpak: jnp.ndarray   # (nnz,) | (W, m) uint32: [expIdx : EI_BIT][col]
@@ -117,6 +125,7 @@ class GSECSR:
     row_ids: jnp.ndarray  # (nnz,) | (W, m) int32, in the segments' order
     ei_bit: int
     shape: Tuple[int, int]
+    slot_map: jnp.ndarray | None = None  # (W,) int32 column offset a slot
 
     @property
     def m_h(self) -> int:
@@ -143,14 +152,23 @@ class GSECSR:
         per = precision_table.TAG_VALUE_BYTES[tag]
         return self.nnz * per + self.table.size * 4
 
+    @property
+    def stored(self) -> np.ndarray | None:
+        """Where a slot-major store holds entries and not padding
+        (``stored_slots``), ``None`` in CSR order."""
+        if not self.slot_major:
+            return None
+        return stored_slots(self.colpak, self.ei_bit, self.shape[1])
+
     def in_csr_order(self) -> "GSECSR":
         """This operand with its segments and ``row_ids`` in CSR order, as
         host arrays (``csr_order``): itself where it is stored so."""
         if not self.slot_major:
             return self
-        segs = {f: csr_order(getattr(self, f), self.rowptr)
+        stored = self.stored
+        segs = {f: csr_order(getattr(self, f), stored)
                 for f in ("colpak", "head", "tail1", "tail2", "row_ids")}
-        return dataclasses.replace(self, **segs)
+        return dataclasses.replace(self, slot_map=None, **segs)
 
     def bytes_per_nnz(self, tag: int) -> int:
         """Modeled matrix-stream bytes per nonzero of the encoding at
@@ -198,12 +216,13 @@ class GSECSR:
     def tree_flatten(self):
         return (
             self.rowptr, self.colpak, self.head, self.tail1, self.tail2,
-            self.table, self.row_ids,
+            self.table, self.row_ids, self.slot_map,
         ), (self.ei_bit, self.shape)
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(*leaves, ei_bit=aux[0], shape=aux[1])
+        return cls(*leaves[:7], ei_bit=aux[0], shape=aux[1],
+                   slot_map=leaves[7])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -283,7 +302,9 @@ class GSESellC:
       * ``unperm``  -- (m,) position of each original row in that
         concatenation (``perm[unperm[i]] == i``);
       * ``row_ids`` -- the ``GSECSR``'s row ids, in ``gather``'s order;
-      * ``table``   -- shared-exponent table.
+      * ``table``   -- shared-exponent table;
+      * ``slot_map`` -- the ``GSECSR``'s column offset of each slot, or
+        ``None``.
 
     Static: per-bucket ``widths``, ``c``, ``sigma``, ``lane``, ``ei_bit``,
     ``shape``.  The byte model charges ACTUAL padded slots
@@ -305,6 +326,7 @@ class GSESellC:
     lane: int
     ei_bit: int
     shape: Tuple[int, int]
+    slot_map: jnp.ndarray | None = None  # (W,) int32, the GSECSR's
 
     @property
     def slot_major(self) -> bool:
@@ -388,6 +410,7 @@ class GSESellC:
         leaves = (
             self.colpak, self.head, self.tail1, self.tail2,
             self.gather, self.perm, self.unperm, self.row_ids, self.table,
+            self.slot_map,
         )
         aux = (self.widths, self.c, self.sigma, self.lane, self.ei_bit,
                self.shape)
@@ -395,7 +418,7 @@ class GSESellC:
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
-        return cls(*leaves, *aux)
+        return cls(*leaves[:9], *aux, slot_map=leaves[9])
 
 
 def from_coo(rows, cols, vals, shape) -> CSR:
@@ -459,9 +482,17 @@ def pack_csr(a: CSR, k: int = 8) -> GSECSR:
     shift = np.uint32(32 - ei)
     rowptr = np.asarray(a.rowptr, np.int64)
     width = int(np.diff(rowptr).max(initial=0))
-    by_slot = slot_map_fits(width, a.shape[0], col.size)
+    plan = offset_slots([(rowptr, col)], a.shape[0])
+    slot_map = None
+    if plan is not None:
+        slot_map, stored = plan[0][0], plan[1][0]
+    elif slot_map_fits(width, a.shape[0], col.size):
+        stored = row_slots(rowptr, width)
+    else:
+        stored = None
     # Slot-major padding reads column ``shape[1]``, which must fit too.
-    max_col = a.shape[1] if by_slot else int(col.max()) if col.size else 0
+    max_col = (a.shape[1] if stored is not None
+               else int(col.max()) if col.size else 0)
     if max_col >= (1 << (32 - ei)):
         raise ValueError(
             f"column count {max_col} needs > {32 - ei} bits; "
@@ -470,8 +501,8 @@ def pack_csr(a: CSR, k: int = 8) -> GSECSR:
     colpak = (exp_idx.astype(np.uint32) << shift) | col
     segs = dict(colpak=colpak, head=new_head, tail1=new_tail1,
                 tail2=new_tail2)
-    if by_slot:
-        segs = _slot_major_segments(segs, rowptr, width, a.shape[1])
+    if stored is not None:
+        segs = _slot_major_segments(segs, stored, a.shape[1])
     else:
         segs["row_ids"] = a.row_ids
     return GSECSR(
@@ -479,21 +510,21 @@ def pack_csr(a: CSR, k: int = 8) -> GSECSR:
         table=jnp.asarray(table, jnp.int32),
         ei_bit=ei,
         shape=a.shape,
+        slot_map=None if slot_map is None else jnp.asarray(slot_map),
         **{f: jnp.asarray(v) for f, v in segs.items()},
     )
 
 
-def _slot_major_segments(segs: dict, rowptr, width: int, sentinel: int):
+def _slot_major_segments(segs: dict, stored, sentinel: int):
     """CSR-order ``colpak``/``head``/``tail1``/``tail2`` laid out
-    ``(width, rows)`` by ``slot_major``, padding entries zero segments
-    reading column ``sentinel``, and the ``row_ids`` of that order: row
-    ``i`` in every slot of column ``i``."""
-    rows = np.arange(np.asarray(rowptr).size - 1, dtype=np.int32)
-    out = {f: slot_major(segs[f], rowptr, width,
-                         sentinel if f == "colpak" else 0)
+    ``(W, rows)`` by ``slot_major`` at the slots ``stored`` marks, padding
+    entries zero segments reading column ``sentinel``, and the
+    ``row_ids`` of that order: row ``i`` in every slot of column ``i``."""
+    out = {f: slot_major(segs[f], stored, sentinel if f == "colpak" else 0)
            for f in ("colpak", "head", "tail1", "tail2")}
+    rows = np.arange(stored.shape[-1], dtype=np.int32)
     out["row_ids"] = np.ascontiguousarray(
-        np.broadcast_to(rows, (width, rows.size)))
+        np.broadcast_to(rows, stored.shape))
     return out
 
 
@@ -501,31 +532,47 @@ def stack_row_blocks(g: GSECSR, rows: int) -> GSECSR:
     """``g``'s rows in blocks of ``rows`` as one ``GSECSR`` whose leaves
     carry a leading block axis: ``jax.tree.map(lambda v: v[i], stacked)``
     is rows ``i * rows:(i + 1) * rows``, a ``(rows, n)`` operand with the
-    columns unchanged.  Every block is stored slot-major, ``(W, rows)``
-    with W the longest row of ``g``, and holds local row ids and a local
-    row pointer; the exponent table is ``g``'s, repeated."""
+    columns unchanged.  Every block is stored slot-major, ``(W, rows)``:
+    ranked by its column offsets where every block's fit
+    (``offset_slots``, W the most any block has, ``slot_map`` then
+    ``(blocks, W)``), otherwise by row slot, W the longest row of ``g``.
+    Each holds local row ids and a local row pointer; the exponent table
+    is ``g``'s, repeated."""
     rowptr = np.asarray(g.rowptr, np.int64)
     m = rowptr.size - 1
     if m % rows:
         raise ValueError(f"{m} rows do not split into blocks of {rows}")
     nb = m // rows
     c = g.in_csr_order()
-    width = int(np.diff(rowptr).max(initial=0))
-    segs = _slot_major_segments(vars(c), rowptr, width, g.shape[1])
-    segs["row_ids"] = segs["row_ids"] % rows
     starts = rowptr[0:m:rows]
     local = np.concatenate([rowptr[:-1].reshape(nb, rows),
                             rowptr[rows::rows][:, None]], axis=1)
     local -= starts[:, None]
+    cols = np.asarray(c.colpak) & np.uint32((1 << (32 - g.ei_bit)) - 1)
+    ends = np.append(starts[1:], rowptr[-1])
+    plan = offset_slots([(lp, cols[s:e]) for lp, s, e
+                         in zip(local, starts, ends)], rows)
+    if plan is not None:
+        slot_map, stored = plan
+    else:
+        width = int(np.diff(rowptr).max(initial=0))
+        slot_map, stored = None, np.stack([row_slots(lp, width)
+                                           for lp in local])
+    segs = {f: [] for f in ("colpak", "head", "tail1", "tail2", "row_ids")}
+    for b, (s, e) in enumerate(zip(starts, ends)):
+        blk = _slot_major_segments(
+            {f: np.asarray(getattr(c, f))[s:e] for f in segs}, stored[b],
+            g.shape[1])
+        for f in segs:
+            segs[f].append(blk[f])
     table = np.asarray(g.table)
     return GSECSR(
         rowptr=jnp.asarray(local, jnp.int32),
         table=jnp.asarray(np.broadcast_to(table, (nb,) + table.shape)),
         ei_bit=g.ei_bit,
         shape=(rows, g.shape[1]),
-        **{f: jnp.asarray(np.ascontiguousarray(
-            v.reshape(width, nb, rows).transpose(1, 0, 2)))
-           for f, v in segs.items()},
+        slot_map=None if slot_map is None else jnp.asarray(slot_map),
+        **{f: jnp.asarray(np.stack(v)) for f, v in segs.items()},
     )
 
 
@@ -537,6 +584,90 @@ def slot_map_fits(width: int, rows: int, nnz: int) -> bool:
     return width * rows <= MAX_SLOTS_PER_NNZ * nnz
 
 
+def row_slots(rowptr, width: int) -> np.ndarray:
+    """``(width, rows)`` bool: the slots a store by row slot fills, row
+    ``i``'s ``k``-th entry at ``[k, i]`` (DESIGN.md §19)."""
+    lens = np.diff(np.asarray(rowptr, np.int64))
+    return np.arange(width)[:, None] < lens[None, :]
+
+
+def offset_slots(blocks, rows: int):
+    """The store ranked by column offset, where it fits (DESIGN.md §21).
+
+    ``blocks`` is a list of ``(rowptr, col)``: a block's local row pointer
+    over ``rows`` rows and its CSR-order columns, in the space its ``x``
+    is read in.  Each entry's offset is ``col - row``, and each of a
+    block's distinct offsets takes one slot, so every entry of slot ``w``
+    reads ``x[row + slot_map[w]]``.  The slots follow the offsets in an
+    order that every row's CSR order keeps (``_offset_order``):
+    ascending where columns rise, as in a ``pack_csr`` operand.  W is the
+    most distinct offsets of any block, and a block with fewer fills its
+    last slots with offset 0 and padding.  Returns ``(slot_map,
+    stored)``, ``(blocks, W)`` int32 and ``(blocks, W, rows)`` bool, or
+    ``None`` where the W slots do not fit (``slot_map_fits``) or no
+    order of some block's offsets keeps its rows' entry order."""
+    offs, nnz = [], 0
+    for rowptr, col in blocks:
+        row = np.repeat(np.arange(rows), np.diff(np.asarray(rowptr)))
+        off = np.asarray(col, np.int64) - row
+        offs.append((off, row, np.unique(off)))
+        nnz += off.size
+    width = max((d.size for _, _, d in offs), default=0)
+    if not nnz or not slot_map_fits(width, len(blocks) * rows, nnz):
+        return None
+    slot_map = np.zeros((len(blocks), width), np.int32)
+    stored = np.zeros((len(blocks), width, rows), bool)
+    for b, (off, row, d) in enumerate(offs):
+        idx = np.searchsorted(d, off)
+        order = _offset_order(idx, row, d.size)
+        if order is None:
+            return None
+        rank = np.empty(d.size, np.int64)
+        rank[order] = np.arange(d.size)
+        slot_map[b, :d.size] = d[order]
+        stored[b, rank[idx], row] = True
+    return slot_map, stored
+
+
+def _offset_order(idx, row, count: int):
+    """An order of ``count`` offsets, by index into the sorted distinct
+    offsets, in which each row's entries (``idx``, CSR order) come one
+    after another: the lowest first wherever the rows leave a choice, so
+    ascending where columns rise.  ``None`` where none exists: a row holds
+    one offset twice, or two rows order a pair both ways.  Rows of a
+    shard read the halo below them at offsets above their own columns',
+    so there it is not the ascending order; in a slab two planes thick
+    the halo below and the halo above fall at one offset, first in some
+    rows and last in others, and there is none."""
+    same = np.diff(row) == 0
+    pairs = np.unique(idx[:-1][same] * count + idx[1:][same])
+    src, dst = pairs // count, pairs % count
+    if (src == dst).any():
+        return None
+    indeg = np.bincount(dst, minlength=count)
+    succ = [[] for _ in range(count)]
+    for s, t in zip(src.tolist(), dst.tolist()):
+        succ[s].append(t)
+    ready = [i for i in range(count) if indeg[i] == 0]
+    heapq.heapify(ready)
+    order = []
+    while ready:
+        i = heapq.heappop(ready)
+        order.append(i)
+        for t in succ[i]:
+            indeg[t] -= 1
+            if indeg[t] == 0:
+                heapq.heappush(ready, t)
+    return np.asarray(order, np.int64) if len(order) == count else None
+
+
+def stored_slots(colpak, ei_bit: int, sentinel: int) -> np.ndarray:
+    """Where a slot-major ``colpak`` holds entries: every slot whose
+    column is not the padding's ``sentinel``."""
+    mask = np.uint32((1 << (32 - ei_bit)) - 1)
+    return (np.asarray(colpak, np.uint32) & mask) < sentinel
+
+
 def is_slot_major(entries, lead: int = 0) -> bool:
     """Whether a stored entry array (a segment, ``row_ids``, a SELL
     ``gather`` or what the decode makes of them) is slot-major,
@@ -546,35 +677,36 @@ def is_slot_major(entries, lead: int = 0) -> bool:
     return entries.ndim - lead == 2
 
 
-def slot_major(entries, rowptr, width: int, fill) -> np.ndarray:
-    """CSR-order ``entries`` laid out ``(width, rows)`` by row slot.
+def slot_major(entries, stored, fill) -> np.ndarray:
+    """CSR-order ``entries`` laid out ``(W, rows)`` at the slots
+    ``stored`` marks (``row_slots`` or ``offset_slots``), ``fill``
+    elsewhere.
 
-    Entry ``[k, i]`` is row ``i``'s ``k``-th entry, in CSR order within
-    the row, while ``k`` is below the row's length, and ``fill`` past it.
-    Rows run along the minor axis, which the TPU tiles as lanes: a
-    ``(rows, width)`` store with a short minor axis would pad it to 128.
+    Column ``i`` holds row ``i``'s entries down its marked slots, in CSR
+    order.  Rows run along the minor axis, which the TPU tiles as lanes:
+    a ``(rows, W)`` store with a short minor axis would pad it to 128.
     ``sparse.spmv.gather_scatter`` sums each column of the products from
-    the top, which is ``segment_sum``'s order.  ``csr_order`` inverts it.
+    the top, which is ``segment_sum``'s order, padding adding +0.0.
+    ``csr_order`` inverts it.
     """
     entries = np.asarray(entries)
-    rowptr = np.asarray(rowptr, np.int64)
-    k = np.arange(width, dtype=np.int64)[:, None]
-    pos = np.where(k < np.diff(rowptr)[None, :], rowptr[None, :-1] + k,
-                   entries.size)
-    return np.append(entries, np.asarray(fill, entries.dtype))[pos]
+    stored = np.asarray(stored, bool)
+    out = np.full(stored.shape, fill, entries.dtype)
+    out.T[stored.T] = entries
+    return out
 
 
-def csr_order(store, rowptr) -> np.ndarray:
+def csr_order(store, stored) -> np.ndarray:
     """A stored entry array in CSR order, as a host array: the identity on
     a CSR-order ``(nnz,)`` array, and on a slot-major ``(W, rows)`` one
-    (``slot_major``) each row's entries in slot order, padding dropped.
-    The one way back to CSR order for the host-side packers."""
+    (``slot_major``) each row's entries in slot order, the padding that
+    ``stored`` leaves unmarked dropped wherever it sits in the row
+    (``GSECSR.stored``).  The one way back to CSR order for the
+    host-side packers."""
     store = np.asarray(store)
     if not is_slot_major(store):
         return store
-    lens = np.diff(np.asarray(rowptr, np.int64))
-    real = np.arange(store.shape[0])[:, None] < lens[None, :]
-    return store.T[real.T]
+    return store.T[np.asarray(stored, bool).T]
 
 
 def vector_stream_bytes(op, dtype=jnp.float64) -> int:
@@ -807,8 +939,7 @@ def pack_sell(a: GSECSR, c: int = 8, sigma: int | None = None,
     if a.slot_major:
         # Straight into the operand's slot-major order; padding reads the
         # padding entry the decode appends after the buckets.
-        rowptr = np.asarray(a.rowptr, np.int64)
-        gather = slot_major(gather, rowptr, a.colpak.shape[0], flat_off)
+        gather = slot_major(gather, a.stored, flat_off)
     return GSESellC(
         colpak=tuple(jnp.asarray(outs[w][0]) for w in widths),
         head=tuple(jnp.asarray(outs[w][1]) for w in widths),
@@ -825,4 +956,5 @@ def pack_sell(a: GSECSR, c: int = 8, sigma: int | None = None,
         lane=lane,
         ei_bit=a.ei_bit,
         shape=a.shape,
+        slot_map=a.slot_map,
     )

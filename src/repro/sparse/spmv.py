@@ -6,8 +6,10 @@ the target precision but multiply-accumulate happens at high precision
 
 The jnp implementations sum each row of an operand stored slot-major
 (``csr.slot_major``) as a reduce over its W slots: the decode yields the
-``(W, rows)`` values and columns, so ``x[col]`` is the one gather, and
-the sum runs in ``segment_sum``'s order.  An operand stored in CSR order
+``(W, rows)`` values and columns, ``x`` is read at the columns, and the
+sum runs in ``segment_sum``'s order.  Where the store ranks its slots by
+column offset (``csr.offset_slots``) the read is W contiguous windows of
+``x``; otherwise it is the gather ``x[col]``.  An operand stored in CSR order
 (plain ``CSR`` baselines, rows too skewed for slots) keeps
 ``segment_sum`` over precomputed row ids, which XLA lowers to a
 scatter-add (DESIGN.md §19).  The Pallas blocked-ELL kernel
@@ -30,7 +32,7 @@ from repro.sparse.csr import CSR, GSECSR, GSESellC, is_slot_major
 
 __all__ = ["spmv", "spmv_gse", "spmv_ell", "spmm", "spmm_gse",
            "decode_gsecsr", "decode_operand", "gather_scatter",
-           "gather_products", "sum_rows", "spmv_operand"]
+           "gather_products", "x_windows", "sum_rows", "spmv_operand"]
 
 
 # Which row reduction each traced SpMV took: counted once a trace, so it
@@ -40,35 +42,80 @@ ROW_REDUCTION = OM.REGISTRY.counter(
     "Traced SpMVs by row reduction: over a slot-major store or segment_sum.",
     labelnames=("path",))
 
+# How each traced SpMV read ``x``: as contiguous windows of a store ranked
+# by column offset, or by the gather ``x[col]``; counted once a trace.
+X_READ = OM.REGISTRY.counter(
+    "spmv_x_read_total",
+    "Traced SpMVs by how x is read: contiguous windows or a gather.",
+    labelnames=("path",))
 
-def gather_scatter(val, col, x, row_ids, num_rows, acc_dtype):
+
+def gather_scatter(val, col, x, row_ids, num_rows, acc_dtype,
+                   slot_map=None):
     """``segment_sum(val * x[col], row_ids)``, the SpMV after its decode:
     ``gather_products`` then ``sum_rows``.  ``x`` is ``(n,)`` or an
     ``(n, nrhs)`` block."""
-    return sum_rows(gather_products(val, col, x, acc_dtype), row_ids,
-                    num_rows)
+    return sum_rows(gather_products(val, col, x, acc_dtype, slot_map),
+                    row_ids, num_rows)
 
 
-def gather_products(val, col, x, acc_dtype):
+def x_windows(x, slot_map, rows):
+    """``(W, rows)`` (or ``(W, rows, nrhs)``) reads of ``x``: ``[w, i]`` is
+    ``x[i + slot_map[w]]``, and zero where that falls outside ``x``.
+
+    Slot ``w`` reads ``rows`` consecutive entries of ``x`` padded with
+    ``rows`` zeros at both ends, which any offset ``col - row`` of a
+    block, in ``(-rows, n)``, stays inside.  On an accelerator each slot
+    is one dynamic slice, a contiguous read, and a traced ``slot_map`` (a
+    shard's, a stacked block's inside a loop) reads as a constant one
+    does.  On the CPU the same entries are read by one gather: behind
+    contiguous loads XLA's CPU backend vectorises the fused slot sum and
+    a consuming dot, and reassociates both, where behind a gather it
+    sums in order (DESIGN.md §21)."""
+    pad = jnp.zeros((rows,) + x.shape[1:], x.dtype)
+    xp = jnp.concatenate([pad, x, pad])
+    start = rows + slot_map
+
+    def slices(xp, start):
+        return jnp.stack([jax.lax.dynamic_slice_in_dim(xp, start[w], rows)
+                          for w in range(start.shape[0])])
+
+    def gather(xp, start):
+        return xp[start[:, None] + jnp.arange(rows, dtype=start.dtype)]
+
+    return jax.lax.platform_dependent(xp, start, cpu=gather,
+                                      default=slices)
+
+
+def gather_products(val, col, x, acc_dtype, slot_map=None):
     """``val * x[col]`` in the decode's entry order, under the ``gather``
     scope.
 
     For ``(W, rows)`` values and columns of a slot-major store
-    (``csr.slot_major``), ``x`` gets one zero appended, which the padding
-    entries' column ``n`` reads, and the padding's products (+0.0
-    already) are selected to zero: the select stands between each
-    multiply and the reduce's add, which XLA's CPU backend would
-    otherwise contract into an FMA, unlike ``segment_sum``, which rounds
-    every product first.  On a v5e the select costs nothing measurable
-    (DESIGN.md §19)."""
+    (``csr.slot_major``), ``x`` is read by ``x_windows`` where the store
+    ranks its slots by column offset (``slot_map``), and otherwise by
+    the gather ``x[col]`` with one zero appended to ``x``, which the
+    padding entries' column ``n`` reads.  Either way the padding's
+    products (+0.0 already, or NaN against a non-finite window entry)
+    are selected to zero: the select stands between each multiply and
+    the reduce's add, which XLA's CPU backend would otherwise contract
+    into an FMA, unlike ``segment_sum``, which rounds every product
+    first.  On a v5e the select costs nothing measurable (DESIGN.md §19,
+    §21)."""
     with OT.scope(OT.GATHER):
         xa = x.astype(acc_dtype)
+        windowed = slot_map is not None
+        X_READ.labels(path="window" if windowed else "gather").inc()
         if not is_slot_major(col):
             xg = xa[col]
             return val * xg if x.ndim == 1 else val[:, None] * xg
         n = xa.shape[0]
-        xa = jnp.concatenate([xa, jnp.zeros((1,) + xa.shape[1:], acc_dtype)])
-        xg = xa[col]
+        if windowed:
+            xg = x_windows(xa, slot_map, col.shape[-1])
+        else:
+            xa = jnp.concatenate(
+                [xa, jnp.zeros((1,) + xa.shape[1:], acc_dtype)])
+            xg = xa[col]
         if x.ndim == 1:
             return jnp.where(col < n, val * xg, 0.0)
         return jnp.where((col < n)[..., None], val[..., None] * xg, 0.0)
@@ -204,6 +251,10 @@ def decode_operand(a, tag: int, acc_dtype=jnp.float64):
 @partial(jax.jit, static_argnames=("tag", "acc_dtype", "num_rows", "ei_bit"))
 def _spmv_gse(colpak, head, tail1, tail2, table, row_ids, x, ei_bit, tag,
               acc_dtype, num_rows):
+    # Reads x by the gather on every store.  The final correction's eager
+    # check lowers a switch over this jaxpr once a solve, where a change
+    # to what it lowers has cost a v5e host 0.1 s a solve (PERF.md §6);
+    # the windows would save one SpMV's gather a solve.
     with OT.scope(OT.SPMV):
         with OT.scope(OT.DECODE):
             val, col = _decode_gsecsr(
@@ -214,12 +265,14 @@ def _spmv_gse(colpak, head, tail1, tail2, table, row_ids, x, ei_bit, tag,
 
 def spmv_operand(a, x, tag: int, acc_dtype=jnp.float64):
     """``A @ x`` of a ``GSECSR`` or ``GSESellC`` at a static ``tag``, under
-    the ``spmv`` scope: one ``decode_operand``, then ``gather_scatter``.
+    the ``spmv`` scope: one ``decode_operand``, then ``gather_scatter``,
+    which reads ``x`` in windows where the operand has a ``slot_map``.
     The solver step (``fused_cg.cg_step``) inlines it; ``spmv_gse`` jits
     it for SELL."""
     with OT.scope(OT.SPMV):
         val, col = decode_operand(a, tag, acc_dtype)
-        return gather_scatter(val, col, x, a.row_ids, a.shape[0], acc_dtype)
+        return gather_scatter(val, col, x, a.row_ids, a.shape[0], acc_dtype,
+                              a.slot_map)
 
 
 _spmv_gse_sell = partial(jax.jit, static_argnames=("tag", "acc_dtype"))(

@@ -293,6 +293,38 @@ def test_solve_cg_kshard_trajectory_parity(shards, params):
 
 
 @multidevice
+@pytest.mark.parametrize("kind", ["cg", "pcg"])
+def test_sharded_windows_follow_the_gathers_trajectory(kind):
+    """Four shards of a 3-D Laplacian read their windows through one
+    offset a slot; the sharded CG and Jacobi-PCG take the iterations,
+    residuals (the flight ring) and iterate of the same shards read by
+    ``x[col]``, the tags stepping 1 -> 2 -> 3 on the way."""
+    import dataclasses
+
+    from repro.obs import flight as OF
+    from repro.solvers import make_jacobi, solve_cg, solve_pcg
+
+    a = G.poisson3d(12)
+    part = partition_gsecsr(pack_csr(a, k=8), 4)
+    assert part.slot_map is not None
+    bare = dataclasses.replace(part, slot_map=None)
+    b = _b_for(a)
+    kw = dict(tol=1e-10, maxiter=2000, params=_STEP_PARAMS,
+              flight=OF.FlightParams(capacity=512), final_correction=True)
+    if kind == "cg":
+        runs = [solve_cg(p, b, **kw) for p in (part, bare)]
+    else:
+        m = make_jacobi(a, k=8)
+        runs = [solve_pcg(p, b, m, **kw) for p in (part, bare)]
+    assert bool(runs[0].converged)
+    assert int(runs[0].iters) == int(runs[1].iters)
+    assert np.array_equal(np.asarray(runs[0].x), np.asarray(runs[1].x))
+    for f0, f1 in zip(jax.tree.leaves(runs[0].flight),
+                      jax.tree.leaves(runs[1].flight)):
+        assert np.array_equal(np.asarray(f0), np.asarray(f1))
+
+
+@multidevice
 @pytest.mark.parametrize("shards", [4, 8])
 def test_solve_cg_gse_wire_converges(shards):
     """The tag-aware compressed halo is lossy at tags 1/2, but the

@@ -63,13 +63,14 @@ def test_row_slots_layout_and_sentinel():
     fill past each row, and ``csr_order`` takes it back."""
     rowptr = np.array([0, 2, 2, 5, 6])
     entries = np.array([10, 11, 12, 13, 14, 15], np.uint32)
-    sm = C.slot_major(entries, rowptr, 3, 99)
+    stored = C.row_slots(rowptr, 3)
+    sm = C.slot_major(entries, stored, 99)
     assert sm.dtype == np.uint32 and sm.shape == (3, 4)
     np.testing.assert_array_equal(sm, [[10, 99, 12, 15],
                                        [11, 99, 13, 99],
                                        [99, 99, 14, 99]])
-    np.testing.assert_array_equal(C.csr_order(sm, rowptr), entries)
-    assert C.csr_order(entries, rowptr) is entries
+    np.testing.assert_array_equal(C.csr_order(sm, stored), entries)
+    assert C.csr_order(entries, None) is entries
 
 
 def test_pack_csr_builds_the_map_with_rows_on_lanes(monkeypatch):
@@ -100,8 +101,8 @@ def test_pack_csr_builds_the_map_with_rows_on_lanes(monkeypatch):
         assert (val[pad] == 0.0).all() and not np.signbit(val[pad]).any()
         assert (col[pad] == a.shape[1]).all()
         val0, col0 = decode_gsecsr(ref, tag)
-        np.testing.assert_array_equal(C.csr_order(val, rowptr), val0)
-        np.testing.assert_array_equal(C.csr_order(col, rowptr), col0)
+        np.testing.assert_array_equal(C.csr_order(val, g.stored), val0)
+        np.testing.assert_array_equal(C.csr_order(col, g.stored), col0)
     # The SELL pack gathers the slot-major store straight out of its
     # buckets.
     from repro.sparse.spmv import _sell_segments
@@ -231,7 +232,7 @@ def test_large_exponent_table_decodes_as_csr_order(tag):
     assert g.slot_major and g.table.shape == (97,)
     val, _ = decode_gsecsr(g, tag)
     val0, _ = decode_gsecsr(g0, tag)
-    np.testing.assert_array_equal(C.csr_order(val, g.rowptr), val0)
+    np.testing.assert_array_equal(C.csr_order(val, g.stored), val0)
     x = jnp.asarray(np.random.default_rng(tag).standard_normal(a.shape[1]))
     np.testing.assert_array_equal(np.asarray(spmv_gse(g, x, tag=tag)),
                                   np.asarray(spmv_gse(g0, x, tag=tag)))
@@ -264,11 +265,11 @@ def test_nonfinite_x_spreads_as_segment_sum(layout, nrhs):
 
 
 def test_spmv_compiles_to_one_gather_of_x():
-    """Outside its decode, the compiled CPU SpMV over a slot-major store
+    """Outside its decode, the compiled CPU SpMV over a store by row slot
     gathers once, ``x[col]``: the products are reduced where they are,
     not gathered into a row-slot layout as well."""
-    g = C.pack_csr(G.poisson3d(6), k=8)
-    assert g.slot_major
+    g = C.pack_csr(_ragged(), k=8)
+    assert g.slot_major and g.slot_map is None
     x = jnp.linspace(0.5, 1.5, g.shape[0])
     text = jax.jit(lambda g, x: spmv_operand(g, x, 3)).lower(
         g, x).compile().as_text()

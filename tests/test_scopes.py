@@ -151,6 +151,7 @@ _SHARDED = textwrap.dedent("""
     jax.config.update("jax_enable_x64", True)
     from repro.core import precision as P
     from repro.distributed.partition import partition_gsecsr
+    from repro.kernels.dist_spmv import block_arrays
     from repro.solvers import sharded as S
     from repro.solvers.precond import make_jacobi
     from repro.sparse import generators as G
@@ -169,9 +170,7 @@ _SHARDED = textwrap.dedent("""
         fn = S._sharded_loop_fn(part, kind, "exact", 50,
                                 P.MonitorParams.for_cg(), 1, meta[kind])
         bp = S._pad_to(b, part.n_padded)
-        text = fn.lower(part.colpak, part.head, part.tail1, part.tail2,
-                        part.row_ids, part.bnd_idx, part.halo_idx,
-                        part.table, *diag[kind], bp,
+        text = fn.lower(block_arrays(part), part.table, *diag[kind], bp,
                         jnp.zeros_like(bp),
                         jnp.asarray(1e-8), jnp.linalg.norm(b)
                         ).compile().as_text()

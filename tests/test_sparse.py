@@ -93,7 +93,7 @@ def test_colpak_roundtrip():
     a = G.random_spd(300, seed=5)
     g = C.pack_csr(a, k=8)
     _, col = S.decode_gsecsr(g, tag=3)
-    np.testing.assert_array_equal(C.csr_order(col, g.rowptr),
+    np.testing.assert_array_equal(C.csr_order(col, g.stored),
                                   np.asarray(a.col))
 
 

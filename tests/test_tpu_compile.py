@@ -6,7 +6,8 @@ cannot show that the kernels run on the chip.  These tests hand the TPU
 compiler the shapes of ``poisson3d(128)`` -- 2,097,152 rows, ELL width
 128 -- for ``topo.devices[0]`` of a ``v5e:2x2`` topology and compile:
 the ELL SpMV at tags 1-3, the ELL SpMM, one SELL width bucket, and the
-float64 jnp SpMV every solver runs; and the sharded CG/PCG loop over all
+float64 jnp SpMV the solvers run, by gather and by windows of ``x``; and
+the sharded CG/PCG loop over all
 four chips of the topology, whose tag switch wraps the whole step.  x64 stays on, as ``conftest.py``
 sets it.  The topology is described in a fixture, never at import, so
 every test worker collects the same tests; where it cannot be described
@@ -142,6 +143,32 @@ def test_f64_jnp_spmv_compiles_for_v5e(one_chip, tag):
              _spec(s, (N,), jnp.float64))
 
 
+@pytest.mark.parametrize("tag", [1, 3])
+def test_windowed_f64_spmv_compiles_for_v5e(one_chip, tag):
+    """The solver step's SpMV over a store ranked by column offset,
+    poisson3d(128)'s seven slots: ``x`` read as seven dynamic slices, no
+    gather of ``x`` left in the program, nor the CPU's branch."""
+    import re
+
+    from repro.sparse.csr import GSECSR
+    from repro.sparse.spmv import spmv_operand
+
+    s = one_chip
+    slots = (7, N)
+    g = GSECSR(rowptr=_spec(s, (N + 1,), jnp.int32),
+               colpak=_spec(s, slots, jnp.uint32),
+               head=_spec(s, slots, jnp.uint16),
+               tail1=_spec(s, slots, jnp.uint16),
+               tail2=_spec(s, slots, jnp.uint32),
+               table=_spec(s, (K,), jnp.int32),
+               row_ids=_spec(s, slots, jnp.int32), ei_bit=EI_BIT,
+               shape=(N, N), slot_map=_spec(s, (7,), jnp.int32))
+    text = _compile(lambda g, x: spmv_operand(g, x, tag), g,
+                    _spec(s, (N,), jnp.float64))
+    assert not re.search(r" gather\(", text)
+    assert " conditional(" not in text
+
+
 @pytest.mark.parametrize("kind", ["cg", "pcg"])
 def test_sharded_loop_compiles_for_v5e_2x2(topo, kind):
     """The whole sharded loop, its step's tag switch around each shard's
@@ -153,6 +180,7 @@ def test_sharded_loop_compiles_for_v5e_2x2(topo, kind):
 
     from repro.core import precision as Prec
     from repro.distributed.partition import AXIS, partition_gsecsr
+    from repro.kernels.dist_spmv import block_arrays
     from repro.robustness.guards import DEFAULT_GUARDS
     from repro.solvers import make_jacobi, sharded as S
     from repro.sparse import generators as G
@@ -173,11 +201,11 @@ def test_sharded_loop_compiles_for_v5e_2x2(topo, kind):
     fn = S._sharded_loop_fn(part, kind, "exact", 5000,
                             Prec.MonitorParams.for_cg(), 1, meta,
                             DEFAULT_GUARDS)
-    arrays = (part.colpak, part.head, part.tail1, part.tail2, part.row_ids,
-              part.bnd_idx, part.halo_idx)
+    assert part.slot_map is not None      # every shard reads windows
     vec = _spec(sh, (part.n_padded,), jnp.float64)
     scalar = _spec(rep, (), jnp.float64)
-    args = ([_spec(sh, x.shape, x.dtype) for x in arrays]
+    args = ([jax.tree.map(lambda x: _spec(sh, x.shape, x.dtype),
+                          block_arrays(part))]
             + [_spec(rep, part.table.shape, part.table.dtype)]
             + [_spec(sh, x.shape, x.dtype) for x in diag[:3]]
             + [_spec(rep, diag[3].shape, diag[3].dtype)]
